@@ -12,10 +12,6 @@ __version__ = "0.1.0"
 
 import jax as _jax
 
-from .framework.jax_compat import install as _install_jax_compat  # noqa: E402
-
-_install_jax_compat()
-
 # -- core types --------------------------------------------------------------
 Tensor = _jax.Array
 

@@ -25,8 +25,8 @@ from dataclasses import dataclass, replace
 from typing import Callable, Dict, Iterable, List, Optional
 
 from .report import SEVERITIES, Finding
-from .walker import (EqnSite, iter_jaxprs, source_summary, subjaxprs,
-                     unwrap, walk)
+from .walker import (EqnSite, iter_jaxprs, pallas_kernel_name,
+                     source_summary, subjaxprs, unwrap, walk)
 
 __all__ = [
     "AnalysisConfig", "RuleContext", "Rule", "RULES", "register_rule",
@@ -583,8 +583,7 @@ def pallas_config_untuned(ctx):
     for site in ctx.sites:
         if site.primitive != "pallas_call":
             continue
-        info = site.eqn.params.get("name_and_src_info")
-        kernel_name = getattr(info, "name", "")
+        kernel_name = pallas_kernel_name(site.eqn)
         # forward kernels only: the paired backward kernels of the same
         # call would re-report the identical missing entry
         if kernel_name not in ("_fwd_kernel", "_ce_fwd_kernel",

@@ -29,7 +29,7 @@ from typing import Iterator, Optional, Tuple
 __all__ = [
     "EqnSite", "SubJaxpr", "INLINE_CALL_PRIMS", "unwrap", "inline_target",
     "subjaxprs", "has_inner", "walk", "iter_jaxprs", "count_eqns",
-    "source_summary", "SchedNode", "linear_schedule",
+    "pallas_kernel_name", "source_summary", "SchedNode", "linear_schedule",
 ]
 
 # call-like primitives whose single inner jaxpr is semantically the
@@ -300,6 +300,15 @@ def linear_schedule(jaxpr) -> list:
 def count_eqns(jaxpr) -> int:
     """Total equation count, recursively through all inner jaxprs."""
     return sum(1 for _ in walk(jaxpr))
+
+
+def pallas_kernel_name(eqn) -> str:
+    """Kernel name of a ``pallas_call`` equation: the ``name`` the call
+    was given, else the kernel function's name from the kernel jaxpr's
+    debug info (where jax 0.9 keeps it); "" when neither is there."""
+    return eqn.params.get("name") or getattr(
+        getattr(eqn.params.get("jaxpr"), "debug_info", None),
+        "func_name", "") or ""
 
 
 def source_summary(eqn) -> Optional[str]:

@@ -15,9 +15,14 @@ Capability map (reference):
   checkpoint (deterministic resumable checkpoints are the TPU-idiomatic
   recovery path — SURVEY.md §5 failure detection row).
 
-TPU notes: one process drives all local chips (single-controller JAX), so
-``--nproc_per_node`` counts *host processes*, not chips. Workers read
-PADDLE_* + JAX coordinator vars and call
+TPU notes: on one host ONE process drives all the chips
+(single-controller JAX), and a chip belongs to one process at a time — N
+local ranks would each claim every chip, and all but the first fail or
+hang. So ``--nproc_per_node`` counts *host processes*, not chips: on a
+TPU host it is 1 (one launcher rank per host of a multi-host job), and
+``--nproc_per_node N > 1`` on one machine is the CPU simulation
+(``JAX_PLATFORMS=cpu``, one virtual device per rank) that the tests use.
+Workers read PADDLE_* + JAX coordinator vars and call
 ``paddle_tpu.distributed.init_parallel_env()`` /
 ``jax.distributed.initialize()`` with no arguments.
 """

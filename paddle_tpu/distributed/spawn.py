@@ -1,9 +1,12 @@
 """paddle.distributed.spawn equivalent (reference: distributed/spawn.py).
 
-On TPU, one process drives all local chips (single-controller JAX), so
-spawn-per-device is unnecessary; this spawns one process per *host group*
-for multi-process simulation/testing (the SURVEY.md §4 TestDistBase pattern),
-setting PADDLE_TRAINER_ID / PADDLE_TRAINERS_NUM env vars.
+On TPU, one process drives all the chips of a host (single-controller
+JAX) and a chip belongs to one process at a time, so spawn-per-device is
+not only unnecessary but wrong there: every spawned rank would claim
+every chip. This spawns one process per *host group* for the CPU
+simulation of a multi-process job (``JAX_PLATFORMS=cpu``; the SURVEY.md
+§4 TestDistBase pattern), setting PADDLE_TRAINER_ID /
+PADDLE_TRAINERS_NUM env vars.
 """
 from __future__ import annotations
 

@@ -6,9 +6,12 @@ of autoregressive serving — contiguous per-sequence KV buffers fragment
 and strand memory. This module is the vLLM-style answer scaled to the
 repo's serving runtime:
 
-- **Fixed pool** — ``(layers, pages, page_size, heads, head_dim)`` host
-  arrays; a page id spans all layers, so one block table drives every
-  layer's gather. Allocation is a free-list pop; there is no growth path,
+- **Fixed pool** — ``(layers, pages, heads, page_size, head_dim)`` host
+  arrays, head-major inside a page so the Pallas paged kernels can block
+  one head's ``(page_size, head_dim)`` tile (the TPU lowering wants the
+  block's last two dims to be the array's); a page id spans all layers,
+  so one block table drives every layer's gather. Allocation is a
+  free-list pop; there is no growth path,
   which is the point: capacity pressure must surface in admission
   (``can_admit``) as modeled wait / shedding, never as OOM mid-decode.
 - **Prefix sharing** — completed pages register under a *chained* chunk
@@ -91,8 +94,8 @@ class PagedKVCache:
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
         self.num_layers = int(num_layers)
-        shape = (self.num_layers, self.num_pages, self.page_size,
-                 int(num_heads), int(head_dim))
+        shape = (self.num_layers, self.num_pages, int(num_heads),
+                 self.page_size, int(head_dim))
         self.k = np.zeros(shape, dtype)
         self.v = np.zeros(shape, dtype)
         self.ref = [0] * self.num_pages
@@ -282,13 +285,15 @@ class PagedKVCache:
                     if self.ref[page] > 1:
                         # COW: the tail is shared — copy what's written
                         fresh = self._alloc_locked()
-                        self.k[:, fresh, :slot] = self.k[:, page, :slot]
-                        self.v[:, fresh, :slot] = self.v[:, page, :slot]
+                        self.k[:, fresh, :, :slot] = \
+                            self.k[:, page, :, :slot]
+                        self.v[:, fresh, :, :slot] = \
+                            self.v[:, page, :, :slot]
                         self._deref_locked(page)
                         seq.pages[-1] = fresh
                 page = seq.pages[-1]
-                self.k[:, page, slot] = k_new[:, i]
-                self.v[:, page, slot] = v_new[:, i]
+                self.k[:, page, :, slot] = k_new[:, i]
+                self.v[:, page, :, slot] = v_new[:, i]
                 seq.tail_tokens.append(toks[i])
                 seq.length += 1
                 if slot == ps - 1:
@@ -321,7 +326,7 @@ class PagedKVCache:
 
     # -- read side ----------------------------------------------------------
     def pools(self, layer: int = 0) -> Tuple[np.ndarray, np.ndarray]:
-        """(k_pool, v_pool) views for one layer: (pages, page_size, heads,
+        """(k_pool, v_pool) views for one layer: (pages, heads, page_size,
         head_dim) — the arrays the attention gather indexes."""
         return self.k[layer], self.v[layer]
 

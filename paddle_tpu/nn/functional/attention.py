@@ -52,26 +52,21 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     # undropped cost from s=512, and XLA-with-dropout pays an extra
     # (B,H,S,S) mask on top. Dropout and kv_lens padding masks run inside
     # the kernel; only dense attn_mask tensors force the XLA path.
-    use_flash = (attn_mask is None and
-                 flash_supported(query, key, min_seq=512))
-    if not use_flash:
-        from ...ops.pallas.tuner import record_fallback
-        record_fallback("flash_attention")
-    if use_flash:
-        try:
-            rate, seed = 0.0, None
-            if dropout_p > 0.0 and training:
-                from ...framework.random import get_rng_key
-                rate = float(dropout_p)
-                seed = jax.random.randint(get_rng_key(), (), 0,
-                                          jnp.iinfo(jnp.int32).max,
-                                          dtype=jnp.int32)
-            return flash_attention(query, key, value, causal=is_causal,
-                                   kv_lens=kv_lens, dropout_rate=rate,
-                                   dropout_seed=seed)
-        except Exception:
-            from ...ops.pallas.tuner import record_fallback
-            record_fallback("flash_attention")
+    if attn_mask is None and flash_supported(query, key, min_seq=512):
+        # no except around the kernel: one the gate selected must raise
+        # when it breaks, not leave the model training on the O(S²) path
+        rate, seed = 0.0, None
+        if dropout_p > 0.0 and training:
+            from ...framework.random import get_rng_key
+            rate = float(dropout_p)
+            seed = jax.random.randint(get_rng_key(), (), 0,
+                                      jnp.iinfo(jnp.int32).max,
+                                      dtype=jnp.int32)
+        return flash_attention(query, key, value, causal=is_causal,
+                               kv_lens=kv_lens, dropout_rate=rate,
+                               dropout_seed=seed)
+    from ...ops.pallas.tuner import record_fallback
+    record_fallback("flash_attention")
     if kv_lens is not None:
         t = key.shape[1]
         lens_mask = (jnp.arange(t)[None, None, None, :] <

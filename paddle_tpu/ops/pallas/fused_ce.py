@@ -44,9 +44,12 @@ from .flash_attention import LANES, NEG_INF, STAT_LANES
 # entries); a v5e timing refresh only has to update the DB, not these
 DEFAULT_BLOCK_TOKENS = 256
 DEFAULT_BLOCK_VOCAB = 1024
+# blocks are bounded to fit the v5e's default scoped-VMEM limit (16 MiB)
+# with 1 MiB to spare; see vmem_bytes
+VMEM_BUDGET = 15 << 20
 
-__all__ = ["fused_lm_ce", "fused_ce_supported",
-           "DEFAULT_BLOCK_TOKENS", "DEFAULT_BLOCK_VOCAB"]
+__all__ = ["fused_lm_ce", "fused_ce_supported", "vmem_bytes",
+           "DEFAULT_BLOCK_TOKENS", "DEFAULT_BLOCK_VOCAB", "VMEM_BUDGET"]
 
 
 def _vocab_cols(j, shape, block_vocab):
@@ -320,15 +323,44 @@ def fused_ce_supported(min_tokens=128):
     return jax.default_backend() == "tpu"
 
 
-def _clamp_blocks(n, v, block_tokens, block_vocab):
-    """Shrink oversized blocks to the problem, keeping Mosaic tiling:
-    token blocks on the sublane quantum (8), vocab blocks on the lane
-    quantum (128). Padding rounds the problem UP to the block, so any
-    aligned block is legal — this only avoids gross over-padding."""
+def vmem_bytes(h, block_tokens, block_vocab, dtype):
+    """Upper estimate of the scoped VMEM the largest of the three kernels
+    asks Mosaic for: double-buffered input/output blocks, the fp32
+    accumulator, the fp32 copy of the weight tile the dh kernel keeps for
+    its two matmuls, one (block_tokens, block_vocab) fp32 logits tile and
+    the three lane-padded stat blocks. Fitted against the smallest
+    ``vmem_limit_bytes`` each kernel compiles under for v5e (bf16 and
+    fp32, h 128..4096): never below what the compiler needed, at most
+    ~30% above."""
+    it = jnp.dtype(dtype).itemsize
+    a, b, c = block_tokens * h, h * block_vocab, block_tokens * block_vocab
+    stats = 3 * 2 * block_tokens * LANES * 4
+    dh = (4 * it + 4) * a + (2 * it + 4) * b + 4 * c + stats
+    dw = 2 * it * a + (4 * it + 4) * b + 4 * c + stats
+    return max(dh, dw)
+
+
+def _clamp_blocks(n, v, h, dtype, block_tokens, block_vocab):
+    """Shrink oversized blocks to the problem and to VMEM, keeping Mosaic
+    tiling: token blocks on the sublane quantum (8), vocab blocks on the
+    lane quantum (128). Padding rounds the problem UP to the block, so
+    any aligned block is legal. The VMEM bound halves whichever block
+    carries the larger tile until :func:`vmem_bytes` fits
+    :data:`VMEM_BUDGET` — without it the dw kernel's (h, block_vocab)
+    tiles outgrow the scoped limit as the hidden width rises."""
     bt = max(8, min(int(block_tokens), int(-(-n // 8) * 8)))
     bt = (bt // 8) * 8
     bv = max(LANES, min(int(block_vocab), int(-(-v // LANES) * LANES)))
     bv = (bv // LANES) * LANES
+    while vmem_bytes(h, bt, bv, dtype) > VMEM_BUDGET:
+        if bv > LANES and bv >= bt:
+            bv = max(LANES, (bv // 2) // LANES * LANES)
+        elif bt > 8:
+            bt = max(8, (bt // 2) // 8 * 8)
+        else:
+            raise ValueError(
+                f"fused_lm_ce: hidden width {h} does not fit VMEM even at "
+                f"the smallest ({bt}, {bv}) blocks")
     return bt, bv
 
 
@@ -362,7 +394,7 @@ def fused_lm_ce(hidden, weight, labels, block_tokens=None,
              "block_vocab": DEFAULT_BLOCK_VOCAB})
         block_tokens = block_tokens or cfg["block_tokens"]
         block_vocab = block_vocab or cfg["block_vocab"]
-    bt, bv = _clamp_blocks(n, v, block_tokens, block_vocab)
+    bt, bv = _clamp_blocks(n, v, h, hid.dtype, block_tokens, block_vocab)
 
     if interpret is None:
         interpret = jax.default_backend() != "tpu"

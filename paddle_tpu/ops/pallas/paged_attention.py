@@ -88,7 +88,7 @@ def paged_decode_supported(q, k_pool, interpret: bool = False) -> bool:
     return ((interpret or jax.default_backend() == "tpu") and
             q.ndim == 4 and 1 <= q.shape[1] <= MAX_VERIFY_TQ and
             q.shape[-1] in (32, 64, 128, 256) and
-            k_pool.shape[1] % 8 == 0)
+            k_pool.shape[2] % 8 == 0)
 
 
 # ---------------------------------------------------------------------------
@@ -96,20 +96,20 @@ def paged_decode_supported(q, k_pool, interpret: bool = False) -> bool:
 # ---------------------------------------------------------------------------
 
 def _gather_ctx(pool, tables):
-    """(P, ps, H, D) pool + (B, n) tables → (B, n*ps, H, D) context."""
+    """(P, H, ps, D) pool + (B, n) tables → (B, H, n*ps, D) context."""
     b, n = tables.shape
-    p, ps, h, d = pool.shape
-    return pool[tables].reshape(b, n * ps, h, d)
+    p, h, ps, d = pool.shape
+    return jnp.swapaxes(pool[tables], 1, 2).reshape(b, h, n * ps, d)
 
 
 def _xla_paged_decode(q, k_pool, v_pool, tables, lens, k_new, v_new,
                       sm_scale):
     b, tq, h, d = q.shape
-    kc = _gather_ctx(k_pool, tables).astype(jnp.float32)   # (B, S, H, D)
+    kc = _gather_ctx(k_pool, tables).astype(jnp.float32)   # (B, H, S, D)
     vc = _gather_ctx(v_pool, tables).astype(jnp.float32)
-    s_len = kc.shape[1]
+    s_len = kc.shape[2]
     qf = q.astype(jnp.float32) * sm_scale
-    s_ctx = jnp.einsum("bqhd,bshd->bhqs", qf, kc)          # (B, H, Tq, S)
+    s_ctx = jnp.einsum("bqhd,bhsd->bhqs", qf, kc)          # (B, H, Tq, S)
     cols = jnp.arange(s_len, dtype=jnp.int32)
     ctx_mask = cols[None, None, None, :] < \
         lens.astype(jnp.int32)[:, None, None, None]
@@ -127,7 +127,7 @@ def _xla_paged_decode(q, k_pool, v_pool, tables, lens, k_new, v_new,
     p = jnp.exp(s - m) * (s > NEG_INF * 0.5)
     l = jnp.sum(p, axis=-1, keepdims=True)
     p = p / jnp.where(l == 0.0, 1.0, l)
-    out = jnp.einsum("bhqs,bshd->bqhd", p[..., :s_len], vc)
+    out = jnp.einsum("bhqs,bhsd->bqhd", p[..., :s_len], vc)
     if v_new is not None:
         out = out + jnp.einsum("bhqu,buhd->bqhd", p[..., s_len:],
                                v_new.astype(jnp.float32))
@@ -140,7 +140,7 @@ def _xla_paged_decode(q, k_pool, v_pool, tables, lens, k_new, v_new,
 
 def _paged_decode_kernel(tables_ref, lens_ref,      # scalar prefetch (SMEM)
                          q_ref,                     # (1, 1, q_pad, D)
-                         k_ref, v_ref,              # (1, ps, 1, D)
+                         k_ref, v_ref,              # (1, 1, ps, D)
                          o_ref,                     # (1, 1, q_pad, D) f32
                          m_ref, l_ref,              # (1, 1, q_pad, STAT)
                          m_scr, l_scr, acc_scr,     # VMEM running stats
@@ -158,7 +158,7 @@ def _paged_decode_kernel(tables_ref, lens_ref,      # scalar prefetch (SMEM)
     @pl.when(i * page_size < lens_ref[b])
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * sm_scale     # (q_pad, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)          # (ps, D)
+        k = k_ref[0, 0].astype(jnp.float32)                # (ps, D)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         cols = i * page_size + jax.lax.broadcasted_iota(
@@ -170,7 +170,7 @@ def _paged_decode_kernel(tables_ref, lens_ref,      # scalar prefetch (SMEM)
         p = p * (s > NEG_INF * 0.5)      # fully-masked rows stay at l == 0
         alpha = jnp.exp(m_prev - m_new)
         l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        v = v_ref[0, 0].astype(jnp.float32)
         pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         acc_scr[:] = acc_scr[:] * alpha + pv
@@ -202,7 +202,7 @@ def _run_paged_kernel(kernel_fn, qhp, k_pool, v_pool, tables, lens,
     """pallas_call plumbing shared by the decode and verify wrappers:
     qhp is (B, H, q_pad, D); returns unnormalized (acc, m, l)."""
     b, h, q_pad, d = qhp.shape
-    num_pool_pages, ps, _, _ = k_pool.shape
+    num_pool_pages, _, ps, _ = k_pool.shape
     npages = tables.shape[1]
     # masked-out table slots may hold sentinel ids: the index map fetches
     # even skipped pages, so clamp every slot into the pool
@@ -218,12 +218,14 @@ def _run_paged_kernel(kernel_fn, qhp, k_pool, v_pool, tables, lens,
         in_specs=[
             pl.BlockSpec((1, 1, q_pad, d),
                          lambda bi, hi, i, tables, lens: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, ps, 1, d),
+            # head-major pool: the block's last two dims are the array's
+            # (ps, D), which is what the TPU lowering requires of a block
+            pl.BlockSpec((1, 1, ps, d),
                          lambda bi, hi, i, tables, lens:
-                         (tables[bi, i], 0, hi, 0)),
-            pl.BlockSpec((1, ps, 1, d),
+                         (tables[bi, i], hi, 0, 0)),
+            pl.BlockSpec((1, 1, ps, d),
                          lambda bi, hi, i, tables, lens:
-                         (tables[bi, i], 0, hi, 0)),
+                         (tables[bi, i], hi, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, q_pad, d),
@@ -334,7 +336,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, context_lens,
     q: (B, Tq, H, D) new-token queries (Tq == 1 for pure decode;
     Tq = 1 + K for a speculative-verify chunk — every query attends the
     full cached context plus the chunk's earlier tokens causally).
-    k_pool/v_pool: (P, page_size, H, D) page pools.
+    k_pool/v_pool: (P, H, page_size, D) head-major page pools.
     block_tables: (B, n_pages) int32 page ids per row (padded slots may
     hold any value; only the first ceil(len/page_size) are read).
     context_lens: (B,) int32 valid cached tokens per row.
@@ -361,7 +363,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, context_lens,
         from .tuner import resolve
         if q_pad is None:
             cfg, _ = resolve("paged_attention", q.dtype,
-                             paged_dims(d, k_pool.shape[1],
+                             paged_dims(d, k_pool.shape[2],
                                         block_tables.shape[1], tq=tq),
                              {"q_pad": (DEFAULT_Q_PAD if tq == 1
                                         else verify_rows(tq))})
@@ -395,13 +397,13 @@ def paged_prefill_attention(q, k_new, v_new, row_id, positions, valid,
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
     row_id = row_id.astype(jnp.int32)
-    kc = _gather_ctx(k_pool, block_tables).astype(jnp.float32)  # (R,S,H,D)
+    kc = _gather_ctx(k_pool, block_tables).astype(jnp.float32)  # (R,H,S,D)
     vc = _gather_ctx(v_pool, block_tables).astype(jnp.float32)
-    s_len = kc.shape[1]
-    kct = jnp.take(kc, row_id, axis=0)                      # (T, S, H, D)
+    s_len = kc.shape[2]
+    kct = jnp.take(kc, row_id, axis=0)                      # (T, H, S, D)
     vct = jnp.take(vc, row_id, axis=0)
     qf = q.astype(jnp.float32) * sm_scale
-    s_ctx = jnp.einsum("thd,tshd->ths", qf, kct)            # (T, H, S)
+    s_ctx = jnp.einsum("thd,thsd->ths", qf, kct)            # (T, H, S)
     cols = jnp.arange(s_len, dtype=jnp.int32)
     ctx_len_t = jnp.take(context_lens.astype(jnp.int32), row_id)
     s_ctx = jnp.where(cols[None, None, :] < ctx_len_t[:, None, None],
@@ -418,7 +420,7 @@ def paged_prefill_attention(q, k_new, v_new, row_id, positions, valid,
     p = jnp.exp(s - m) * (s > NEG_INF * 0.5)
     l = jnp.sum(p, axis=-1, keepdims=True)
     p = p / jnp.where(l == 0.0, 1.0, l)
-    out = jnp.einsum("ths,tshd->thd", p[..., :s_len], vct) + \
+    out = jnp.einsum("ths,thsd->thd", p[..., :s_len], vct) + \
         jnp.einsum("thu,uhd->thd", p[..., s_len:],
                    v_new.astype(jnp.float32))
     return out.astype(q.dtype)

@@ -47,7 +47,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 __all__ = [
     "TuningDB", "default_db_path", "overlay_db_path", "get_db",
     "clear_cache", "shape_bucket", "device_kind", "make_key", "resolve",
-    "record_fallback", "tune", "flash_candidates", "ce_candidates",
+    "record_fallback", "entry_origin", "tune", "flash_candidates",
+    "ce_candidates",
     "paged_candidates", "entry_for_traced_call", "GENERIC_DEVICE",
 ]
 
@@ -144,7 +145,9 @@ class TuningDB:
     ``validated: "seed"`` marks a shipped config-only entry for a shape
     too large to interpret-validate on CPU (the TPU bench buckets): the
     blocks are legal for the shape but unmeasured — a device tuner run
-    (``--suite bench``) refreshes them in place.
+    (``--suite bench``) refreshes them in place. ``"device"`` with a null
+    ``mean_us`` is a config compared with its reference on the chip but
+    never timed (``chip_smoke.py``'s ``kernels`` phase).
     """
 
     def __init__(self, entries: Optional[Dict[str, dict]] = None,
@@ -242,16 +245,41 @@ def resolve(kernel: str, dtype, dims: Dict[str, int],
     (source "default"). Either way the outcome is counted in
     ``pallas_config_resolved_total{kernel, source}``.
     """
-    db = get_db()
-    for dev in (device_kind(), GENERIC_DEVICE):
-        entry = db.lookup(make_key(kernel, dev, dtype, dims))
-        if entry and isinstance(entry.get("config"), dict):
-            _count(kernel, "db")
-            cfg = dict(defaults)
-            cfg.update({k: int(v) for k, v in entry["config"].items()})
-            return cfg, "db"
+    _, entry = _find(kernel, dtype, dims)
+    if entry is not None:
+        _count(kernel, "db")
+        cfg = dict(defaults)
+        cfg.update({k: int(v) for k, v in entry["config"].items()})
+        return cfg, "db"
     _count(kernel, "default")
     return dict(defaults), "default"
+
+
+def _find(kernel: str, dtype, dims: Dict[str, int]):
+    """(key, entry) of the merged-DB row serving this call — the exact
+    device kind first, then :data:`GENERIC_DEVICE` — or (None, None)."""
+    db = get_db()
+    for dev in (device_kind(), GENERIC_DEVICE):
+        key = make_key(kernel, dev, dtype, dims)
+        entry = db.lookup(key)
+        if entry and isinstance(entry.get("config"), dict):
+            return key, entry
+    return None, None
+
+
+def entry_origin(kernel: str, dtype, dims: Dict[str, int]) -> \
+        Tuple[Optional[str], Optional[str]]:
+    """(key, DB file) of the entry :func:`resolve` picks for this call,
+    (None, None) when it takes the kernel's compiled-in defaults. Lets a
+    run say whether a config came from the checkout's seed DB or from an
+    overlay outside it."""
+    key, _ = _find(kernel, dtype, dims)
+    if key is None:
+        return None, None
+    # overlay entries win per key, so the overlay holding the key owns it
+    overlay = overlay_db_path()
+    return key, (overlay if TuningDB.load(overlay).lookup(key)
+                 else default_db_path())
 
 
 def record_fallback(kernel: str):
@@ -320,7 +348,7 @@ def entry_for_traced_call(kernel_name: str, avals: List, grid) -> \
             {"h": int(h), "v": int(vpad), "t": tb}), None
     if kernel_name in ("_paged_decode_kernel", "_paged_verify_kernel"):
         # paged decode/verify attention: invars (tables, lens, q, k_pool,
-        # v_pool) with q (B, H, rows, D) and k_pool (P, page_size, H, D).
+        # v_pool) with q (B, H, rows, D) and k_pool (P, H, page_size, D).
         # For the verify kernel the traced row count IS the bucketed
         # speculative chunk width, so it keys the ``sq`` dim directly.
         if len(avals) < 4:
@@ -328,7 +356,7 @@ def entry_for_traced_call(kernel_name: str, avals: List, grid) -> \
         tables, q, kpool = avals[0], avals[2], avals[3]
         from .paged_attention import paged_dims
         tq = q.shape[2] if kernel_name == "_paged_verify_kernel" else 1
-        dims = paged_dims(q.shape[-1], kpool.shape[1], tables.shape[1],
+        dims = paged_dims(q.shape[-1], kpool.shape[2], tables.shape[1],
                           tq=tq)
         for dev in (device_kind(), GENERIC_DEVICE):
             key = make_key("paged_attention", dev, q.dtype, dims)
@@ -359,14 +387,19 @@ def flash_candidates(sq: int, sk: int) -> List[Dict[str, int]]:
     return out or [{"block_q": min(sq, 128), "block_k": min(sk, 128)}]
 
 
-def ce_candidates(tokens: int, vocab: int) -> List[Dict[str, int]]:
-    """(block_tokens, block_vocab) grid for the fused CE kernel."""
+def ce_candidates(tokens: int, vocab: int, hidden: int,
+                  dtype) -> List[Dict[str, int]]:
+    """(block_tokens, block_vocab) grid for the fused CE kernel, less
+    the blocks the kernel's VMEM bound would shrink (a candidate must
+    run as the config it is recorded under)."""
+    from .fused_ce import VMEM_BUDGET, vmem_bytes
     out = []
     for bt in (128, 256, 512):
         if bt > tokens or tokens % bt:
             continue
         for bv in (512, 1024, 2048, 4096):
-            if bv > max(vocab, 512):
+            if bv > max(vocab, 512) or \
+                    vmem_bytes(hidden, bt, bv, dtype) > VMEM_BUDGET:
                 continue
             out.append({"block_tokens": bt, "block_vocab": bv})
     return out or [{"block_tokens": min(tokens, 128),
@@ -545,8 +578,8 @@ def _paged_case_arrays(b, h, d, ps, pages, dtype, tq=1):
 
     rs = np.random.RandomState(0)
     q = jnp.asarray(rs.randn(b, tq, h, d), dtype)
-    kp = jnp.asarray(rs.randn(pages, ps, h, d), dtype)
-    vp = jnp.asarray(rs.randn(pages, ps, h, d), dtype)
+    kp = jnp.asarray(rs.randn(pages, h, ps, d), dtype)
+    vp = jnp.asarray(rs.randn(pages, h, ps, d), dtype)
     # shuffled tables + ragged lens exercise the gather and masking
     tables = jnp.asarray(
         np.stack([rs.permutation(pages) for _ in range(b)]), jnp.int32)
@@ -642,7 +675,7 @@ def tune_case(kernel: str, case: Dict[str, int], dtype,
         hdim, v = case["h"], case["v"]
         tokens = shape_bucket(case["t"], floor=128)
         dims = ce_dims(hdim, v, tokens)
-        cands = ce_candidates(tokens, v)
+        cands = ce_candidates(tokens, v, hdim, dtype)
         validate = lambda c: _validate_ce(c, tokens, hdim, v, dtype,  # noqa: E731
                                           interpret)
         timeit = lambda c: _time_ce(c, tokens, hdim, v, dtype,  # noqa: E731

@@ -231,7 +231,8 @@ __all__ = [
     "calibration",
     "enable", "disable", "enabled", "is_enabled",
     "get_registry", "counter", "gauge", "histogram",
-    "prometheus_text", "emit", "peak_flops_per_sec",
+    "prometheus_text", "emit", "peak_flops_per_sec", "published_peak",
+    "PUBLISHED_PEAKS",
 ]
 
 _enabled = False
@@ -302,14 +303,38 @@ from . import slo  # noqa: E402,F401
 from . import tracing  # noqa: E402,F401
 
 
+# Published per-chip peaks keyed by ``jax.devices()[0].device_kind`` — the
+# one table every utilization in the repo divides by. A TPU that is not in
+# it is an error, not a default. Source: Google Cloud documentation,
+# "TPU v5e" (the v5e reports itself as "TPU v5 lite").
+PUBLISHED_PEAKS = {
+    "TPU v5 lite": {"bf16_flops_per_sec": 197e12,
+                    "hbm_bytes_per_sec": 819e9,
+                    "hbm_bytes": 16e9},
+}
+
+
+def published_peak(device_kind: str) -> dict:
+    """The :data:`PUBLISHED_PEAKS` row of ``device_kind``; raises for a
+    device nobody has entered a published figure for."""
+    try:
+        return PUBLISHED_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peak for device kind {device_kind!r}; add its "
+            f"row (with source) to telemetry.PUBLISHED_PEAKS — known: "
+            f"{sorted(PUBLISHED_PEAKS)}") from None
+
+
 def peak_flops_per_sec() -> float:
     """Hardware peak used as the MFU denominator.
 
     Precedence: ``PADDLE_TPU_PEAK_FLOPS`` env (e.g. per-chip bf16 peak
     of the actual slice) > the calibration DB's fitted effective peak
     (``telemetry.calibration``, written by ``bench_collectives --suite
-    calibrate``) > the v5e bf16 peak on TPU and a nominal 1 TFLOP/s
-    elsewhere so MFU stays a positive, comparable-within-a-run number on
+    calibrate``) > on TPU the published bf16 peak of the device kind
+    (:func:`published_peak`; an unknown kind raises), and a nominal
+    1 TFLOP/s elsewhere so the cost model has a rate to plan with on
     CPU test meshes.
     """
     env = os.environ.get("PADDLE_TPU_PEAK_FLOPS")
@@ -318,9 +343,8 @@ def peak_flops_per_sec() -> float:
     fitted = calibration.peak_flops_override()
     if fitted is not None:
         return fitted
-    try:
-        import jax
-        backend = jax.default_backend()
-    except Exception:  # pragma: no cover - jax always present in-repo
-        backend = "cpu"
-    return 197e12 if backend == "tpu" else 1e12
+    import jax
+    if jax.default_backend() != "tpu":
+        return 1e12
+    return published_peak(
+        jax.devices()[0].device_kind)["bf16_flops_per_sec"]

@@ -158,8 +158,7 @@ class TestCost:
 
 class TestRules:
     def test_fp64_leak_fires_once(self):
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64():
             cj = jax.make_jaxpr(
                 lambda x: x.astype(jnp.float64))(jnp.zeros(4, jnp.float32))
         rep = analyze_jaxpr(cj)

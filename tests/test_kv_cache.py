@@ -22,7 +22,7 @@ def kv_for(tokens, heads=2, dim=4, layers=1):
 
 
 def fill(cache, seq, tokens):
-    k, v = kv_for(tokens, cache.k.shape[3], cache.k.shape[4],
+    k, v = kv_for(tokens, cache.k.shape[2], cache.k.shape[4],
                   cache.num_layers)
     cache.append(seq, tokens, k, v)
 
@@ -121,12 +121,12 @@ def test_cow_fork_on_write_to_shared_tail():
     assert a.pages[-1] != tail and b.pages[-1] == tail
     assert c.ref[tail] == 1 and c.ref[a.pages[-1]] == 1
     # the copied prefix of the tail (tokens 5, 6) rode along
-    np.testing.assert_array_equal(c.k[0, a.pages[-1], :2],
-                                  c.k[0, tail, :2])
+    np.testing.assert_array_equal(c.k[0, a.pages[-1], :, :2],
+                                  c.k[0, tail, :, :2])
     # and b's view is untouched by a's divergence
     fill(c, b, [8])
-    assert float(c.k[0, a.pages[-1], 2, 0, 0]) == 7.0
-    assert float(c.k[0, b.pages[-1], 2, 0, 0]) == 8.0
+    assert float(c.k[0, a.pages[-1], 0, 2, 0]) == 7.0
+    assert float(c.k[0, b.pages[-1], 0, 2, 0]) == 8.0
     assert a.length == b.length == 7
 
 

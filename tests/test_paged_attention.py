@@ -35,6 +35,12 @@ def case(b=4, h=2, d=32, ps=8, pool_pages=12, width=6, seed=0,
     return q, kp, vp, tables, lens, kn, vn
 
 
+def head_major(x):
+    """The token-major (P, ps, H, D) pool the float64 references read,
+    presented in the API's head-major (P, H, ps, D) layout."""
+    return jnp.asarray(np.swapaxes(np.asarray(x), 1, 2))
+
+
 def dense_decode_ref(q, kp, vp, tables, lens, kn, vn):
     """float64 contiguous-KV attention: gather each row's pages into a
     dense sequence, append the new token, plain softmax."""
@@ -68,8 +74,8 @@ def test_decode_matches_dense_reference(kernel, dtype):
     ref = dense_decode_ref(q, kp, vp, tables, lens, kn, vn)
     cast = lambda a: jnp.asarray(a, dtype)  # noqa: E731
     got = paged_decode_attention(
-        cast(q), cast(kp), cast(vp), jnp.asarray(tables),
-        jnp.asarray(lens), k_new=cast(kn), v_new=cast(vn),
+        cast(q), cast(head_major(kp)), cast(head_major(vp)),
+        jnp.asarray(tables), jnp.asarray(lens), k_new=cast(kn), v_new=cast(vn),
         kernel=kernel, interpret=True)
     assert got.shape == q.shape and got.dtype == jnp.dtype(dtype)
     err = np.max(np.abs(np.asarray(got, np.float64) - ref))
@@ -84,7 +90,7 @@ def test_decode_edge_lens_and_qpad(kernel, q_pad):
     q, kp, vp, tables, lens, kn, vn = case(lens=[0, 8, 3, 48])
     ref = dense_decode_ref(q, kp, vp, tables, lens, kn, vn)
     got = paged_decode_attention(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(q), head_major(kp), head_major(vp),
         jnp.asarray(tables), jnp.asarray(lens), k_new=jnp.asarray(kn),
         v_new=jnp.asarray(vn), kernel=kernel, q_pad=q_pad,
         interpret=True)
@@ -100,7 +106,7 @@ def test_decode_without_new_token_xla():
                                          lens=[5, 0, 16, 30])
     ref = dense_decode_ref(q, kp, vp, tables, lens, None, None)
     got = paged_decode_attention(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(q), head_major(kp), head_major(vp),
         jnp.asarray(tables), jnp.asarray(lens), kernel="xla")
     err = np.max(np.abs(np.asarray(got, np.float64) - ref))
     assert err < TOL[np.float32]
@@ -110,19 +116,19 @@ def test_decode_without_new_token_xla():
 
 def test_pallas_gate_and_dispatch():
     q, kp, vp, tables, lens, kn, vn = case(d=32)
-    assert paged_decode_supported(jnp.asarray(q), jnp.asarray(kp),
+    assert paged_decode_supported(jnp.asarray(q), head_major(kp),
                                   interpret=True)
     # unsupported head_dim: explicit pallas raises, auto falls back
     qb, kpb, vpb, tb, lb, knb, vnb = case(d=48, seed=1)
-    assert not paged_decode_supported(jnp.asarray(qb), jnp.asarray(kpb),
+    assert not paged_decode_supported(jnp.asarray(qb), head_major(kpb),
                                       interpret=True)
     with pytest.raises(ValueError):
         paged_decode_attention(
-            jnp.asarray(qb), jnp.asarray(kpb), jnp.asarray(vpb),
+            jnp.asarray(qb), head_major(kpb), head_major(vpb),
             jnp.asarray(tb), jnp.asarray(lb), k_new=jnp.asarray(knb),
             v_new=jnp.asarray(vnb), kernel="pallas", interpret=True)
     got = paged_decode_attention(
-        jnp.asarray(qb), jnp.asarray(kpb), jnp.asarray(vpb),
+        jnp.asarray(qb), head_major(kpb), head_major(vpb),
         jnp.asarray(tb), jnp.asarray(lb), k_new=jnp.asarray(knb),
         v_new=jnp.asarray(vnb), kernel="auto", interpret=True)
     ref = dense_decode_ref(qb, kpb, vpb, tb, lb, knb, vnb)
@@ -196,7 +202,7 @@ def test_prefill_matches_dense_reference():
     got = paged_prefill_attention(
         jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
         jnp.asarray(row_id), jnp.asarray(positions), jnp.asarray(valid),
-        jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tables),
+        head_major(kp), head_major(vp), jnp.asarray(tables),
         jnp.asarray(ctx_lens))
     got = np.asarray(got, np.float64)
     err = np.max(np.abs(got[valid.astype(bool)]
@@ -245,7 +251,7 @@ def test_verify_multi_query_matches_dense_reference(kernel, tq):
     vn = rs.randn(b, tq, h, d).astype(np.float32)
     ref = dense_verify_ref(q, kp, vp, tables, lens, kn, vn)
     got = paged_decode_attention(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(q), head_major(kp), head_major(vp),
         jnp.asarray(tables), jnp.asarray(lens), k_new=jnp.asarray(kn),
         v_new=jnp.asarray(vn), kernel=kernel, interpret=True)
     assert got.shape == q.shape

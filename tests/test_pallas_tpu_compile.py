@@ -1,0 +1,87 @@
+"""The three Pallas kernels must COMPILE for a TPU v5e at the shapes
+``chip_smoke.py`` runs — checked here without a chip.
+
+Interpret mode (every other kernel test) never meets the TPU lowering's
+block-shape rule or Mosaic's scoped-VMEM limit; both broke kernels that
+were green on CPU. ``jax.experimental.topologies`` builds a v5e device
+description from the installed libtpu, and jit's AOT path compiles
+against it: nothing executes, so this says "lowers and fits", never
+"is right" — numerics on the chip are ``chip_smoke.py``'s ``kernels``
+phase.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    try:
+        from jax.experimental import topologies
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu in this installation
+        pytest.skip(f"cannot build a v5e topology: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+            for s, d in shapes]
+    # conftest pins "highest" matmul precision for CPU numerics; the chip
+    # runs the default, and Mosaic's multi-pass fp32 matmul needs VMEM the
+    # block bounds are not sized for
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(fn).trace(*args).lower(
+            lowering_platforms=("tpu",)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_attention_fwd_bwd_gpt_base(v5e):
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+
+    def f(q, k, v):
+        return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True).astype(jnp.float32) ** 2),
+            argnums=(0, 1, 2))(q, k, v)
+
+    qkv = ((8, 1024, 12, 64), jnp.bfloat16)
+    _compile(f, v5e, qkv, qkv, qkv)
+
+
+@pytest.mark.parametrize("h,dtype", [(768, jnp.bfloat16),
+                                     (768, jnp.float32),
+                                     (2048, jnp.bfloat16)])
+def test_fused_ce_fwd_bwd_fits_vmem(v5e, h, dtype):
+    """h768 bf16 is the bench shape (its DB row asked for 18 MB of the
+    16 MB scoped VMEM before the bound); fp32 and h2048 take the module
+    defaults, which did not fit either."""
+    from paddle_tpu.ops.pallas.fused_ce import fused_lm_ce
+
+    def f(hid, w, lbl):
+        return jax.value_and_grad(
+            lambda a, b: fused_lm_ce(a, b, lbl, interpret=False),
+            argnums=(0, 1))(hid, w)
+
+    _compile(f, v5e, ((8192, h), dtype), ((h, 50304), dtype),
+             ((8192,), jnp.int32))
+
+
+@pytest.mark.parametrize("tq", [1, 5])
+def test_paged_attention_decode_and_verify(v5e, tq):
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    b, h, d, ps, pages, width = 4, 12, 64, 16, 64, 8
+    impl = pa._pallas_paged_decode if tq == 1 else pa._pallas_paged_verify
+
+    def f(q, kp, vp, tables, lens, kn, vn):
+        # the wrapper, not the dispatcher: paged_decode_supported gates
+        # on the RUNNING backend, which is the CPU here
+        return impl(q, kp, vp, tables, lens, kn, vn, d ** -0.5,
+                    max(16, pa.verify_rows(tq)), False)
+
+    tok = ((b, tq, h, d), jnp.bfloat16)
+    pool = ((pages, h, ps, d), jnp.bfloat16)
+    _compile(f, v5e, tok, pool, pool, ((b, width), jnp.int32),
+             ((b,), jnp.int32), tok, tok)

@@ -246,7 +246,7 @@ class TestAnalysisRule:
             paged_decode_attention
         rs = np.random.RandomState(3)
         q = jnp.asarray(rs.randn(2, 1, 2, d), jnp.float32)
-        kp = jnp.asarray(rs.randn(pool, ps, 2, d), jnp.float32)
+        kp = jnp.asarray(rs.randn(pool, 2, ps, d), jnp.float32)
         tb = jnp.zeros((2, pages), jnp.int32)
         ln = jnp.asarray([ps, 2 * ps], jnp.int32)
         return jax.make_jaxpr(

@@ -251,6 +251,8 @@ def main():
                     help="tiny shapes + few iters (CI plumbing check; "
                          "CPU-safe)")
     args = ap.parse_args()
+    from tools._mesh_setup import use_compile_cache
+    use_compile_cache()
     if args.op in (None, "suite"):
         if args.suite == "pallas":
             pallas_suite(args.dtype, iters=args.iters, smoke=args.smoke,
