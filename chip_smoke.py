@@ -164,7 +164,8 @@ class CompileCounter:
 
 
 def _pallas_kernels(closed_jaxpr):
-    """{kernel function name: count} over every pallas_call of a jaxpr."""
+    """{kernel name: count} over every pallas_call of a jaxpr: the call's
+    ``name=`` where it has one, else its kernel function's name."""
     from collections import Counter
 
     from paddle_tpu.analysis import walker
@@ -173,6 +174,16 @@ def _pallas_kernels(closed_jaxpr):
         walker.pallas_kernel_name(site.eqn)
         for site in walker.walk(closed_jaxpr)
         if site.primitive == "pallas_call"))
+
+
+def _check_flash_calls(kernels, layers):
+    """Every layer's attention staged the three flash kernels."""
+    from paddle_tpu.ops.pallas.flash_attention import KERNEL_NAMES
+
+    n_flash = sum(kernels.get(k, 0) for k in KERNEL_NAMES)
+    check(n_flash == len(KERNEL_NAMES) * layers,
+          f"staged step holds {kernels}, expected "
+          f"{len(KERNEL_NAMES) * layers} flash-attention Pallas calls")
 
 
 def _config_origins(run, entries):
@@ -263,11 +274,7 @@ def phase_train(run: Run):
           f"{compiled_late} compilations after step 2")
     if not run.rehearsal:
         # off the TPU the gate routes attention to XLA by design
-        n_flash = sum(kernels.get(k, 0) for k in (
-            "_fwd_kernel", "_bwd_dq_kernel", "_bwd_dkv_kernel"))
-        check(n_flash == 3 * cfg["layers"],
-              f"staged step holds {kernels}, expected "
-              f"{3 * cfg['layers']} flash-attention Pallas calls")
+        _check_flash_calls(kernels, cfg["layers"])
         check(flash_fallbacks == 0,
               f"flash attention fell back {flash_fallbacks} times")
         check(out["peak_bytes_in_use"], "backend reports no memory stats")

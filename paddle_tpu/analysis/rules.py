@@ -578,6 +578,7 @@ def pallas_config_untuned(ctx):
     perf loss the autotuner (ops/pallas/tuner.py) exists to close. Run
     ``python -m paddle_tpu.ops.pallas.tuner`` on the target device (or
     ship a generic interpret-validated entry) to clear it."""
+    from ..ops.pallas.flash_attention import FWD as flash_fwd
     from ..ops.pallas.tuner import entry_for_traced_call
     seen = set()
     for site in ctx.sites:
@@ -586,7 +587,7 @@ def pallas_config_untuned(ctx):
         kernel_name = pallas_kernel_name(site.eqn)
         # forward kernels only: the paired backward kernels of the same
         # call would re-report the identical missing entry
-        if kernel_name not in ("_fwd_kernel", "_ce_fwd_kernel",
+        if kernel_name not in (flash_fwd, "_ce_fwd_kernel",
                                "_paged_decode_kernel"):
             continue
         grid = getattr(site.eqn.params.get("grid_mapping"), "grid", ())

@@ -459,7 +459,11 @@ class ParallelTrainer:
                             m, pp_, b, xx, rng=rng)[0])
                     return f(p, *a), b
             out, _ = fwd(model, params, buffers, inputs, rng=key)
-            return loss_fn(out, labels)
+            # Trace scope: the engine is the one place that sees every
+            # loss path (a callable, a Layer, a model that returns its
+            # own loss). Read by lm_head_loss_ms_per_step.
+            with jax.named_scope("loss"):
+                return loss_fn(out, labels)
 
         zero3_dims = self.zero3_dims
         zero2_dims = self.zero2_dims
@@ -659,6 +663,7 @@ class ParallelTrainer:
                     def h_fwd(sub, res):
                         return sub, res
 
+                    @jax.named_scope("grad_exchange")
                     def h_bwd(res, g):
                         g = dict(g)
                         if use_amp:
@@ -719,92 +724,98 @@ class ParallelTrainer:
                     grads = {k: (g if k in bucketed
                                  else g * inv.astype(g.dtype))
                              for k, g in grads.items()}
-            # DP grad averaging over the data axes; 'model'/'pipe' grads
-            # are handled by shard_map transposition of the collectives.
-            # Pipe-replicated grads are psum'd FIRST: psum/pmean commute
-            # for the exact policies, and the int8 path must quantize the
-            # full (pipe-summed) grad so every stage computes the same
-            # residual — otherwise the pipe-replicated comm_err state
-            # would silently diverge across stages.
-            for k in pipe_psum_keys:
-                if k not in bucketed:
-                    grads[k] = lax.psum(grads[k], "pipe")
-            new_comm_err = dict(comm_err)
+            # Trace scope: everything between the backward pass and the
+            # optimizer (casts, bucket packing, the collectives,
+            # unpacking). The bucketed hook's h_bwd opens the same scope
+            # inside the backward pass. Read by the benchmark's
+            # grad_exchange_ms_per_step.
+            with jax.named_scope("grad_exchange"):
+                # DP grad averaging over the data axes; 'model'/'pipe' grads
+                # are handled by shard_map transposition of the collectives.
+                # Pipe-replicated grads are psum'd FIRST: psum/pmean commute
+                # for the exact policies, and the int8 path must quantize the
+                # full (pipe-summed) grad so every stage computes the same
+                # residual — otherwise the pipe-replicated comm_err state
+                # would silently diverge across stages.
+                for k in pipe_psum_keys:
+                    if k not in bucketed:
+                        grads[k] = lax.psum(grads[k], "pipe")
+                new_comm_err = dict(comm_err)
 
-            # ZeRO-2/3 leaves keep per-tensor handling: they LEAVE the
-            # exchange sharded over "sharding" (reduce-scatter), which the
-            # flat bucketed path cannot express.
-            def _pmean(g, ax):
-                # fp16_allreduce: fp32 grads cross the wire as bf16
-                if self.fp16_allreduce and g.dtype == jnp.float32:
-                    return lax.pmean(g.astype(jnp.bfloat16),
-                                     ax).astype(jnp.float32)
-                return lax.pmean(g, ax)
+                # ZeRO-2/3 leaves keep per-tensor handling: they LEAVE the
+                # exchange sharded over "sharding" (reduce-scatter), which the
+                # flat bucketed path cannot express.
+                def _pmean(g, ax):
+                    # fp16_allreduce: fp32 grads cross the wire as bf16
+                    if self.fp16_allreduce and g.dtype == jnp.float32:
+                        return lax.pmean(g.astype(jnp.bfloat16),
+                                         ax).astype(jnp.float32)
+                    return lax.pmean(g, ax)
 
-            for k in grads:
-                if k in zero3_dims:
-                    # ZeRO-3 grads already carry the SUM over the sharding
-                    # axis (all_gather transpose = reduce-scatter): divide
-                    # for the mean, pmean over the remaining data axes
-                    if pp_grads is not None:
-                        # manual grads are wrt the GATHERED param: explicit
-                        # reduce-scatter (mean) back onto the storage
-                        # shard, threading the leaf's EF residual when the
-                        # sharding hop quantizes
+                for k in grads:
+                    if k in zero3_dims:
+                        # ZeRO-3 grads already carry the SUM over the sharding
+                        # axis (all_gather transpose = reduce-scatter): divide
+                        # for the mean, pmean over the remaining data axes
+                        if pp_grads is not None:
+                            # manual grads are wrt the GATHERED param: explicit
+                            # reduce-scatter (mean) back onto the storage
+                            # shard, threading the leaf's EF residual when the
+                            # sharding hop quantizes
+                            if k in comm_err:
+                                grads[k], r = _reduce_scatter(
+                                    grads[k], zero3_dims[k], comm_err[k][0])
+                                new_comm_err[k] = r[None]
+                            else:
+                                grads[k] = _reduce_scatter(
+                                    grads[k], zero3_dims[k])
+                        else:
+                            grads[k] = grads[k] / n_shard
+                        for ax in ("data", "sep"):
+                            if ax in reduce_axes and mesh.shape.get(ax, 1) > 1:
+                                grads[k] = _pmean(grads[k], ax)
+                    elif k in zero2_dims:
+                        # reduce-scatter (mean) over sharding; pmean over data
                         if k in comm_err:
                             grads[k], r = _reduce_scatter(
-                                grads[k], zero3_dims[k], comm_err[k][0])
+                                grads[k], zero2_dims[k], comm_err[k][0])
                             new_comm_err[k] = r[None]
                         else:
-                            grads[k] = _reduce_scatter(
-                                grads[k], zero3_dims[k])
-                    else:
-                        grads[k] = grads[k] / n_shard
-                    for ax in ("data", "sep"):
-                        if ax in reduce_axes and mesh.shape.get(ax, 1) > 1:
-                            grads[k] = _pmean(grads[k], ax)
-                elif k in zero2_dims:
-                    # reduce-scatter (mean) over sharding; pmean over data
-                    if k in comm_err:
-                        grads[k], r = _reduce_scatter(
-                            grads[k], zero2_dims[k], comm_err[k][0])
-                        new_comm_err[k] = r[None]
-                    else:
-                        grads[k] = _reduce_scatter(grads[k], zero2_dims[k])
-                    for ax in ("data", "sep"):
-                        if ax in reduce_axes and mesh.shape.get(ax, 1) > 1:
-                            grads[k] = _pmean(grads[k], ax)
+                            grads[k] = _reduce_scatter(grads[k], zero2_dims[k])
+                        for ax in ("data", "sep"):
+                            if ax in reduce_axes and mesh.shape.get(ax, 1) > 1:
+                                grads[k] = _pmean(grads[k], ax)
 
-            if use_buckets:
-                # the per-bucket exchanges already ran inside the
-                # backward; fold each bucket's new residual (the
-                # cotangent of its residual input) back into the
-                # replica-major comm_err state
-                for r in gres:
-                    for k, v in r.items():
-                        new_comm_err[k] = v[None]
+                if use_buckets:
+                    # the per-bucket exchanges already ran inside the
+                    # backward; fold each bucket's new residual (the
+                    # cotangent of its residual input) back into the
+                    # replica-major comm_err state
+                    for r in gres:
+                        for k, v in r.items():
+                            new_comm_err[k] = v[None]
+                    return loss, grads, new_comm_err
+
+                # plain leaves, monolithic (K=1) mode: ONE bucketed flat
+                # exchange (compressed.py) over the data axes instead of one
+                # pmean per tensor — the Reducer bucketing, plus bf16/int8
+                # wire compression per self.grad_sync. comm_err is the int8
+                # error-feedback state, replica-major outside the step; its
+                # local view here is (1, *shape).
+                plain = {k: grads[k] for k in grads
+                         if k not in zero3_dims and k not in zero2_dims}
+                if plain and sync_axes:
+                    res = ({k: comm_err[k][0] for k in plain
+                            if k in comm_err} or None)
+                    mean, res = compressed_tree_mean(
+                        plain, sync_axes, policy=self._axis_policy,
+                        block=self.grad_sync_block,
+                        bucket_bytes=self.grad_sync_bucket_bytes,
+                        residuals=res)
+                    grads.update(mean)
+                    if res:
+                        new_comm_err.update({k: res[k][None] for k in res})
                 return loss, grads, new_comm_err
-
-            # plain leaves, monolithic (K=1) mode: ONE bucketed flat
-            # exchange (compressed.py) over the data axes instead of one
-            # pmean per tensor — the Reducer bucketing, plus bf16/int8
-            # wire compression per self.grad_sync. comm_err is the int8
-            # error-feedback state, replica-major outside the step; its
-            # local view here is (1, *shape).
-            plain = {k: grads[k] for k in grads
-                     if k not in zero3_dims and k not in zero2_dims}
-            if plain and sync_axes:
-                res = ({k: comm_err[k][0] for k in plain
-                        if k in comm_err} or None)
-                mean, res = compressed_tree_mean(
-                    plain, sync_axes, policy=self._axis_policy,
-                    block=self.grad_sync_block,
-                    bucket_bytes=self.grad_sync_bucket_bytes,
-                    residuals=res)
-                grads.update(mean)
-                if res:
-                    new_comm_err.update({k: res[k][None] for k in res})
-            return loss, grads, new_comm_err
 
         def _grad_spec(k):
             if k in zero2_dims:
@@ -916,33 +927,38 @@ class ParallelTrainer:
                         taint, grads[k0].dtype)
                 tparams = {k: v for k, v in params.items()
                            if self.trainable[k]}
-                new_t, new_opt = opt.apply_gradients(tparams, grads,
-                                                     opt_state, lr=lr)
-                new_params = dict(params)
-                new_params.update(new_t)
-                # keep optimizer slots on their ZeRO shardings
-                new_opt = jax.tree_util.tree_map(
-                    lambda v, s: lax.with_sharding_constraint(
-                        v, NamedSharding(mesh, s)),
-                    new_opt, self.opt_specs)
-                new_guard = dict(guard)
-                if nan_guard or use_amp:
-                    # ONE fused reduction, fully in-graph: no host sync,
-                    # and the same flag serves the loss-scale policy
-                    finite = all_finite(grads)
-                    if nan_guard:
-                        def keep(new, old):
-                            return jax.tree_util.tree_map(
-                                lambda n, o: jnp.where(finite, n, o),
-                                new, old)
-                        new_params = keep(new_params, dict(params))
-                        new_opt = keep(new_opt, opt_state)
-                        comm_err = keep(comm_err, comm_err0)
-                        new_guard["skipped"] = guard["skipped"] + \
-                            (~finite).astype(jnp.int32)
-                    if use_amp:
-                        new_guard["amp"] = scaler.update_scale_state(
-                            guard["amp"], ~finite)
+                # Trace scope: the optimizer's update and the guard's
+                # select between new and old state, which XLA fuses into
+                # one op per leaf, so the device cannot tell them apart.
+                # Read by the benchmark's update_ms_per_step.
+                with jax.named_scope("update"):
+                    new_t, new_opt = opt.apply_gradients(tparams, grads,
+                                                         opt_state, lr=lr)
+                    new_params = dict(params)
+                    new_params.update(new_t)
+                    # keep optimizer slots on their ZeRO shardings
+                    new_opt = jax.tree_util.tree_map(
+                        lambda v, s: lax.with_sharding_constraint(
+                            v, NamedSharding(mesh, s)),
+                        new_opt, self.opt_specs)
+                    new_guard = dict(guard)
+                    if nan_guard or use_amp:
+                        # ONE fused reduction, fully in-graph: no host sync,
+                        # and the same flag serves the loss-scale policy
+                        finite = all_finite(grads)
+                        if nan_guard:
+                            def keep(new, old):
+                                return jax.tree_util.tree_map(
+                                    lambda n, o: jnp.where(finite, n, o),
+                                    new, old)
+                            new_params = keep(new_params, dict(params))
+                            new_opt = keep(new_opt, opt_state)
+                            comm_err = keep(comm_err, comm_err0)
+                            new_guard["skipped"] = guard["skipped"] + \
+                                (~finite).astype(jnp.int32)
+                        if use_amp:
+                            new_guard["amp"] = scaler.update_scale_state(
+                                guard["amp"], ~finite)
                 # integrity fingerprints of the FINAL (possibly
                 # guard-reverted) state — exactly what a checkpoint at
                 # this step would persist. None on the plain program:
@@ -1261,37 +1277,45 @@ class ParallelTrainer:
         a scalar multiplied into one gradient leaf inside the step (NaN
         poisons the step; the in-graph guard must then skip the update).
         Normal callers leave it None."""
-        key = get_rng_key()
-        lr = self.optimizer.get_lr() if lr is None else lr
-        leaves = jax.tree_util.tree_leaves(inputs)
-        batch0 = jnp.shape(leaves[0])[0] if leaves and \
-            len(jnp.shape(leaves[0])) else None
-        if self.accumulate_steps > 1 and batch0 is not None and \
-                batch0 % self.accumulate_steps != 0:
-            raise ValueError(
-                f"batch size {batch0} is not divisible by "
-                f"accumulate_steps={self.accumulate_steps}")
-        # inputs/labels may be arbitrary pytrees (e.g. (mlm, nsp) labels)
-        tel = _telemetry.enabled()
-        t_start = time.perf_counter() if tel else 0.0
-        # integrity cadence: host-side choice between the two cached
-        # programs (no recompile, no in-graph branch on the step count)
-        self._steps_run += 1
-        ce = self.integrity_check_every
-        do_check = bool(ce) and self._steps_run % ce == 0
-        inputs, labels, step = self._stage(inputs, labels,
-                                           do_check=do_check)
+        # Two host spans on the jax profiler's clock, always on (creating
+        # one checks an atomic and does nothing else while no trace runs):
+        # "stage" is the rng key, the lr, the batch going to the device and
+        # the program lookup; "launch" the call of the staged step until it
+        # returns. Neither blocks. Read by the benchmark's trainer_stage_ms,
+        # trainer_launch_ms and dispatch_exposed_ms_per_step.
+        with jax.profiler.TraceAnnotation("paddle_tpu.trainer.stage"):
+            key = get_rng_key()
+            lr = self.optimizer.get_lr() if lr is None else lr
+            leaves = jax.tree_util.tree_leaves(inputs)
+            batch0 = jnp.shape(leaves[0])[0] if leaves and \
+                len(jnp.shape(leaves[0])) else None
+            if self.accumulate_steps > 1 and batch0 is not None and \
+                    batch0 % self.accumulate_steps != 0:
+                raise ValueError(
+                    f"batch size {batch0} is not divisible by "
+                    f"accumulate_steps={self.accumulate_steps}")
+            # inputs/labels may be arbitrary pytrees (e.g. (mlm, nsp) labels)
+            tel = _telemetry.enabled()
+            t_start = time.perf_counter() if tel else 0.0
+            # integrity cadence: host-side choice between the two cached
+            # programs (no recompile, no in-graph branch on the step count)
+            self._steps_run += 1
+            ce = self.integrity_check_every
+            do_check = bool(ce) and self._steps_run % ce == 0
+            inputs, labels, step = self._stage(inputs, labels,
+                                               do_check=do_check)
         # Host range for the profiler/chrome trace; the telemetry counter
-        # track is aligned against these. Skipped entirely (no object,
-        # no named_scope) when the profiler is off.
+        # track is aligned against these. Skipped entirely (no object)
+        # when the profiler is off.
         ev = (_profiler.RecordEvent("train_step").begin()
               if _profiler.is_profiler_enabled() else None)
         n_compiled0 = self._jit_cache_size(step) if tel else None
         taint = 1.0 if grad_taint is None else float(grad_taint)
-        loss, new_params, new_opt, new_comm_err, new_guard, integ = step(
-            self.state["params"], self.state["buffers"], self.state["opt"],
-            self.state["comm_err"], self.state["guard"], key, lr, taint,
-            inputs, labels)
+        with jax.profiler.TraceAnnotation("paddle_tpu.trainer.launch"):
+            loss, new_params, new_opt, new_comm_err, new_guard, integ = step(
+                self.state["params"], self.state["buffers"],
+                self.state["opt"], self.state["comm_err"],
+                self.state["guard"], key, lr, taint, inputs, labels)
         if tel or ev is not None:
             # the documented telemetry sync point: step wall time includes
             # device execution (loss is the last value the step produces)

@@ -45,6 +45,17 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     (a dense (B,1,1,T) ``attn_mask`` falls back to XLA, since streaming an
     O(S²) mask forfeits flash's memory advantage anyway).
     """
+    # Trace scopes: "sdpa" holds everything attention stages (the kernels
+    # or the O(S^2) path, and the layout changes around them), "flash" or
+    # "xla" inside it says which path ran. Read by the benchmark's
+    # attn_path_ms_per_step through the device trace's tf_op.
+    with jax.named_scope("sdpa"):
+        return _sdpa(query, key, value, attn_mask, dropout_p, is_causal,
+                     training, kv_lens)
+
+
+def _sdpa(query, key, value, attn_mask, dropout_p, is_causal, training,
+          kv_lens):
     from ...ops.pallas.flash_attention import flash_attention, flash_supported
     # Round-3 re-sweep on a real v5e (fwd+bwd, b4 h12 d64, causal,
     # in-kernel dropout): flash+dropout 6.84/6.99/9.19 ms at s=512/1024/
@@ -62,9 +73,10 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
             seed = jax.random.randint(get_rng_key(), (), 0,
                                       jnp.iinfo(jnp.int32).max,
                                       dtype=jnp.int32)
-        return flash_attention(query, key, value, causal=is_causal,
-                               kv_lens=kv_lens, dropout_rate=rate,
-                               dropout_seed=seed)
+        with jax.named_scope("flash"):
+            return flash_attention(query, key, value, causal=is_causal,
+                                   kv_lens=kv_lens, dropout_rate=rate,
+                                   dropout_seed=seed)
     from ...ops.pallas.tuner import record_fallback
     record_fallback("flash_attention")
     if kv_lens is not None:
@@ -78,5 +90,7 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         else:  # additive bias: padding keys get -inf-like logits
             attn_mask = attn_mask + jnp.where(
                 lens_mask, 0.0, jnp.finfo(jnp.float32).min)
-    return _xla_attention(query, key, value, mask=attn_mask, causal=is_causal,
-                          dropout_p=dropout_p, training=training)
+    with jax.named_scope("xla"):
+        return _xla_attention(query, key, value, mask=attn_mask,
+                              causal=is_causal, dropout_p=dropout_p,
+                              training=training)

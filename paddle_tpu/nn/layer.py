@@ -105,6 +105,11 @@ class Layer:
         self.training = True
         self._dtype = dtype_mod.convert_dtype_to_jax(dtype) or dtype_mod.get_default_dtype()
         self._full_name = unique_name(name_scope or type(self).__name__.lower())
+        # what forward is staged under (jax.named_scope): the name a parent
+        # registers this layer by, the class name for a root. Unlike
+        # _full_name it carries no counter, so it is the same in every
+        # process and for every model built in one.
+        self._scope_name = type(self).__name__.lower()
         self._forward_pre_hooks: OrderedDict = OrderedDict()
         self._forward_post_hooks: OrderedDict = OrderedDict()
         self._hook_id = 0
@@ -138,7 +143,18 @@ class Layer:
 
     def add_sublayer(self, name: str, sublayer: "Layer"):
         self._sub_layers[name] = sublayer
+        self._name_sublayer(name, sublayer)
         return sublayer
+
+    def _name_sublayer(self, name: str, sublayer: Optional["Layer"]):
+        """The one place a registered sublayer learns its scope name. A
+        layer registered twice stages under the later name; what only
+        reads a model (a container's slice) registers nothing."""
+        if sublayer is not None:
+            sublayer._set_scope_name(name)
+
+    def _set_scope_name(self, name: str):
+        self._scope_name = name
 
     def register_buffer(self, name: str, tensor, persistable: bool = True):
         self._buffers[name] = tensor if tensor is None else jnp.asarray(tensor)
@@ -154,6 +170,7 @@ class Layer:
         elif isinstance(value, Layer):
             self.__dict__.pop(name, None)
             self._sub_layers[name] = value
+            self._name_sublayer(name, value)
         elif "_buffers" in self.__dict__ and name in self._buffers:
             self._buffers[name] = value if value is None else jnp.asarray(value)
         elif "_parameters" in self.__dict__ and name in self._parameters and value is None:
@@ -344,7 +361,8 @@ class Layer:
             res = hook(self, args)
             if res is not None:
                 args = res if isinstance(res, tuple) else (res,)
-        out = self.forward(*args, **kwargs)
+        with jax.named_scope(self._scope_name):
+            out = self.forward(*args, **kwargs)
         for hook in self._forward_post_hooks.values():
             res = hook(self, args, out)
             if res is not None:

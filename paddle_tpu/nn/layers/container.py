@@ -6,6 +6,17 @@ from collections import OrderedDict
 from ..layer import Layer, Parameter
 
 
+def _slice_view(cls, layers):
+    """A slice of a container: a new one over the same layers, keyed from
+    0 as the constructor keys them, that leaves their scope names alone.
+    Registering them (``add_sublayer``) would rename the owner's layers,
+    and a read of a model must not change what its step is staged under."""
+    view = cls()
+    for i, l in enumerate(layers):
+        view._sub_layers[str(i)] = l
+    return view
+
+
 class Sequential(Layer):
     def __init__(self, *layers):
         super().__init__()
@@ -21,7 +32,8 @@ class Sequential(Layer):
 
     def __getitem__(self, idx):
         if isinstance(idx, slice):
-            return Sequential(*list(self._sub_layers.values())[idx])
+            return _slice_view(Sequential,
+                               list(self._sub_layers.values())[idx])
         return list(self._sub_layers.values())[idx]
 
     def __len__(self):
@@ -42,13 +54,24 @@ class LayerList(Layer):
         for i, l in enumerate(sublayers or []):
             self.add_sublayer(str(i), l)
 
+    # A list is iterated, never called, so it opens no scope of its own:
+    # it passes its name on and its children stage under "h.0", "h.1".
+    def _name_sublayer(self, name, sublayer):
+        super()._name_sublayer(f"{self._scope_name}.{name}", sublayer)
+
+    def _set_scope_name(self, name):
+        super()._set_scope_name(name)
+        for key, sub in self._sub_layers.items():
+            self._name_sublayer(key, sub)
+
     def __getitem__(self, idx):
         if isinstance(idx, slice):
-            return LayerList(list(self._sub_layers.values())[idx])
+            return _slice_view(LayerList,
+                               list(self._sub_layers.values())[idx])
         return list(self._sub_layers.values())[idx]
 
     def __setitem__(self, idx, layer):
-        self._sub_layers[str(idx)] = layer
+        self.add_sublayer(str(idx), layer)
 
     def __len__(self):
         return len(self._sub_layers)
@@ -65,7 +88,7 @@ class LayerList(Layer):
         layers.insert(index, layer)
         self._sub_layers.clear()
         for i, l in enumerate(layers):
-            self._sub_layers[str(i)] = l
+            self.add_sublayer(str(i), l)
 
     def extend(self, layers):
         for l in layers:

@@ -21,6 +21,12 @@ Round-3 widening (verdict item 5):
   dv = (m∘p)ᵀdo, and ds = p∘(m∘dp − δ) where δ = do·out already
   absorbs the dropped normalizer term.
 
+Trace names: each ``pallas_call`` carries ``name=`` (``flash_fwd``,
+``flash_bwd_dq``, ``flash_bwd_dkv``). That names the kernel's op in a
+device trace and stages it under a ``jax.named_scope`` of the same string
+(``.../attn/sdpa/flash/flash_bwd_dq/pallas_call`` in the op's ``tf_op``),
+so a reader finds the kernels whatever their operands are.
+
 TPU layout notes: per-row stats (m, l, lse, delta) are carried at LANE=8
 width (last dim equal to the array dim satisfies Mosaic's tiling rule);
 VMEM scratch uses full (block, 128) tiles.
@@ -48,6 +54,11 @@ DEFAULT_BLOCK_K = 512
 LANES = 128
 STAT_LANES = 8
 NEG_INF = -1e30
+# The pallas_calls' name=: what a staged program and a device trace call
+# the three kernels. The tuner, the analyzer's pallas-config-untuned rule
+# and chip_smoke.py find them by these.
+FWD, BWD_DQ, BWD_DKV = KERNEL_NAMES = (
+    "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
 
 
 def _causal_mask(s, iq, ik, block_q, block_k):
@@ -195,6 +206,7 @@ def _fwd(q, k, v, lens, seed, sm_scale, causal, block_q, block_k,
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name=FWD,
     )(lens, seed, q, k, v)
     return out, lse
 
@@ -335,6 +347,7 @@ def _bwd(sm_scale, causal, block_q, block_k, use_kv_mask, dropout_rate,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name=BWD_DQ,
     )(lens, seed, q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -366,6 +379,7 @@ def _bwd(sm_scale, causal, block_q, block_k, use_kv_mask, dropout_rate,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name=BWD_DKV,
     )(lens, seed, q, k, v, do, lse, delta)
     # int-array inputs (lens, seed) take float0 cotangents
     return (dq, dk, dv, np.zeros(lens.shape, jax.dtypes.float0),

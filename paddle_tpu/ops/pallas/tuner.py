@@ -293,15 +293,17 @@ def entry_for_traced_call(kernel_name: str, avals: List, grid) -> \
     """Map a traced ``pallas_call`` equation back to its DB entry — the
     analysis rule's hook (``pallas-config-untuned``).
 
-    ``kernel_name`` is the pallas_call's kernel function name; ``avals``
+    ``kernel_name`` is the pallas_call's ``name=`` (the flash kernels)
+    or its kernel function's name; ``avals``
     the input abstract values; ``grid`` the launch grid.  Returns
     ``(key, entry_or_None)``; ``(None, None)`` when the kernel is not
     one the tuner knows.  For fused CE the vocab seen in the jaxpr is
     the block-padded one, so the match accepts any DB entry whose true
     vocab pads to the traced width.
     """
+    from .flash_attention import KERNEL_NAMES as flash_kernels
     db = get_db()
-    if kernel_name in ("_fwd_kernel", "_bwd_dq_kernel", "_bwd_dkv_kernel"):
+    if kernel_name in flash_kernels:
         # flash attention: invars (lens, seed, q, k, v, ...) — q at 2
         if len(avals) < 5:
             return None, None

@@ -11,8 +11,12 @@ Capability map (reference, not copied):
 - chrome-trace export                  ← tools/timeline.py (proto → chrome);
   here host events are written directly in the chrome://tracing JSON format.
 
-Host events nest via a thread-local stack; on TPU each event also opens a
-``jax.named_scope`` so the range shows up inside the XLA trace viewer.
+Host events nest via a thread-local stack; each event also opens a
+``jax.profiler.TraceAnnotation`` named ``paddle_tpu.<name>``, so the range
+shows up in the host plane of a jax profiler trace, on the same clock as
+the device's ops. It opens no ``jax.named_scope``: a host range is not a
+staging scope, and one that is open while a step is first traced would
+write itself into that program's op names.
 """
 from __future__ import annotations
 
@@ -64,14 +68,14 @@ class RecordEvent:
     silently drops the range instead of writing garbage timestamps into
     the new session. The nesting stack holds the event objects themselves
     (removed by identity), so an ``end()`` arriving out of LIFO order can
-    never pop another event's entry; the ``jax.named_scope`` is always
+    never pop another event's entry; the ``TraceAnnotation`` is always
     exited iff it was entered.
     """
 
     def __init__(self, name: str):
         self.name = name
         self._t0 = None
-        self._scope = None
+        self._span = None
         self._session = None
 
     def begin(self):
@@ -79,8 +83,9 @@ class RecordEvent:
             self._session = _session
             self._t0 = time.perf_counter_ns()
             _stack().append(self)
-            self._scope = jax.named_scope(self.name)
-            self._scope.__enter__()
+            self._span = jax.profiler.TraceAnnotation(
+                "paddle_tpu." + self.name)
+            self._span.__enter__()
         return self
 
     def end(self):
@@ -99,9 +104,9 @@ class RecordEvent:
             with _lock:
                 _events.append((self.name, parent, self._t0, t1, cur.ident))
                 _tid_names[cur.ident] = cur.name
-        if self._scope is not None:
-            self._scope.__exit__(None, None, None)
-            self._scope = None
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
         self._t0 = None
         self._session = None
 
