@@ -57,12 +57,14 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
 def _sdpa(query, key, value, attn_mask, dropout_p, is_causal, training,
           kv_lens):
     from ...ops.pallas.flash_attention import flash_attention, flash_supported
-    # Round-3 re-sweep on a real v5e (fwd+bwd, b4 h12 d64, causal,
-    # in-kernel dropout): flash+dropout 6.84/6.99/9.19 ms at s=512/1024/
-    # 2048 vs XLA *without* dropout 7.12/6.85/10.64 — flash matches XLA's
-    # undropped cost from s=512, and XLA-with-dropout pays an extra
-    # (B,H,S,S) mask on top. Dropout and kv_lens padding masks run inside
-    # the kernel; only dense attn_mask tensors force the XLA path.
+    # The gate at 512 positions has no run at 512 on record. What the
+    # benchmark's cells measured (PERF.md sections 5 and 6, PR 26): at
+    # 1,024 positions the kernels and the layout copies around them take
+    # 43.7 ms of gpt2-small's 129.6 ms step, at 256 positions this XLA
+    # path takes 42.0 ms of a 209.1 ms step for the same tokens. Re-judging
+    # the gate is queued in PERF.md section 7. Dropout and kv_lens padding
+    # masks run inside the kernel; only dense attn_mask tensors force the
+    # XLA path.
     if attn_mask is None and flash_supported(query, key, min_seq=512):
         # no except around the kernel: one the gate selected must raise
         # when it breaks, not leave the model training on the O(S²) path

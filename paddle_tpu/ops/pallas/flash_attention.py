@@ -5,7 +5,15 @@ Replaces the reference's fused attention CUDA kernels
 materialize the full S×S probability matrix (O(S²) HBM). This kernel is
 blockwise-online-softmax: O(S) memory, MXU matmuls with fp32 accumulators,
 causal block skipping. Forward + custom-VJP backward (dq and dk/dv passes) so
-long-context training works end-to-end.
+long-context training works end-to-end. The nine products multiply in the
+dtype q/k/v arrive in: bf16 operands reach the MXU as bf16, its packed
+format; ``p`` and ``ds`` are cast to that dtype where they enter a product;
+the scores, the softmax statistics and the accumulators are float32 whatever
+the operands are, and a float32 caller keeps float32 products. (An upcast
+operand makes Mosaic issue the product in the MXU's f32 format, twice the
+pushes and latches; at the default precision the chip rounds such operands
+to bf16 in one pass, so the values were the same, and neither form sets the
+kernels' time: PERF.md section 6, PR 26.)
 
 Round-3 widening (verdict item 5):
 - ragged tails: inputs are zero-padded to lane multiples and the padded key
@@ -46,9 +54,17 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# swept on a real v5e chip (fwd+bwd, causal, d64): (256, 512) beats the
-# (128, 128) baseline by ~25-35% at s2048-8192 — bigger K blocks amortize
-# the online-softmax rescale; q=256 doubles MXU work per grid step
+# Measured on a v5e at the cells' shape (PERF.md section 6, PR 26: bf16
+# (16, 1024, 12, 64), causal, forward + backward of one layer, device ms of
+# every op): (128, 128) 18.34, (256, 256) 8.90, (256, 512) 6.14,
+# (512, 512) 5.01, (256, 1024) 4.90, (512, 1024) 4.15, (1024, 1024) 3.88.
+# Nothing inside a tile moves those times (operand dtype, masks, exp):
+# they follow the grid steps (about 0.26 us each) and the bytes the blocks
+# bring from HBM in its padded tiles (head width 64 in 128 lanes, the
+# 8-lane statistics in 128), K/V fetched again for each q block. So larger
+# blocks are faster, and the tuning DB's row for bf16, width 64 and 513 to
+# 1,024 positions says (1024, 1024): one tile a head. These defaults serve
+# the calls no row covers; no run on record has measured them there.
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 512
 LANES = 128
@@ -59,6 +75,14 @@ NEG_INF = -1e30
 # and chip_smoke.py find them by these.
 FWD, BWD_DQ, BWD_DKV = KERNEL_NAMES = (
     "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+
+
+def _scores(q, k, sm_scale):
+    """sm_scale * q k^T in float32. The operands go to the MXU in the dtype
+    they arrive in (a bf16 x bf16 product is exact in float32); the scale
+    is applied to the float32 scores, never to a q rounded back to bf16."""
+    return sm_scale * jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
 
 
 def _causal_mask(s, iq, ik, block_q, block_k):
@@ -130,10 +154,7 @@ def _fwd_kernel(lens_ref, seed_ref,       # (1,STAT) i32, (1,STAT) i32
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0].astype(jnp.float32) * sm_scale
-        k = k_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        s = _scores(q_ref[0], k_ref[0], sm_scale)
         if causal:
             s = _causal_mask(s, iq, ik, block_q, block_k)
         if use_kv_mask:
@@ -154,8 +175,9 @@ def _fwd_kernel(lens_ref, seed_ref,       # (1,STAT) i32, (1,STAT) i32
             # the value accumulation is dropped
             p = p * _dropout_mask(p.shape, dropout_rate, seed_ref[0],
                                   b, iq, ik)
-        v = v_ref[0].astype(jnp.float32)
-        pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+        v = v_ref[0]
+        pv = jax.lax.dot_general(p.astype(v.dtype), v,
+                                 (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         acc_scr[:] = acc_scr[:] * alpha + pv
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
@@ -230,25 +252,22 @@ def _bwd_dq_kernel(lens_ref, seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0].astype(jnp.float32) * sm_scale
-        k = k_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        k = k_ref[0]
+        s = _scores(q_ref[0], k, sm_scale)
         if causal:
             s = _causal_mask(s, iq, ik, block_q, block_k)
         if use_kv_mask:
             s = _kv_mask(s, ik, block_k, lens_ref[b])
         p = jnp.exp(s - lse_ref[0][:, :1])
-        do = do_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+        dp = jax.lax.dot_general(do_ref[0], v_ref[0],
+                                 (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         if dropout_rate > 0.0:
             dp = dp * _dropout_mask(dp.shape, dropout_rate, seed_ref[0],
                                     b, iq, ik)
         ds = p * (dp - delta_ref[0][:, :1])
         dq_scr[:] += sm_scale * jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     @pl.when(ik == num_k_blocks - 1)
@@ -273,11 +292,8 @@ def _bwd_dkv_kernel(lens_ref, seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     @pl.when(run)
     def _compute():
-        q_raw = q_ref[0].astype(jnp.float32)
-        q = q_raw * sm_scale
-        k = k_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        q = q_ref[0]
+        s = _scores(q, k_ref[0], sm_scale)
         if causal:
             s = _causal_mask(s, iq, ik, block_q, block_k)
         if use_kv_mask:
@@ -290,18 +306,17 @@ def _bwd_dkv_kernel(lens_ref, seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         else:
             m = None
             p_drop = p
-        do = do_ref[0].astype(jnp.float32)          # (Bq, D)
-        dv_scr[:] += jax.lax.dot_general(p_drop, do,
+        do = do_ref[0]                              # (Bq, D)
+        dv_scr[:] += jax.lax.dot_general(p_drop.astype(do.dtype), do,
                                          (((0,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+        dp = jax.lax.dot_general(do, v_ref[0], (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         if m is not None:
             dp = dp * m
         ds = p * (dp - delta_ref[0][:, :1])         # (Bq, Bk)
         dk_scr[:] += sm_scale * jax.lax.dot_general(
-            ds, q_raw, (((0,), (0,)), ((), ())),
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     @pl.when(iq == num_q_blocks - 1)
@@ -442,11 +457,13 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, kv_lens=None,
 
     block_q/block_k: ``None`` resolves from the tuning DB
     (``ops/pallas/tuner.py``: tuned entry → those blocks, miss → the
-    swept DEFAULT_BLOCK_Q/K, counted in
+    compiled-in DEFAULT_BLOCK_Q/K, counted in
     ``pallas_config_resolved_total``); explicit values bypass the DB.
     """
     b, sq, h, d = q.shape
     sk = k.shape[1]
+    # the kernels multiply in their operands' dtype: one dtype, q's
+    k, v = k.astype(q.dtype), v.astype(q.dtype)
     if block_q is None or block_k is None:
         from .tuner import flash_dims, resolve
         cfg, _ = resolve("flash_attention", q.dtype, flash_dims(d, sq, sk),

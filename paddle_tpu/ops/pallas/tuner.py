@@ -379,7 +379,7 @@ def flash_candidates(sq: int, sk: int) -> List[Dict[str, int]]:
     the bucketed sequence lengths (the wrapper's clamp would mangle
     anything else)."""
     out = []
-    for bq in (128, 256, 512):
+    for bq in (128, 256, 512, 1024):
         if bq > sq or sq % bq:
             continue
         for bk in (128, 256, 512, 1024):

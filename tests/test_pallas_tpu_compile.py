@@ -70,7 +70,17 @@ def test_flash_kernels_keep_name_and_scope_on_the_tpu(v5e, kernel, transform):
                       f"/{kernel}/pallas_call"
 
 
-def test_flash_attention_fwd_bwd_gpt_base(v5e):
+@pytest.mark.parametrize("shape", [
+    (8, 1024, 12, 64),     # chip_smoke.py's GPT-base
+    (16, 1024, 12, 64),    # gpt2-small.seq1024 and .dp4, rows a chip
+    (8, 1024, 16, 64),     # gpt2-medium.seq1024
+    (4, 1024, 16, 128),    # head width 128: gpt3-1p3b, the next one
+], ids=lambda s: "x".join(map(str, s)))
+def test_flash_attention_fwd_bwd_gpt_base(v5e, shape):
+    """Lowers and fits on a v5e at every shape the cells run, in the blocks
+    the tuning DB resolves for it ((1024, 1024) at head width 64), with the
+    bf16 products (transposed-left ones in ``flash_bwd_dkv`` among them)
+    that interpret mode never shows Mosaic."""
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
 
     def f(q, k, v):
@@ -78,7 +88,7 @@ def test_flash_attention_fwd_bwd_gpt_base(v5e):
             q, k, v, causal=True).astype(jnp.float32) ** 2),
             argnums=(0, 1, 2))(q, k, v)
 
-    qkv = ((8, 1024, 12, 64), jnp.bfloat16)
+    qkv = (shape, jnp.bfloat16)
     _compile(f, v5e, qkv, qkv, qkv)
 
 
