@@ -10,7 +10,15 @@ alltoall_op.cc enables.
 Design (static shapes, MXU-friendly): capacity-based dispatch. Each device
 routes its tokens to per-expert buffers of fixed capacity C (drop+pad, like
 GShard/Switch), all_to_all's them over the expert axis, applies its local
-experts batched, and all_to_all's back.
+experts batched, and all_to_all's back. Its dispatch and combine tensors are
+``(tokens, experts, capacity)`` one-hots, so it serves a few experts.
+
+``DroplessMoELayer`` (ISSUE 27) is the layer for hundreds of experts: a
+top-k router over all of them, a layer that is told which experts it holds,
+sorted (token, expert) assignments and grouped matrix products
+(``jax.lax.ragged_dot``), a shared expert, and no dropped token. It computes
+its own experts' part of the result; the exchange that would bring other
+chips' tokens to them is not written yet.
 """
 from __future__ import annotations
 
@@ -20,7 +28,7 @@ from jax import lax
 
 from ..nn import functional as F
 from ..nn.layer import Layer
-from ..nn.layers.common import Linear
+from ..nn.layers.common import GatedSiluFFN, Linear
 
 EXPERT_AXIS = "model"
 
@@ -179,3 +187,230 @@ class MoELayer(Layer):
         out = jnp.einsum("tec,ecd->td", combine.astype(tokens.dtype),
                          expert_out)
         return jnp.reshape(out, (b, s, d))
+
+
+# ---------------------------------------------------------------------------
+# dropless top-k layer over held experts
+# ---------------------------------------------------------------------------
+def route_top_k(logits, top_k, scoring="sigmoid", scaling_factor=1.0):
+    """``(expert ids int32 (T, k), weights float32 (T, k))`` from float32
+    router logits ``(T, E)``: scores are ``sigmoid`` or ``softmax`` of the
+    logits, the ``top_k`` largest are chosen, and their weights are the
+    scores over their sum, times ``scaling_factor``."""
+    logits = logits.astype(jnp.float32)
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"unknown router scoring {scoring!r}")
+    top, ids = lax.top_k(scores, top_k)
+    top = top / jnp.sum(top, axis=-1, keepdims=True)
+    return ids.astype(jnp.int32), top * scaling_factor
+
+
+class GroupedExperts(Layer):
+    """``count`` gated SiLU FFNs with stacked weights, applied to rows that
+    are sorted by expert: three grouped matrix products."""
+
+    def __init__(self, count, d_model, d_expert):
+        super().__init__()
+        from ..nn.initializer import XavierUniform
+        wide = XavierUniform(fan_in=d_model, fan_out=d_expert)
+        self.gate_proj = self.create_parameter((count, d_model, d_expert),
+                                               initializer=wide)
+        self.up_proj = self.create_parameter((count, d_model, d_expert),
+                                             initializer=wide)
+        self.down_proj = self.create_parameter(
+            (count, d_expert, d_model),
+            initializer=XavierUniform(fan_in=d_expert, fan_out=d_model))
+
+    def forward(self, rows, group_sizes):
+        """rows ``(R, d_model)``, the first ``group_sizes[0]`` of them for
+        expert 0 and so on. Rows past ``sum(group_sizes)`` belong to no
+        group: on the TPU the kernels do not write them, in the result or
+        in its gradient, so the caller masks them."""
+        dt = rows.dtype
+        gate = lax.ragged_dot(rows, self.gate_proj.value.astype(dt),
+                              group_sizes)
+        up = lax.ragged_dot(rows, self.up_proj.value.astype(dt), group_sizes)
+        return lax.ragged_dot(F.silu(gate) * up,
+                              self.down_proj.value.astype(dt), group_sizes)
+
+
+# the compiler's grouped-product kernels work in tiles of this many rows
+ROW_TILE = 512
+# The part of the buffer that always runs, over the expected assignments.
+# Gathers and scatters cost by the row, masked or not (about 0.1 us a row
+# on a v5e), so this is paid on every step; the rest of the buffer costs a
+# whole pass whenever it runs. Over 12 seeds of the benchmark's traffic
+# the held share of a freshly initialised router was 0.83 to 1.22 of the
+# expectation, and at 1.25 the second part ran in some layer on most
+# steps (PERF.md section 6, PR 27): twice the expectation keeps it for
+# real imbalance.
+CHUNK_HEADROOM = 2.0
+
+
+class DroplessMoELayer(Layer):
+    """``shared_expert(x) + sum over the held e in top_k(router(x)) of
+    w_e * expert_e(x)``.
+
+    The router scores every token over all ``num_experts`` in float32. The
+    layer holds the experts ``held = (first, count)`` (all of them by
+    default) and computes their part of the sum; what the experts held
+    elsewhere would add is left out.
+
+    Every (token, expert) assignment that falls on a held expert is
+    computed, whatever the imbalance. The assignments are sorted by expert,
+    held ones first. Up to ``buffer_rows = tokens * min(top_k, count)`` of
+    them can fall here (a token's ``top_k`` experts are distinct), and all
+    of those are walked, in two parts: the first ``chunk_rows`` (twice the
+    expected number ``tokens * top_k * count / num_experts``) always, the
+    rest of the buffer only when assignments are left for it (``lax.cond``,
+    decided at run time). So a balanced router pays for its load and the
+    worst one for the whole buffer, and nothing is ever cut. In each part
+    the tokens are gathered, the held experts run as grouped products over
+    the sorted rows, and the weighted results are added back to their
+    tokens. Rows past the last assignment belong to no group; the kernels
+    leave them unwritten, so they are masked on the way in and on the way
+    out, in both directions.
+
+    Sublayers ``router``, ``shared_expert``, ``experts`` and the scopes
+    ``dispatch`` (sort, gather) and ``combine`` (weights, scatter back) name
+    every op in a device trace. Three non-persistable buffers carry the last
+    call's counts out of a jitted ``functional_call`` as ``aux_loss`` does
+    for ``MoELayer``: ``tokens_routed``, ``held_assignments`` and
+    ``max_load_over_mean`` (the fullest held expert's assignments over the
+    mean per held expert); ``publish_routing`` writes them to the telemetry
+    registry. ``scoring`` is the router's rule, ``"sigmoid"`` or
+    ``"softmax"``; either way the chosen scores are divided by their sum and
+    multiplied by ``routed_scaling_factor``.
+    """
+
+    def __init__(self, d_model, d_expert, num_experts, top_k, held=None,
+                 routed_scaling_factor=1.0, scoring="sigmoid", d_shared=None):
+        super().__init__()
+        first, count = held if held is not None else (0, num_experts)
+        if not (0 <= first and count >= 1 and first + count <= num_experts):
+            raise ValueError(f"held={held} is not a range of the "
+                             f"{num_experts} experts")
+        if top_k > num_experts:
+            raise ValueError(f"top_k={top_k} of {num_experts} experts")
+        self.num_experts, self.top_k = num_experts, top_k
+        self.first, self.count = first, count
+        self.routed_scaling_factor = routed_scaling_factor
+        self.scoring = scoring
+        self.router = Linear(d_model, num_experts, bias_attr=False)
+        self.shared_expert = (GatedSiluFFN(d_model, d_shared)
+                              if d_shared else None)
+        self.experts = GroupedExperts(count, d_model, d_expert)
+        for name, dtype in (("tokens_routed", jnp.int32),
+                            ("held_assignments", jnp.int32),
+                            ("max_load_over_mean", jnp.float32)):
+            self.register_buffer(name, jnp.zeros((), dtype),
+                                 persistable=False)
+
+    def buffer_rows(self, tokens: int) -> int:
+        """The most assignments that can fall on the held experts."""
+        return tokens * min(self.top_k, self.count)
+
+    def chunk_rows(self, tokens: int) -> int:
+        """Rows of the part that always runs: ``CHUNK_HEADROOM`` times the
+        expected assignments, in whole kernel tiles, at most
+        ``buffer_rows``."""
+        expected = tokens * self.top_k * self.count / self.num_experts
+        tiles = -(-int(CHUNK_HEADROOM * expected) // ROW_TILE)
+        return min(max(tiles, 1) * ROW_TILE, self.buffer_rows(tokens))
+
+    def route(self, tokens):
+        """Expert ids and weights ``(T, top_k)`` of ``tokens (T, d_model)``,
+        the router's product accumulated and scored in float32."""
+        with jax.named_scope("router"):
+            logits = lax.dot_general(
+                tokens, self.router.weight.value.astype(tokens.dtype),
+                (((1,), (0,)), ((), ())), precision=lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+            return route_top_k(logits, self.top_k, self.scoring,
+                               self.routed_scaling_factor)
+
+    def forward(self, x):
+        shape = x.shape
+        tokens = jnp.reshape(x, (-1, shape[-1]))
+        T, k = tokens.shape[0], self.top_k
+        ids, weights = self.route(tokens)
+        with jax.named_scope("dispatch"):
+            local = jnp.reshape(ids, (-1,)) - self.first        # (T*k,)
+            is_held = (local >= 0) & (local < self.count)
+            # held assignments first, by expert; the others after them
+            key = jnp.where(is_held, local, self.count)
+            order = jnp.argsort(key, stable=True).astype(jnp.int32)
+            group_sizes = jnp.bincount(key, length=self.count + 1)[
+                :self.count].astype(jnp.int32)
+            ends = jnp.cumsum(group_sizes)
+            starts, n_held = ends - group_sizes, ends[-1]
+            flat_weights = jnp.reshape(weights, (-1,))
+
+        def add_rows(acc, lo, size):
+            """The sorted assignments ``lo .. lo + size`` added to acc."""
+            with jax.named_scope("dispatch"):
+                which = order[lo:lo + size]
+                valid = lo + jnp.arange(size) < n_held
+                token_of = which // k
+                rows = jnp.where(valid[:, None],
+                                 jnp.take(tokens, token_of, axis=0), 0)
+                sizes = jnp.clip(ends - lo, 0, size) \
+                    - jnp.clip(starts - lo, 0, size)
+            out = self.experts(rows, sizes)
+            with jax.named_scope("combine"):
+                # masked before the weights touch it: an unwritten row may
+                # hold anything, and 0 * nan would reach the router's
+                # gradient through the weight
+                out = jnp.where(valid[:, None], out, 0).astype(jnp.float32)
+                out = out * jnp.take(flat_weights, which)[:, None]
+                return acc.at[token_of].add(out.astype(acc.dtype))
+
+        # The first chunk holds the expected load and always runs. The rest
+        # of the buffer runs only when assignments are left for it, which a
+        # balanced router never has: skipped at run time otherwise, and
+        # recomputed in the backward pass so that it keeps nothing for it.
+        chunk, rows = self.chunk_rows(T), self.buffer_rows(T)
+        routed = add_rows(jnp.zeros_like(tokens), 0, chunk)
+        if rows > chunk:
+            routed = lax.cond(
+                n_held > chunk,
+                jax.checkpoint(lambda acc: add_rows(acc, chunk, rows - chunk)),
+                lambda acc: acc, routed)
+
+        self.tokens_routed = jnp.asarray(T, jnp.int32)
+        self.held_assignments = n_held
+        self.max_load_over_mean = jnp.max(group_sizes) * self.count \
+            / jnp.maximum(n_held, 1).astype(jnp.float32)
+
+        out = routed
+        if self.shared_expert is not None:
+            out = out + self.shared_expert(tokens)
+        return jnp.reshape(out, shape)
+
+    def publish_routing(self, buffers=None, prefix="", **labels):
+        """The last call's counts into the telemetry registry: counters
+        ``moe_tokens_routed_total`` and ``moe_held_assignments_total``, gauge
+        ``moe_max_load_over_mean``. ``buffers`` is what a jitted
+        ``functional_call`` returned (names under ``prefix``); without it
+        the layer's own buffers, which an eager call wrote. Host side: it
+        fetches three scalars."""
+        from .. import telemetry
+        src = buffers if buffers is not None else dict(self.named_buffers())
+        tokens = int(src[prefix + "tokens_routed"])
+        held = int(src[prefix + "held_assignments"])
+        telemetry.counter(
+            "moe_tokens_routed_total",
+            "tokens a dropless expert layer routed").inc(tokens, **labels)
+        telemetry.counter(
+            "moe_held_assignments_total",
+            "(token, expert) assignments that fell on the experts held "
+            "here; none is dropped").inc(held, **labels)
+        telemetry.gauge(
+            "moe_max_load_over_mean",
+            "assignments of the fullest held expert over the mean per held "
+            "expert, last call").set(
+                float(src[prefix + "max_load_over_mean"]), **labels)

@@ -10,8 +10,8 @@ from .layers.activation import (  # noqa: F401
     Tanhshrink, ThresholdedReLU)
 from .layers.common import (  # noqa: F401
     AlphaDropout, Bilinear, CosineSimilarity, Dropout, Dropout2D, Dropout3D,
-    Embedding, Flatten, Fold, Identity, Linear, Pad1D, Pad2D, Pad3D,
-    PairwiseDistance, Unfold, Upsample, UpsamplingBilinear2D,
+    Embedding, Flatten, Fold, GatedSiluFFN, Identity, Linear, Pad1D, Pad2D,
+    Pad3D, PairwiseDistance, Unfold, Upsample, UpsamplingBilinear2D,
     UpsamplingNearest2D, ZeroPad2D)
 from .layers.container import LayerDict, LayerList, ParameterList, Sequential  # noqa: F401
 from .layers.conv import (  # noqa: F401
@@ -23,8 +23,8 @@ from .layers.loss import (  # noqa: F401
 from .decode import BeamSearchDecoder, Decoder, dynamic_decode  # noqa: F401
 from .layers.norm import (  # noqa: F401
     BatchNorm, BatchNorm1D, BatchNorm2D, BatchNorm3D, GroupNorm, InstanceNorm1D,
-    InstanceNorm2D, InstanceNorm3D, LayerNorm, LocalResponseNorm, SpectralNorm,
-    SyncBatchNorm)
+    InstanceNorm2D, InstanceNorm3D, LayerNorm, LocalResponseNorm, RMSNorm,
+    SpectralNorm, SyncBatchNorm)
 from .layers.pooling import (  # noqa: F401
     AdaptiveAvgPool1D, AdaptiveAvgPool2D, AdaptiveAvgPool3D, AdaptiveMaxPool1D,
     AdaptiveMaxPool2D, AdaptiveMaxPool3D, AvgPool1D, AvgPool2D, AvgPool3D,

@@ -21,7 +21,7 @@ from .loss import (  # noqa: F401
     square_error_cost, triplet_margin_loss)
 from .norm import (  # noqa: F401
     batch_norm, group_norm, instance_norm, layer_norm, local_response_norm,
-    normalize)
+    normalize, rms_norm)
 from .pooling import (  # noqa: F401
     adaptive_avg_pool1d, adaptive_avg_pool2d, adaptive_avg_pool3d,
     adaptive_max_pool1d, adaptive_max_pool2d, adaptive_max_pool3d, avg_pool1d,
@@ -29,3 +29,4 @@ from .pooling import (  # noqa: F401
 from .vision import (  # noqa: F401
     affine_grid, channel_shuffle, grid_sample, pixel_shuffle, pixel_unshuffle)
 from .attention import scaled_dot_product_attention  # noqa: F401
+from .rotary import rope_frequencies, rotary_embedding  # noqa: F401

@@ -14,14 +14,25 @@ import jax.numpy as jnp
 
 
 def _xla_attention(q, k, v, mask=None, scale=None, causal=False, dropout_p=0.0,
-                   training=True):
-    # q,k,v: (B, S, H, D)
+                   training=True, window=None):
+    # q: (B, S, H, D); k, v: (B, T, H_kv, D) with H % H_kv == 0
     d = q.shape[-1]
     scale = scale if scale is not None else d ** -0.5
-    logits = jnp.einsum("bshd,bthd->bhst", q, k) * scale
+    grouped = k.shape[2] != q.shape[2]
+    if grouped:
+        # query head i reads KV head i // group: no repeated K or V
+        b, s, h, _ = q.shape
+        q = jnp.reshape(q, (b, s, k.shape[2], h // k.shape[2], d))
+        logits = jnp.reshape(jnp.einsum("bskgd,btkd->bkgst", q, k),
+                             (b, h, s, k.shape[1])) * scale
+    else:
+        logits = jnp.einsum("bshd,bthd->bhst", q, k) * scale
     if causal:
         s, t = logits.shape[-2], logits.shape[-1]
         cm = jnp.tril(jnp.ones((s, t), dtype=bool))
+        if window is not None:
+            # key j is visible to query i iff 0 <= i - j < window
+            cm = cm & ~jnp.tril(jnp.ones((s, t), dtype=bool), -int(window))
         logits = jnp.where(cm, logits, jnp.finfo(logits.dtype).min)
     if mask is not None:
         if mask.dtype == jnp.bool_:
@@ -32,13 +43,23 @@ def _xla_attention(q, k, v, mask=None, scale=None, causal=False, dropout_p=0.0,
     if dropout_p > 0.0 and training:
         from .common import dropout as _dropout
         probs = _dropout(probs, p=dropout_p, training=True)
+    if grouped:
+        probs = jnp.reshape(probs, (b, k.shape[2], h // k.shape[2], s,
+                                    k.shape[1]))
+        return jnp.reshape(jnp.einsum("bkgst,btkd->bskgd", probs, v),
+                           (b, s, h, d))
     return jnp.einsum("bhst,bthd->bshd", probs, v)
 
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False, training=True,
-                                 kv_lens=None, name=None):
-    """query/key/value: (batch, seq, num_heads, head_dim).
+                                 kv_lens=None, name=None, window=None):
+    """query: (batch, seq, num_heads, head_dim); key/value: (batch, seq,
+    kv_heads, head_dim), where ``kv_heads`` divides ``num_heads`` and query
+    head ``i`` reads KV head ``i // (num_heads // kv_heads)``.
+
+    window: optional causal window (needs ``is_causal``): query ``i`` sees
+    key ``j`` iff ``0 <= i - j < window``.
 
     kv_lens: optional (batch,) valid key/value counts — the O(B) form of a
     trailing-padding key mask; keeps padded batches on the flash kernel
@@ -49,13 +70,15 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     # or the O(S^2) path, and the layout changes around them), "flash" or
     # "xla" inside it says which path ran. Read by the benchmark's
     # attn_path_ms_per_step through the device trace's tf_op.
+    if window is not None and not is_causal:
+        raise ValueError("window is a causal window: it needs is_causal")
     with jax.named_scope("sdpa"):
         return _sdpa(query, key, value, attn_mask, dropout_p, is_causal,
-                     training, kv_lens)
+                     training, kv_lens, window)
 
 
 def _sdpa(query, key, value, attn_mask, dropout_p, is_causal, training,
-          kv_lens):
+          kv_lens, window):
     from ...ops.pallas.flash_attention import flash_attention, flash_supported
     # The gate at 512 positions has no run at 512 on record. What the
     # benchmark's cells measured (PERF.md sections 5 and 6, PR 26): at
@@ -78,7 +101,7 @@ def _sdpa(query, key, value, attn_mask, dropout_p, is_causal, training,
         with jax.named_scope("flash"):
             return flash_attention(query, key, value, causal=is_causal,
                                    kv_lens=kv_lens, dropout_rate=rate,
-                                   dropout_seed=seed)
+                                   dropout_seed=seed, window=window)
     from ...ops.pallas.tuner import record_fallback
     record_fallback("flash_attention")
     if kv_lens is not None:
@@ -95,4 +118,4 @@ def _sdpa(query, key, value, attn_mask, dropout_p, is_causal, training,
     with jax.named_scope("xla"):
         return _xla_attention(query, key, value, mask=attn_mask,
                               causal=is_causal, dropout_p=dropout_p,
-                              training=training)
+                              training=training, window=window)

@@ -66,6 +66,20 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5, name=N
     return out
 
 
+def rms_norm(x, weight=None, epsilon=1e-6, name=None):
+    """``x / sqrt(mean(x**2) + epsilon) * weight`` over the last axis: no
+    mean is subtracted and there is no bias. The statistics are float32
+    whatever ``x`` is."""
+    weight = _unwrap(weight)
+    x32 = x.astype(jnp.float32)
+    out = (x32 * jax.lax.rsqrt(
+        jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + epsilon)
+    ).astype(x.dtype)
+    if weight is not None:
+        out = out * weight
+    return out
+
+
 def instance_norm(x, running_mean=None, running_var=None, weight=None, bias=None,
                   use_input_stats=True, momentum=0.9, eps=1e-5,
                   data_format="NCHW", name=None):

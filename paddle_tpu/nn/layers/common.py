@@ -38,6 +38,24 @@ class Linear(Layer):
         return f"in_features={self.in_features}, out_features={self.out_features}"
 
 
+class GatedSiluFFN(Layer):
+    """``down(silu(gate(x)) * up(x))``: the gated feed-forward block with
+    SiLU, three products and no bias."""
+
+    def __init__(self, hidden_size, intermediate_size, weight_attr=None,
+                 name=None):
+        super().__init__()
+        self.gate_proj = Linear(hidden_size, intermediate_size,
+                                weight_attr=weight_attr, bias_attr=False)
+        self.up_proj = Linear(hidden_size, intermediate_size,
+                              weight_attr=weight_attr, bias_attr=False)
+        self.down_proj = Linear(intermediate_size, hidden_size,
+                                weight_attr=weight_attr, bias_attr=False)
+
+    def forward(self, x):
+        return self.down_proj(F.silu(self.gate_proj(x)) * self.up_proj(x))
+
+
 class Embedding(Layer):
     def __init__(self, num_embeddings, embedding_dim, padding_idx=None,
                  sparse=False, weight_attr=None, name=None):

@@ -182,6 +182,26 @@ class LayerNorm(Layer):
         return f"normalized_shape={self.normalized_shape}"
 
 
+class RMSNorm(Layer):
+    """Root-mean-square norm over the last axis with a learned scale
+    (``F.rms_norm``)."""
+
+    def __init__(self, hidden_size, epsilon=1e-6, weight_attr=None,
+                 name=None):
+        super().__init__()
+        self.hidden_size = hidden_size
+        self.epsilon = epsilon
+        self.weight = self.create_parameter(
+            (hidden_size,), attr=weight_attr,
+            initializer=_to_initializer(weight_attr, None) or Constant(1.0))
+
+    def forward(self, x):
+        return F.rms_norm(x, self.weight, self.epsilon)
+
+    def extra_repr(self):
+        return f"hidden_size={self.hidden_size}"
+
+
 class GroupNorm(Layer):
     def __init__(self, num_groups, num_channels, epsilon=1e-5, weight_attr=None,
                  bias_attr=None, data_format="NCHW", name=None):

@@ -29,6 +29,18 @@ Round-3 widening (verdict item 5):
   dv = (m∘p)ᵀdo, and ds = p∘(m∘dp − δ) where δ = do·out already
   absorbs the dropped normalizer term.
 
+Grouped KV heads and a causal window (ISSUE 27): ``k``/``v`` may carry
+fewer heads than ``q`` (``heads % kv_heads == 0``); query head ``i`` reads
+KV head ``i // group`` through the K/V block index maps, and the dk/dv
+kernel, whose grid runs over KV heads, walks the group's query heads in
+its inner dimension, so no repeated K/V and no per-query-head dk/dv ever
+reach HBM. ``window=w`` (causal only) lets query ``i`` see key ``j`` iff
+``0 <= i - j < w``: the kernels mask by it, and the grid's inner dimension
+runs over the band of blocks a window leaves (first block from the index
+map) instead of over all of them, so blocks wholly outside the window cost
+neither a grid step nor a DMA. Without a window and with equal head counts
+the staged kernels are what they were.
+
 Trace names: each ``pallas_call`` carries ``name=`` (``flash_fwd``,
 ``flash_bwd_dq``, ``flash_bwd_dkv``). That names the kernel's op in a
 device trace and stages it under a ``jax.named_scope`` of the same string
@@ -40,8 +52,8 @@ width (last dim equal to the array dim satisfies Mosaic's tiling rule);
 VMEM scratch uses full (block, 128) tiles.
 
 Public API: flash_attention(q, k, v, causal=False, sm_scale=None,
-kv_lens=None, dropout_rate=0.0, dropout_seed=None)
-with q/k/v: (batch, seq, heads, head_dim).
+kv_lens=None, dropout_rate=0.0, dropout_seed=None, window=None)
+with q: (batch, seq, heads, head_dim), k/v: (batch, seq, kv_heads, head_dim).
 """
 from __future__ import annotations
 
@@ -85,12 +97,57 @@ def _scores(q, k, sm_scale):
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
 
 
-def _causal_mask(s, iq, ik, block_q, block_k):
+def _causal_mask(s, iq, ik, block_q, block_k, window=None):
+    """Keep key ``col`` for query ``row`` iff ``0 <= row - col`` and, with
+    a window, ``row - col < window``."""
     rows = iq * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
     cols = ik * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1)
-    return jnp.where(rows >= cols, s, NEG_INF)
+    keep = rows >= cols
+    if window is not None:
+        keep = keep & (rows - cols < window)
+    return jnp.where(keep, s, NEG_INF)
+
+
+def _floor(x, lo):
+    return max(x, lo) if isinstance(x, int) else jnp.maximum(x, lo)
+
+
+def _cap(x, hi):
+    return min(x, hi) if isinstance(x, int) else jnp.minimum(x, hi)
+
+
+class _Band:
+    """Which blocks of the other axis a block needs under a causal window.
+
+    ``k_first(iq)``..``k_last(iq)`` are the key blocks q block ``iq`` sees
+    (columns ``iq*bq - window + 1 .. (iq+1)*bq - 1``), ``q_first(ik)``..
+    ``q_last(ik)`` the q blocks that see key block ``ik`` (rows ``ik*bk ..
+    (ik+1)*bk + window - 2``). They take a Python int (for the static step
+    counts ``k_steps`` and ``q_steps``, the widest band of any block) or a
+    traced scalar (index maps, kernels) alike."""
+
+    def __init__(self, window, block_q, block_k, nq, nk):
+        self.window, self.bq, self.bk = window, block_q, block_k
+        self.nq, self.nk = nq, nk
+        self.k_steps = max(self.k_last(i) - self.k_first(i) + 1
+                           for i in range(nq))
+        self.q_steps = max(self.q_last(j) - self.q_first(j) + 1
+                           for j in range(nk))
+
+    def k_first(self, iq):
+        return _floor(iq * self.bq - (self.window - 1), 0) // self.bk
+
+    def k_last(self, iq):
+        return _cap(((iq + 1) * self.bq - 1) // self.bk, self.nk - 1)
+
+    def q_first(self, ik):
+        return (ik * self.bk) // self.bq
+
+    def q_last(self, ik):
+        return _cap(((ik + 1) * self.bk + self.window - 2) // self.bq,
+                    self.nq - 1)
 
 
 def _kv_mask(s, ik, block_k, kv_len):
@@ -139,24 +196,30 @@ def _fwd_kernel(lens_ref, seed_ref,       # (1,STAT) i32, (1,STAT) i32
                 o_ref, lse_ref,           # (1,Bq,D), (1,Bq,STAT_LANES)
                 m_scr, l_scr, acc_scr,    # (Bq,LANES),(Bq,LANES),(Bq,D)
                 *, sm_scale, causal, block_q, block_k, num_k_blocks,
-                use_kv_mask, dropout_rate):
+                use_kv_mask, dropout_rate, band=None):
     b = pl.program_id(0)
     iq = pl.program_id(1)
-    ik = pl.program_id(2)
+    step = pl.program_id(2)
+    # under a window the inner dimension walks the band's key blocks only
+    ik = step if band is None else band.k_first(iq) + step
+    window = None if band is None else band.window
 
-    @pl.when(ik == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    run = (ik * block_k < (iq + 1) * block_q) if causal else True
+    if band is not None:
+        run = ik <= band.k_last(iq)
+    else:
+        run = (ik * block_k < (iq + 1) * block_q) if causal else True
 
     @pl.when(run)
     def _compute():
         s = _scores(q_ref[0], k_ref[0], sm_scale)
         if causal:
-            s = _causal_mask(s, iq, ik, block_q, block_k)
+            s = _causal_mask(s, iq, ik, block_q, block_k, window)
         if use_kv_mask:
             s = _kv_mask(s, ik, block_k, lens_ref[b])
         m_prev = m_scr[:, :1]
@@ -183,7 +246,7 @@ def _fwd_kernel(lens_ref, seed_ref,       # (1,STAT) i32, (1,STAT) i32
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    @pl.when(ik == num_k_blocks - 1)
+    @pl.when(step == num_k_blocks - 1)
     def _finalize():
         l = l_scr[:, :1]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -194,25 +257,44 @@ def _fwd_kernel(lens_ref, seed_ref,       # (1,STAT) i32, (1,STAT) i32
         lse_ref[0] = jnp.broadcast_to(lse, (block_q, STAT_LANES))
 
 
+def _kv_index_maps(group, band):
+    """The K/V block index map of the two kernels whose grid is (q heads,
+    q blocks, key steps). Query head ``b`` reads KV head ``b // group``;
+    under a window step ``j`` is key block ``k_first(i) + j``, held at the
+    band's last block once past it so that the skipped steps fetch nothing
+    new. Ungrouped and unwindowed it is the plain ``(b, j, 0)``."""
+    def head(b):
+        return b if group == 1 else b // group
+
+    if band is None:
+        return lambda b, i, j: (head(b), j, 0)
+    return lambda b, i, j: (
+        head(b), jnp.minimum(band.k_first(i) + j, band.k_last(i)), 0)
+
+
 def _fwd(q, k, v, lens, seed, sm_scale, causal, block_q, block_k,
-         use_kv_mask, dropout_rate, interpret=False):
+         use_kv_mask, dropout_rate, interpret=False, window=None):
     bh, sq, d = q.shape
     sk = k.shape[1]
+    group = bh // k.shape[0]
     nq = pl.cdiv(sq, block_q)
     nk = pl.cdiv(sk, block_k)
+    band = None if window is None else _Band(window, block_q, block_k, nq, nk)
+    steps = nk if band is None else band.k_steps
+    kv_map = _kv_index_maps(group, band)
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
-        block_k=block_k, num_k_blocks=nk, use_kv_mask=use_kv_mask,
-        dropout_rate=dropout_rate)
+        block_k=block_k, num_k_blocks=steps, use_kv_mask=use_kv_mask,
+        dropout_rate=dropout_rate, band=band)
     out, lse = pl.pallas_call(
         kernel,
-        grid=(bh, nq, nk),
+        grid=(bh, nq, steps),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), kv_map),
+            pl.BlockSpec((1, block_k, d), kv_map),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -239,23 +321,28 @@ def _fwd(q, k, v, lens, seed, sm_scale, causal, block_q, block_k,
 def _bwd_dq_kernel(lens_ref, seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                    delta_ref, dq_ref, dq_scr,
                    *, sm_scale, causal, block_q, block_k, num_k_blocks,
-                   use_kv_mask, dropout_rate):
+                   use_kv_mask, dropout_rate, band=None):
     b = pl.program_id(0)
     iq = pl.program_id(1)
-    ik = pl.program_id(2)
+    step = pl.program_id(2)
+    ik = step if band is None else band.k_first(iq) + step
+    window = None if band is None else band.window
 
-    @pl.when(ik == 0)
+    @pl.when(step == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    run = (ik * block_k < (iq + 1) * block_q) if causal else True
+    if band is not None:
+        run = ik <= band.k_last(iq)
+    else:
+        run = (ik * block_k < (iq + 1) * block_q) if causal else True
 
     @pl.when(run)
     def _compute():
         k = k_ref[0]
         s = _scores(q_ref[0], k, sm_scale)
         if causal:
-            s = _causal_mask(s, iq, ik, block_q, block_k)
+            s = _causal_mask(s, iq, ik, block_q, block_k, window)
         if use_kv_mask:
             s = _kv_mask(s, ik, block_k, lens_ref[b])
         p = jnp.exp(s - lse_ref[0][:, :1])
@@ -270,7 +357,7 @@ def _bwd_dq_kernel(lens_ref, seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(ik == num_k_blocks - 1)
+    @pl.when(step == num_k_blocks - 1)
     def _finalize():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
@@ -278,24 +365,36 @@ def _bwd_dq_kernel(lens_ref, seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 def _bwd_dkv_kernel(lens_ref, seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, dk_ref, dv_ref, dk_scr, dv_scr,
                     *, sm_scale, causal, block_q, block_k, num_q_blocks,
-                    use_kv_mask, dropout_rate):
+                    use_kv_mask, dropout_rate, group=1, band=None):
+    # the grid's first dimension is the KV head; the inner one walks the
+    # group's query heads, and for each the q blocks (all of them, or the
+    # band a window leaves): ``num_q_blocks`` steps a query head
     b = pl.program_id(0)
     ik = pl.program_id(1)
-    iq = pl.program_id(2)
+    step = pl.program_id(2)
+    jq = step
+    if group != 1:
+        b = b * group + step // num_q_blocks
+        jq = step % num_q_blocks
+    iq = jq if band is None else band.q_first(ik) + jq
+    window = None if band is None else band.window
 
-    @pl.when(iq == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    run = ((iq + 1) * block_q > ik * block_k) if causal else True
+    if band is not None:
+        run = iq <= band.q_last(ik)
+    else:
+        run = ((iq + 1) * block_q > ik * block_k) if causal else True
 
     @pl.when(run)
     def _compute():
         q = q_ref[0]
         s = _scores(q, k_ref[0], sm_scale)
         if causal:
-            s = _causal_mask(s, iq, ik, block_q, block_k)
+            s = _causal_mask(s, iq, ik, block_q, block_k, window)
         if use_kv_mask:
             s = _kv_mask(s, ik, block_k, lens_ref[b])
         p = jnp.exp(s - lse_ref[0][:, :1])          # (Bq, Bk)
@@ -319,19 +418,24 @@ def _bwd_dkv_kernel(lens_ref, seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(iq == num_q_blocks - 1)
+    @pl.when(step == group * num_q_blocks - 1)
     def _finalize():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
 def _bwd(sm_scale, causal, block_q, block_k, use_kv_mask, dropout_rate,
-         interpret, res, do):
+         interpret, window, res, do):
     q, k, v, lens, seed, out, lse = res
     bh, sq, d = q.shape
-    sk = k.shape[1]
+    bkv, sk = k.shape[0], k.shape[1]
+    group = bh // bkv
     nq = pl.cdiv(sq, block_q)
     nk = pl.cdiv(sk, block_k)
+    band = None if window is None else _Band(window, block_q, block_k, nq, nk)
+    k_steps = nk if band is None else band.k_steps
+    q_steps = nq if band is None else band.q_steps
+    kv_map = _kv_index_maps(group, band)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)               # (bh, sq, 1)
     delta = jnp.broadcast_to(delta, (bh, sq, STAT_LANES))
@@ -339,21 +443,33 @@ def _bwd(sm_scale, causal, block_q, block_k, use_kv_mask, dropout_rate,
     lens_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     seed_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     stat_spec = pl.BlockSpec((1, block_q, STAT_LANES), lambda b, i, j: (b, i, 0))
-    stat_spec_kv = pl.BlockSpec((1, block_q, STAT_LANES),
-                                lambda b, j, i: (b, i, 0))
+
+    # the dk/dv kernel's view of what lives per query head (q, do, lse,
+    # delta): KV head ``b``, key block ``j``, inner step ``t``
+    if group == 1 and band is None:
+        def q_map(b, j, t):
+            return (b, t, 0)
+    else:
+        def q_map(b, j, t):
+            jq = t if group == 1 else t % q_steps
+            head = b if group == 1 else b * group + t // q_steps
+            if band is not None:
+                jq = jnp.minimum(band.q_first(j) + jq, band.q_last(j))
+            return (head, jq, 0)
+    stat_spec_kv = pl.BlockSpec((1, block_q, STAT_LANES), q_map)
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k, num_k_blocks=nk,
-                          use_kv_mask=use_kv_mask,
-                          dropout_rate=dropout_rate),
-        grid=(bh, nq, nk),
+                          block_q=block_q, block_k=block_k,
+                          num_k_blocks=k_steps, use_kv_mask=use_kv_mask,
+                          dropout_rate=dropout_rate, band=band),
+        grid=(bh, nq, k_steps),
         in_specs=[
             lens_spec,
             seed_spec,
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), kv_map),
+            pl.BlockSpec((1, block_k, d), kv_map),
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             stat_spec,
             stat_spec,
@@ -367,17 +483,17 @@ def _bwd(sm_scale, causal, block_q, block_k, use_kv_mask, dropout_rate,
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k, num_q_blocks=nq,
-                          use_kv_mask=use_kv_mask,
-                          dropout_rate=dropout_rate),
-        grid=(bh, nk, nq),
+                          block_q=block_q, block_k=block_k,
+                          num_q_blocks=q_steps, use_kv_mask=use_kv_mask,
+                          dropout_rate=dropout_rate, group=group, band=band),
+        grid=(bkv, nk, group * q_steps),
         in_specs=[
             lens_spec,
             seed_spec,
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, d), q_map),
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, d), q_map),
             stat_spec_kv,
             stat_spec_kv,
         ],
@@ -386,8 +502,8 @@ def _bwd(sm_scale, causal, block_q, block_k, use_kv_mask, dropout_rate,
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
+            jax.ShapeDtypeStruct((bkv, sk, d), k.dtype),
+            jax.ShapeDtypeStruct((bkv, sk, d), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
@@ -404,25 +520,26 @@ def _bwd(sm_scale, causal, block_q, block_k, use_kv_mask, dropout_rate,
 # ---------------------------------------------------------------------------
 # public API
 # ---------------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12))
 def _flash_bhsd(q, k, v, lens, seed, sm_scale, causal, block_q, block_k,
-                use_kv_mask, dropout_rate, interpret):
+                use_kv_mask, dropout_rate, interpret, window):
     out, _ = _fwd(q, k, v, lens, seed, sm_scale, causal, block_q, block_k,
-                  use_kv_mask, dropout_rate, interpret)
+                  use_kv_mask, dropout_rate, interpret, window)
     return out
 
 
 def _flash_fwd_rule(q, k, v, lens, seed, sm_scale, causal, block_q, block_k,
-                    use_kv_mask, dropout_rate, interpret):
+                    use_kv_mask, dropout_rate, interpret, window):
     out, lse = _fwd(q, k, v, lens, seed, sm_scale, causal, block_q, block_k,
-                    use_kv_mask, dropout_rate, interpret)
+                    use_kv_mask, dropout_rate, interpret, window)
     return out, (q, k, v, lens, seed, out, lse)
 
 
 def _flash_bwd_rule(sm_scale, causal, block_q, block_k, use_kv_mask,
-                    dropout_rate, interpret, res, do):
+                    dropout_rate, interpret, window, res, do):
     return _bwd(sm_scale, causal, block_q, block_k, use_kv_mask,
-                dropout_rate, interpret, res, do)
+                dropout_rate, interpret, window, res, do)
 
 
 _flash_bhsd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -446,8 +563,15 @@ def _pad_seq(x, to_len):
 
 def flash_attention(q, k, v, causal=False, sm_scale=None, kv_lens=None,
                     dropout_rate=0.0, dropout_seed=None,
-                    block_q=None, block_k=None, interpret=False):
-    """q/k/v: (batch, seq, num_heads, head_dim) → same-shaped output.
+                    block_q=None, block_k=None, interpret=False,
+                    window=None):
+    """q: (batch, seq, num_heads, head_dim), k/v: (batch, seq, kv_heads,
+    head_dim) with ``num_heads % kv_heads == 0`` → output shaped like q.
+    Query head ``i`` attends over KV head ``i // (num_heads // kv_heads)``.
+
+    window: optional int, causal only — query ``i`` sees key ``j`` iff
+    ``0 <= i - j < window`` (its own position and the ``window - 1``
+    before it).
 
     kv_lens: optional (batch,) int32 — per-row count of VALID key/value
     positions (a trailing-padding key mask, the (B,1,1,T) boolean
@@ -458,17 +582,34 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, kv_lens=None,
     block_q/block_k: ``None`` resolves from the tuning DB
     (``ops/pallas/tuner.py``: tuned entry → those blocks, miss → the
     compiled-in DEFAULT_BLOCK_Q/K, counted in
-    ``pallas_config_resolved_total``); explicit values bypass the DB.
+    ``pallas_config_resolved_total``), cut under a window to the power of
+    two that holds it; explicit values bypass the DB.
     """
     b, sq, h, d = q.shape
-    sk = k.shape[1]
+    sk, h_kv = k.shape[1], k.shape[2]
+    if h % h_kv or v.shape[2] != h_kv:
+        raise ValueError(f"{h} query heads cannot share {h_kv} key and "
+                         f"{v.shape[2]} value heads")
+    if window is not None:
+        window = int(window)
+        if not causal or sq != sk or window < 1:
+            raise ValueError("window needs causal self-attention "
+                             f"(causal={causal}, sq={sq}, sk={sk}) and "
+                             f"window >= 1, got {window}")
+        if window >= sk:
+            window = None               # it cuts nothing: plain causal
     # the kernels multiply in their operands' dtype: one dtype, q's
     k, v = k.astype(q.dtype), v.astype(q.dtype)
     if block_q is None or block_k is None:
-        from .tuner import flash_dims, resolve
+        from .tuner import flash_dims, resolve, shape_bucket
         cfg, _ = resolve("flash_attention", q.dtype, flash_dims(d, sq, sk),
                          {"block_q": DEFAULT_BLOCK_Q,
                           "block_k": DEFAULT_BLOCK_K})
+        if window is not None:
+            # a block wider than the window computes mostly masked scores:
+            # the band takes the row's blocks cut to the window's bucket
+            cfg = {name: min(block, shape_bucket(window))
+                   for name, block in cfg.items()}
         block_q = block_q or cfg["block_q"]
         block_k = block_k or cfg["block_k"]
     if sm_scale is None:
@@ -513,11 +654,11 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, kv_lens=None,
 
     def to_bhsd(x):
         return jnp.reshape(jnp.swapaxes(x, 1, 2),
-                           (b * h, x.shape[1], d))
+                           (b * x.shape[2], x.shape[1], d))
 
     out = _flash_bhsd(to_bhsd(qp), to_bhsd(kp), to_bhsd(vp), lens_bh,
                       seed_arr, float(sm_scale), bool(causal), int(block_q),
                       int(block_k), bool(use_kv_mask), float(dropout_rate),
-                      bool(interpret))
+                      bool(interpret), window)
     out = jnp.swapaxes(jnp.reshape(out, (b, h, sq_pad, d)), 1, 2)
     return out[:, :sq]

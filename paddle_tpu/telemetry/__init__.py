@@ -55,6 +55,14 @@ checkpoint_bytes_total         counter    distributed.checkpoint {op=...}
 pallas_config_resolved_total   counter    ops.pallas.tuner.resolve, trace
                                           time {kernel=...,
                                           source=db|default|fallback}
+moe_tokens_routed_total        counter    incubate.moe DroplessMoELayer.
+                                          publish_routing: tokens routed
+moe_held_assignments_total     counter    (token, expert) assignments on
+                                          the experts held here; none is
+                                          dropped
+moe_max_load_over_mean         gauge      fullest held expert's
+                                          assignments over the mean per
+                                          held expert, last call
 retries_total                  counter    resilience.retry {site=...}
 retry_exhausted_total          counter    resilience.retry {site=...}
 retry_bytes_abandoned_total    counter    resilience.retry byte budget

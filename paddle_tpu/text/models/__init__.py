@@ -8,3 +8,6 @@ from .bert import (  # noqa: F401
     bert_large)
 from .transformer import (  # noqa: F401
     InferTransformerModel, TransformerModel, position_encoding_init)
+from .mixed_decoder import (  # noqa: F401
+    GroupedQueryAttention, MixedDecoderBlock, MixedDecoderForPretraining,
+    MixedDecoderModel)

@@ -1,0 +1,188 @@
+"""A decoder whose layers differ: per layer, the kind of attention (full
+causal or a causal sliding window), the number of query heads, and a dense
+or a sparse (routed experts plus a shared expert) feed-forward block.
+
+What every layer shares: pre-norm residual blocks with RMSNorm, grouped KV
+heads (``kv_heads`` of width ``head_dim`` serve every layer's query heads),
+rotary embeddings whose parameters go by the kind of attention (so a model
+can rotate all lanes at one theta in its window layers and part of them,
+YaRN-scaled, in its full layers), an optional per-head sigmoid gate on the
+attention output, gated SiLU FFNs, token embeddings only, a final RMSNorm
+and an untied head. Sparse layers are ``incubate.moe.DroplessMoELayer``:
+the router's width and ``top_k`` are the model's, ``held_experts`` says
+which experts this copy holds (expert parallelism's share; the whole set by
+default).
+
+Names are what the benchmark's scope metrics read: root
+``mixeddecoderforpretraining``, trunk ``decoder``, blocks ``h.N``, in a
+block ``attn`` and ``mlp`` or ``moe``, then ``lm_head``.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from ... import nn
+from ...incubate.moe import DroplessMoELayer
+from ...nn import functional as F
+from ...nn.layer import Layer
+
+FULL, SLIDING = "full_attention", "sliding_attention"
+DENSE, SPARSE = "dense", "sparse"
+
+
+class GroupedQueryAttention(Layer):
+    """``num_heads`` query heads over ``kv_heads`` key/value heads, rotary
+    embeddings on q and k, causal with an optional window, and (``gated``)
+    a per-head ``sigmoid(x W_g)`` on the heads' outputs before ``o_proj``."""
+
+    def __init__(self, hidden_size, num_heads, kv_heads, head_dim, rope,
+                 window=None, gated=False):
+        super().__init__()
+        if num_heads % kv_heads:
+            raise ValueError(f"{num_heads} query heads over {kv_heads} KV "
+                             f"heads")
+        self.num_heads, self.kv_heads = num_heads, kv_heads
+        self.head_dim, self.window = head_dim, window
+        # rope: {"theta", "rotary_dim", "yarn" or None}
+        self.inv_freq, self.rope_scale = F.rope_frequencies(
+            rope["theta"], rope["rotary_dim"], rope.get("yarn"))
+        self.q_proj = nn.Linear(hidden_size, num_heads * head_dim,
+                                bias_attr=False)
+        self.k_proj = nn.Linear(hidden_size, kv_heads * head_dim,
+                                bias_attr=False)
+        self.v_proj = nn.Linear(hidden_size, kv_heads * head_dim,
+                                bias_attr=False)
+        self.g_proj = (nn.Linear(hidden_size, num_heads, bias_attr=False)
+                       if gated else None)
+        self.o_proj = nn.Linear(num_heads * head_dim, hidden_size,
+                                bias_attr=False)
+
+    def forward(self, x):
+        b, s, _ = x.shape
+        d = self.head_dim
+        q = jnp.reshape(self.q_proj(x), (b, s, self.num_heads, d))
+        k = jnp.reshape(self.k_proj(x), (b, s, self.kv_heads, d))
+        v = jnp.reshape(self.v_proj(x), (b, s, self.kv_heads, d))
+        q = F.rotary_embedding(q, self.inv_freq, self.rope_scale)
+        k = F.rotary_embedding(k, self.inv_freq, self.rope_scale)
+        out = F.scaled_dot_product_attention(
+            q, k, v, is_causal=True, window=self.window,
+            training=self.training)
+        if self.g_proj is not None:
+            gate = jax.nn.sigmoid(self.g_proj(x).astype(jnp.float32))
+            out = out * gate.astype(out.dtype)[..., None]
+        return self.o_proj(jnp.reshape(out, (b, s, self.num_heads * d)))
+
+
+class MixedDecoderBlock(Layer):
+    """``h = x + attn(norm(x)); y = h + ffn(norm(h))`` with the sublayer
+    ``mlp`` (dense) or ``moe`` (sparse)."""
+
+    def __init__(self, attn: Layer, ffn: Layer, sparse: bool, hidden_size,
+                 epsilon):
+        super().__init__()
+        self.input_norm = nn.RMSNorm(hidden_size, epsilon)
+        self.attn = attn
+        self.post_attn_norm = nn.RMSNorm(hidden_size, epsilon)
+        if sparse:
+            self.moe = ffn
+        else:
+            self.mlp = ffn
+        self._ffn_name = "moe" if sparse else "mlp"
+
+    def forward(self, x):
+        x = x + self.attn(self.input_norm(x))
+        return x + getattr(self, self._ffn_name)(self.post_attn_norm(x))
+
+
+def _call_checkpointed(block: Layer, x):
+    """``block(x)`` under ``jax.checkpoint``: its activations are recomputed
+    in the backward pass. What the block writes to its buffers (an expert
+    layer's counts) leaves the checkpointed function as values and is put
+    back, so no tracer of the inner trace stays in a buffer."""
+    owners = [(layer, name)
+              for _, layer in block.named_sublayers(include_self=True)
+              for name, value in layer._buffers.items() if value is not None]
+
+    def run(x_):
+        y = block(x_)
+        return y, [layer._buffers[name] for layer, name in owners]
+
+    y, values = jax.checkpoint(run)(x)
+    for (layer, name), value in zip(owners, values):
+        layer._buffers[name] = value
+    return y
+
+
+class MixedDecoderModel(Layer):
+    """Embedding, the blocks, the final norm.
+
+    ``layer_types[i]`` is ``"full_attention"`` or ``"sliding_attention"``,
+    ``heads_per_layer[i]`` the layer's query heads, ``mlp_layer_types[i]``
+    ``"dense"`` or ``"sparse"``. ``rope[kind]`` holds ``theta``,
+    ``rotary_dim`` and optionally ``yarn`` for each kind of attention.
+    ``checkpoint_blocks`` recomputes each block in the backward pass (the
+    trainer's ``remat`` checkpoints the whole model at once).
+    ``embedding_attr`` is ``nn.Embedding``'s ``weight_attr``. Its default,
+    Xavier over vocab x hidden, is about 0.01 under blocks that write to the
+    residual stream at unit scale: every token of a row then reaches a
+    fresh router with nearly the same hidden state (the attention's running
+    mean) and goes to the same few experts."""
+
+    def __init__(self, vocab_size, hidden_size, layer_types, heads_per_layer,
+                 mlp_layer_types, kv_heads, head_dim, rope, sliding_window,
+                 intermediate_size, num_experts=0, experts_per_token=0,
+                 expert_size=0, shared_expert_size=0, held_experts=None,
+                 routed_scaling_factor=1.0, gated_attention=False,
+                 epsilon=1e-6, checkpoint_blocks=False, embedding_attr=None):
+        super().__init__()
+        if not (len(layer_types) == len(heads_per_layer)
+                == len(mlp_layer_types)):
+            raise ValueError("the three per-layer lists differ in length")
+        self.hidden_size = hidden_size
+        self.checkpoint_blocks = checkpoint_blocks
+        self.embed_tokens = nn.Embedding(vocab_size, hidden_size,
+                                         weight_attr=embedding_attr)
+        blocks = []
+        for kind, heads, ffn_kind in zip(layer_types, heads_per_layer,
+                                         mlp_layer_types):
+            if kind not in (FULL, SLIDING) or ffn_kind not in (DENSE, SPARSE):
+                raise ValueError(f"unknown layer kinds {kind!r}, {ffn_kind!r}")
+            attn = GroupedQueryAttention(
+                hidden_size, heads, kv_heads, head_dim, rope[kind],
+                window=sliding_window if kind == SLIDING else None,
+                gated=gated_attention)
+            if ffn_kind == SPARSE:
+                ffn = DroplessMoELayer(
+                    hidden_size, expert_size, num_experts, experts_per_token,
+                    held=held_experts,
+                    routed_scaling_factor=routed_scaling_factor,
+                    d_shared=shared_expert_size)
+            else:
+                ffn = nn.GatedSiluFFN(hidden_size, intermediate_size)
+            blocks.append(MixedDecoderBlock(attn, ffn, ffn_kind == SPARSE,
+                                            hidden_size, epsilon))
+        self.h = nn.LayerList(blocks)
+        self.norm = nn.RMSNorm(hidden_size, epsilon)
+
+    def forward(self, input_ids):
+        x = self.embed_tokens(input_ids)
+        for block in self.h:
+            x = (_call_checkpointed(block, x) if self.checkpoint_blocks
+                 else block(x))
+        return self.norm(x)
+
+
+class MixedDecoderForPretraining(Layer):
+    """Trunk and an untied head: ``forward`` gives the logits."""
+
+    def __init__(self, decoder: MixedDecoderModel = None, **kwargs):
+        super().__init__()
+        self.decoder = decoder or MixedDecoderModel(**kwargs)
+        vocab = self.decoder.embed_tokens.num_embeddings
+        self.lm_head = nn.Linear(self.decoder.hidden_size, vocab,
+                                 bias_attr=False)
+
+    def forward(self, input_ids):
+        return self.lm_head(self.decoder(input_ids))

@@ -261,7 +261,9 @@ F_NONDIFF = {"one_hot", "sequence_mask", "gather_tree",
                                      # test_nn_extras.py)
 F_STOCHASTIC = {"dropout", "dropout2d", "dropout3d", "alpha_dropout",
                 "rrelu", "gumbel_softmax"}
-F_UTILITY = set()
+F_UTILITY = {"rope_frequencies"}  # host-side table (numpy) from a layer's
+                                  # RoPE parameters; hand values in
+                                  # test_mixed_decoder.py
 
 F_CONFIGS = {
     "adaptive_avg_pool1d": lambda: (lambda x: F.adaptive_avg_pool1d(x, 2),
@@ -374,6 +376,8 @@ F_CONFIGS = {
     "pixel_unshuffle": lambda: (lambda x: F.pixel_unshuffle(x, 2),
                                 _x((1, 1, 4, 4))),
     "prelu": lambda: (lambda x: F.prelu(x - 0.6, jnp.asarray([0.2])), _x()),
+    "rotary_embedding": lambda: (lambda x: F.rotary_embedding(
+        x, F.rope_frequencies(10000.0, 2)[0], 1.3), _x((1, 3, 2, 4))),
     "scaled_dot_product_attention": lambda: (
         lambda x: F.scaled_dot_product_attention(x, x, x),
         _x((1, 4, 2, 4))),

@@ -92,6 +92,50 @@ def test_flash_attention_fwd_bwd_gpt_base(v5e, shape):
     _compile(f, v5e, qkv, qkv, qkv)
 
 
+@pytest.mark.parametrize("heads, window, blocks", [
+    (48, None, (1024, 1024)), (64, 512, (512, 512))],
+    ids=["full_48_over_8", "window_512_64_over_8"])
+def test_flash_grouped_heads_and_window_at_the_mixed_decoder_shapes(
+        v5e, heads, window, blocks):
+    """laguna-xs2.seq4096's two kinds of layer, rows of 4,096 positions at
+    head width 128 over 8 KV heads: the kernels lower and fit in the blocks
+    of the tuning DB's row (cut to the window where there is one: the grid
+    says which ran), and the dk/dv kernel's grid runs over the KV heads."""
+    import re
+
+    from paddle_tpu.analysis.walker import walk
+    from paddle_tpu.ops.pallas import tuner
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+
+    cfg, source = tuner.resolve(
+        "flash_attention", jnp.bfloat16, tuner.flash_dims(128, 4096, 4096), {})
+    assert source == "db" and (cfg["block_q"], cfg["block_k"]) == (1024, 1024)
+
+    def f(q, k, v):
+        return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, window=window).astype(jnp.float32) ** 2),
+            argnums=(0, 1, 2))(q, k, v)
+
+    kv = ((2, 4096, 8, 128), jnp.bfloat16)
+    # (rows x heads, query blocks, key blocks walked): the whole row of
+    # 1,024-key blocks without a window, the band's two of 512 with it
+    jaxpr = jax.make_jaxpr(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window))(
+            *(jax.ShapeDtypeStruct(*a) for a in (
+                ((2, 4096, heads, 128), jnp.bfloat16), kv, kv)))
+    grids = [site.eqn.params["grid_mapping"].grid for site in walk(jaxpr)
+             if site.eqn.primitive.name == "pallas_call"]
+    assert grids == [(2 * heads, 4096 // blocks[0],
+                      2 if window else 4096 // blocks[1])]
+    text = _compile(f, v5e, ((2, 4096, heads, 128), jnp.bfloat16), kv, kv)
+    dkv = [line for line in text.splitlines()
+           if "tpu_custom_call" in line and "flash_bwd_dkv" in line]
+    # dk and dv come out per KV head (2 rows x 8), q goes in per query head
+    assert dkv and re.search(
+        r"= \(bf16\[16,4096,128\]\S*, bf16\[16,4096,128\]", dkv[0])
+    assert f"bf16[{2 * heads},4096,128]" in dkv[0]
+
+
 @pytest.mark.parametrize("h,dtype", [(768, jnp.bfloat16),
                                      (768, jnp.float32),
                                      (2048, jnp.bfloat16)])
