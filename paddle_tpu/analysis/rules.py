@@ -590,10 +590,8 @@ def pallas_config_untuned(ctx):
         if kernel_name not in (flash_fwd, "_ce_fwd_kernel",
                                "_paged_decode_kernel"):
             continue
-        grid = getattr(site.eqn.params.get("grid_mapping"), "grid", ())
-        avals = [getattr(v, "aval", None) for v in site.eqn.invars]
         try:
-            key, entry = entry_for_traced_call(kernel_name, avals, grid)
+            key, entry = entry_for_traced_call(kernel_name, site.eqn)
         except Exception:
             continue
         if key is None or entry is not None or key in seen:

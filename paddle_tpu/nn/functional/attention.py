@@ -81,9 +81,9 @@ def _sdpa(query, key, value, attn_mask, dropout_p, is_causal, training,
           kv_lens, window):
     from ...ops.pallas.flash_attention import flash_attention, flash_supported
     # The gate at 512 positions has no run at 512 on record. What the
-    # benchmark's cells measured (PERF.md sections 5 and 6, PR 26): at
+    # benchmark's cells measured (PERF.md sections 5 and 6, PRs 26, 28): at
     # 1,024 positions the kernels and the layout copies around them take
-    # 43.7 ms of gpt2-small's 129.6 ms step, at 256 positions this XLA
+    # 39.1 ms of gpt2-small's 126.1 ms step, at 256 positions this XLA
     # path takes 42.0 ms of a 209.1 ms step for the same tokens. Re-judging
     # the gate is queued in PERF.md section 7. Dropout and kv_lens padding
     # masks run inside the kernel; only dense attn_mask tensors force the

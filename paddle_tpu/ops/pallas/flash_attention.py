@@ -38,18 +38,33 @@ reach HBM. ``window=w`` (causal only) lets query ``i`` see key ``j`` iff
 ``0 <= i - j < w``: the kernels mask by it, and the grid's inner dimension
 runs over the band of blocks a window leaves (first block from the index
 map) instead of over all of them, so blocks wholly outside the window cost
-neither a grid step nor a DMA. Without a window and with equal head counts
-the staged kernels are what they were.
+neither a grid step nor a DMA.
 
 Trace names: each ``pallas_call`` carries ``name=`` (``flash_fwd``,
 ``flash_bwd_dq``, ``flash_bwd_dkv``). That names the kernel's op in a
 device trace and stages it under a ``jax.named_scope`` of the same string
-(``.../attn/sdpa/flash/flash_bwd_dq/pallas_call`` in the op's ``tf_op``),
-so a reader finds the kernels whatever their operands are.
+(``.../attn/sdpa/flash/jit(_bwd_calls)/flash_bwd_dq/pallas_call`` in the
+op's ``tf_op``), so a reader finds the kernels whatever their operands are.
 
-TPU layout notes: per-row stats (m, l, lse, delta) are carried at LANE=8
-width (last dim equal to the array dim satisfies Mosaic's tiling rule);
-VMEM scratch uses full (block, 128) tiles.
+TPU layout notes. The kernels' HBM operands are ``(batch x lane blocks,
+seq, lanes)``: a lane block is one head at head width 128 or 256 and
+``128 // head_dim`` neighbouring heads side by side below that (two at
+width 64), so every row the kernels move is whole 128-lane tiles, and one
+grid step does the heads of its block one after the other (``_Pack``). A
+``(batch x heads, seq, 64)`` bf16 operand, what width 64 ran on before,
+occupies 128 lanes in HBM: every copy into that form wrote, and every
+block the kernels fetched read, twice its bytes. Measured on a v5e
+(PERF.md section 6, PR 28; one attention layer of gpt2-small with its
+projections, forward + backward, device ms): 5.341 with one head a block,
+4.920 with two; with the operands left as the projections write them,
+``(batch, seq, heads x head_dim)``, and the lane block found by the index
+map: 5.131 (its blocks are 4 KB pieces a row apart; at head width 128,
+where XLA already lays the projections' outputs out as ``(batch, heads,
+seq, 128)`` so that the transposition costs nothing, its kernels read 4%
+slower and it adds 3.3-4.0 ms of copies a layer).
+Per-row stats (m, l, lse, delta) are carried at LANE=8 width, a row a head
+(last dim equal to the array dim satisfies Mosaic's tiling rule); VMEM
+scratch uses full (block, 128) tiles.
 
 Public API: flash_attention(q, k, v, causal=False, sm_scale=None,
 kv_lens=None, dropout_rate=0.0, dropout_seed=None, window=None)
@@ -66,17 +81,19 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Measured on a v5e at the cells' shape (PERF.md section 6, PR 26: bf16
-# (16, 1024, 12, 64), causal, forward + backward of one layer, device ms of
-# every op): (128, 128) 18.34, (256, 256) 8.90, (256, 512) 6.14,
-# (512, 512) 5.01, (256, 1024) 4.90, (512, 1024) 4.15, (1024, 1024) 3.88.
-# Nothing inside a tile moves those times (operand dtype, masks, exp):
-# they follow the grid steps (about 0.26 us each) and the bytes the blocks
-# bring from HBM in its padded tiles (head width 64 in 128 lanes, the
-# 8-lane statistics in 128), K/V fetched again for each q block. So larger
-# blocks are faster, and the tuning DB's row for bf16, width 64 and 513 to
-# 1,024 positions says (1024, 1024): one tile a head. These defaults serve
-# the calls no row covers; no run on record has measured them there.
+# Measured on a v5e at the cells' shape (bf16 (16, 1024, 12, 64), causal,
+# forward + backward of one layer, device ms of every op). One head a
+# block, padded to 128 lanes (PERF.md section 6, PR 26): (128, 128) 18.34,
+# (256, 256) 8.90, (256, 512) 6.14, (512, 512) 5.01, (256, 1024) 4.90,
+# (512, 1024) 4.15, (1024, 1024) 3.88. Two heads a block (PR 28): (512,
+# 512) 4.25, (512, 1024) 3.86, (1024, 1024) 3.54. Nothing inside a tile
+# moved those times in PR 26's copies (operand dtype, masks, exp): they
+# follow the grid steps (about 0.26 us each) and the bytes the blocks bring
+# from HBM (the 8-lane statistics in 128-lane tiles), K/V fetched again for
+# each q block. So larger blocks are faster, and the tuning DB's row for
+# bf16, width 64 and 513 to 1,024 positions says (1024, 1024): one tile a
+# lane block. These defaults serve the calls no row covers; no run on
+# record has measured them there.
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 512
 LANES = 128
@@ -95,19 +112,6 @@ def _scores(q, k, sm_scale):
     is applied to the float32 scores, never to a q rounded back to bf16."""
     return sm_scale * jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-
-
-def _causal_mask(s, iq, ik, block_q, block_k, window=None):
-    """Keep key ``col`` for query ``row`` iff ``0 <= row - col`` and, with
-    a window, ``row - col < window``."""
-    rows = iq * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    cols = ik * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    keep = rows >= cols
-    if window is not None:
-        keep = keep & (rows - cols < window)
-    return jnp.where(keep, s, NEG_INF)
 
 
 def _floor(x, lo):
@@ -150,13 +154,6 @@ class _Band:
                     self.nq - 1)
 
 
-def _kv_mask(s, ik, block_k, kv_len):
-    """Mask key columns >= kv_len (padding tail or per-batch padding)."""
-    cols = ik * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, s.shape, 1)
-    return jnp.where(cols < kv_len, s, NEG_INF)
-
-
 def _dropout_mask(shape, rate, seed, b, iq, ik):
     """Deterministic per-block inverted-dropout multiplier in {0, 1/keep}.
 
@@ -189,15 +186,143 @@ def _dropout_mask(shape, rate, seed, b, iq, ik):
 
 
 # ---------------------------------------------------------------------------
+# heads in lane blocks
+# ---------------------------------------------------------------------------
+class _Pack:
+    """How heads lie in the kernels' lane blocks.
+
+    An operand is ``(batch x lane blocks, seq, lanes)`` with ``lanes =
+    max(LANES, d)``: a lane block is one head at width 128 or 256, ``n =
+    LANES // d`` heads side by side below that (two at width 64), so that no
+    row of HBM is padded; one grid step does a block's heads one after the
+    other (``each_head``). Lane block ``hb`` of q holds query heads ``hb*n
+    .. hb*n + n - 1`` (``hb`` counts through the batch: ``batch * blocks +
+    block``); they read KV lane block ``hb // group`` (a q block never
+    straddles two), where query head ``hb*n + j`` finds its KV head in slot
+    ``kv_slot(hb, j)``.
+
+    A head inside a block is addressed without slicing: ``place(x, j, t)``
+    keeps slot ``j``'s lanes of ``x``, zeros the others and, where ``t`` is
+    another slot, moves them there. A product with such an operand
+    contracts over all lanes (the zeros are exact) or comes out with the
+    other slots' lanes zero; it costs the MXU what a ``d``-deep or
+    ``d``-wide product costs, a full pass of its 128 x 128 array. With one
+    head a block every method is the identity, and the staged kernels are
+    what they were."""
+
+    def __init__(self, d, group, heads=None, stored=None):
+        self.d, self.group = d, group
+        self.n = max(1, LANES // d)
+        self.lanes = max(LANES, d)
+        # query heads a batch row: the caller's, and as stored (zero heads
+        # fill the last lane block)
+        self.heads, self.stored = heads, stored
+
+    def each_head(self, body, carry=None):
+        """``carry = body(j, carry)`` for every slot ``j`` of a lane block.
+        With more than one head a block the slots are the trips of one
+        ``fori_loop``, so the kernel holds (and Mosaic compiles) one head's
+        body whatever ``n`` is; ``j`` is then a traced scalar."""
+        if self.n == 1:
+            return body(0, carry)
+        return jax.lax.fori_loop(0, self.n, body, carry)
+
+    def head_id(self, hb, j):
+        """``batch * heads + head`` of slot ``j`` of lane block ``hb``, in
+        the caller's count of heads: what the dropout hash is fed. Stored
+        head ``hb*n + j`` is head ``% stored`` of batch row ``// stored``;
+        the zero heads past the caller's are sliced off, whatever id they
+        get."""
+        g = hb * self.n + j
+        if self.stored == self.heads:
+            return g
+        return (g // self.stored) * self.heads + g % self.stored
+
+    def kv_slot(self, hb, j):
+        """The slot of its KV lane block that query head ``hb*n + j``
+        reads: ``j`` itself with equal head counts."""
+        if self.group == 1 or self.n == 1:
+            return j
+        return ((hb * self.n + j) // self.group) % self.n
+
+    def _slot(self, shape):
+        # lane >> log2(d): below a lane block d is a power of two. (An
+        # integer ``//`` stages two ``sign``s, and each costs Pallas' TPU
+        # lowering a traced helper: 1.9 s of host time over a step's 36
+        # kernels; my chip run, PR 28.)
+        return jax.lax.shift_right_logical(
+            jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1),
+            self.d.bit_length() - 1)
+
+    def place(self, x, src, dst):
+        if self.n == 1:
+            return x
+        slot = self._slot(x.shape)
+        x = jnp.where(slot == src, x, jnp.zeros_like(x))
+        if src is dst:
+            return x
+        # grouped heads: the KV head's slot is another than the query
+        # head's. Copy the kept lanes into every slot (static rotations,
+        # which Mosaic has for 32-bit lanes only: the widening is exact)
+        # and keep ``dst``'s.
+        wide = x.astype(jnp.float32)
+        spread = wide
+        for r in range(1, self.n):
+            spread = spread + pltpu.roll(wide, r * self.d, axis=1)
+        return jnp.where(slot == dst, spread, 0.0).astype(x.dtype)
+
+    def where(self, j, x, other, shape):
+        """``x`` in slot ``j``'s lanes of a ``shape`` tile, ``other`` in
+        the rest."""
+        if self.n == 1:
+            return x
+        return jnp.where(self._slot(shape) == j, x, other)
+
+    def head_sums(self, x):
+        """``(blocks, seq, lanes)`` summed over each head's lanes, in the
+        statistics' row order: ``(blocks x n, seq)``. Every sum runs over
+        whole lane blocks under a mask, so XLA reduces the minor axis as it
+        lies and transposes nothing."""
+        if self.n == 1:
+            return jnp.sum(x, axis=-1)
+        slot = self._slot((1, 1, x.shape[-1]))
+        sums = [jnp.sum(jnp.where(slot == j, x, 0.0), axis=-1)
+                for j in range(self.n)]
+        return jnp.reshape(jnp.stack(sums, axis=1),
+                           (x.shape[0] * self.n, x.shape[1]))
+
+
+def _visible(iq, ik, block_q, block_k, causal, window, kv_len):
+    """Which (query row, key column) pairs of tile ``(iq, ik)`` may meet,
+    or None where all do: ``0 <= row - col`` under ``causal``, ``row - col
+    < window`` with a window, ``col < kv_len`` with a key-padding mask
+    (``kv_len`` None without). One tile serves every head of a step."""
+    if not causal and kv_len is None:
+        return None
+    cols = ik * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 1)
+    keep = None
+    if causal:
+        rows = iq * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        keep = rows >= cols
+        if window is not None:
+            keep = keep & (rows - cols < window)
+    if kv_len is not None:
+        keep = (cols < kv_len) if keep is None else keep & (cols < kv_len)
+    return keep
+
+
+# ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
-def _fwd_kernel(lens_ref, seed_ref,       # (1,STAT) i32, (1,STAT) i32
-                q_ref, k_ref, v_ref,      # (1,Bq,D), (1,Bk,D), (1,Bk,D)
-                o_ref, lse_ref,           # (1,Bq,D), (1,Bq,STAT_LANES)
-                m_scr, l_scr, acc_scr,    # (Bq,LANES),(Bq,LANES),(Bq,D)
+def _fwd_kernel(lens_ref, seed_ref,       # (blocks,) i32, (1,) i32 in SMEM
+                q_ref, k_ref, v_ref,      # (1,Bq,L), (1,Bk,L), (1,Bk,L)
+                o_ref, lse_ref,           # (1,Bq,L), (n,Bq,STAT_LANES)
+                m_scr, l_scr, acc_scr,    # (n,Bq,LANES) x 2, (Bq,L)
                 *, sm_scale, causal, block_q, block_k, num_k_blocks,
-                use_kv_mask, dropout_rate, band=None):
-    b = pl.program_id(0)
+                use_kv_mask, dropout_rate, pack, band=None):
+    hb = pl.program_id(0)
     iq = pl.program_id(1)
     step = pl.program_id(2)
     # under a window the inner dimension walks the band's key blocks only
@@ -217,52 +342,67 @@ def _fwd_kernel(lens_ref, seed_ref,       # (1,STAT) i32, (1,STAT) i32
 
     @pl.when(run)
     def _compute():
-        s = _scores(q_ref[0], k_ref[0], sm_scale)
-        if causal:
-            s = _causal_mask(s, iq, ik, block_q, block_k, window)
-        if use_kv_mask:
-            s = _kv_mask(s, ik, block_k, lens_ref[b])
-        m_prev = m_scr[:, :1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        if causal or use_kv_mask:
-            # NEG_INF is finite, so a FULLY-masked row has m_new == s and
-            # p == exp(0) == 1 — zero masked entries explicitly so l is 0
-            # for such rows (out = 0, lse pinned to 0, no K/V grad leak)
-            p = p * (s > NEG_INF * 0.5)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-        if dropout_rate > 0.0:
-            # normalizer l uses the UNdropped p (softmax semantics); only
-            # the value accumulation is dropped
-            p = p * _dropout_mask(p.shape, dropout_rate, seed_ref[0],
-                                  b, iq, ik)
-        v = v_ref[0]
-        pv = jax.lax.dot_general(p.astype(v.dtype), v,
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        kv_len = lens_ref[hb] if use_kv_mask else None
+
+        def head(j, _):
+            q, k, v = q_ref[0], k_ref[0], v_ref[0]
+            keep = _visible(iq, ik, block_q, block_k, causal, window, kv_len)
+            t = pack.kv_slot(hb, j)
+            s = _scores(pack.place(q, j, t), k, sm_scale)
+            if keep is not None:
+                s = jnp.where(keep, s, NEG_INF)
+            m_prev = m_scr[j, :, :1]
+            m_cur = jnp.max(s, axis=1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            p = jnp.exp(s - m_new)
+            if keep is not None:
+                # NEG_INF is finite, so a FULLY-masked row has m_new == s
+                # and p == exp(0) == 1 — zero masked entries explicitly so
+                # l is 0 for such rows (out = 0, lse pinned to 0, no K/V
+                # grad leak)
+                p = p * (s > NEG_INF * 0.5)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = alpha * l_scr[j, :, :1] + jnp.sum(p, axis=1,
+                                                      keepdims=True)
+            if dropout_rate > 0.0:
+                # normalizer l uses the UNdropped p (softmax semantics);
+                # only the value accumulation is dropped
+                p = p * _dropout_mask(p.shape, dropout_rate, seed_ref[0],
+                                      pack.head_id(hb, j), iq, ik)
+            pv = jax.lax.dot_general(p.astype(v.dtype), v,
+                                     (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            acc = acc_scr[:]
+            acc_scr[:] = (acc * pack.where(j, alpha, 1.0, acc.shape)
+                          + pack.place(pv, t, j))
+            m_scr[j] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[j] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+
+        pack.each_head(head)
 
     @pl.when(step == num_k_blocks - 1)
     def _finalize():
-        l = l_scr[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
-        # fully-masked rows (l == 0, e.g. padded queries) pin lse to 0 so
-        # the backward's p = exp(NEG_INF - lse) is 0, not NaN
-        lse = jnp.where(l == 0.0, 0.0, m_scr[:, :1] + jnp.log(l_safe))
-        lse_ref[0] = jnp.broadcast_to(lse, (block_q, STAT_LANES))
+        def head(j, l_all):
+            l = l_scr[j, :, :1]
+            l_safe = jnp.where(l == 0.0, 1.0, l)
+            # fully-masked rows (l == 0, e.g. padded queries) pin lse to 0
+            # so the backward's p = exp(NEG_INF - lse) is 0, not NaN
+            lse = jnp.where(l == 0.0, 0.0, m_scr[j, :, :1] + jnp.log(l_safe))
+            lse_ref[j] = jnp.broadcast_to(lse, (block_q, STAT_LANES))
+            return pack.where(j, l_safe, l_all, acc_scr.shape)
+
+        l_all = pack.each_head(head, None if pack.n == 1 else jnp.ones(
+            acc_scr.shape, jnp.float32))
+        o_ref[0] = (acc_scr[:] / l_all).astype(o_ref.dtype)
 
 
 def _kv_index_maps(group, band):
-    """The K/V block index map of the two kernels whose grid is (q heads,
-    q blocks, key steps). Query head ``b`` reads KV head ``b // group``;
-    under a window step ``j`` is key block ``k_first(i) + j``, held at the
-    band's last block once past it so that the skipped steps fetch nothing
-    new. Ungrouped and unwindowed it is the plain ``(b, j, 0)``."""
+    """The K/V block index map of the two kernels whose grid is (q lane
+    blocks, q blocks, key steps). Query lane block ``b`` reads KV lane block
+    ``b // group``; under a window step ``j`` is key block ``k_first(i) +
+    j``, held at the band's last block once past it so that the skipped
+    steps fetch nothing new. Ungrouped and unwindowed it is the plain
+    ``(b, j, 0)``."""
     def head(b):
         return b if group == 1 else b // group
 
@@ -272,42 +412,60 @@ def _kv_index_maps(group, band):
         head(b), jnp.minimum(band.k_first(i) + j, band.k_last(i)), 0)
 
 
-def _fwd(q, k, v, lens, seed, sm_scale, causal, block_q, block_k,
-         use_kv_mask, dropout_rate, interpret=False, window=None):
-    bh, sq, d = q.shape
-    sk = k.shape[1]
-    group = bh // k.shape[0]
+def _geometry(q, k, d, heads, block_q, block_k, window):
+    """What the three calls share, from the operands' shapes; ``heads`` is
+    the pair (the caller's query heads, the stored ones)."""
+    blocks, sq, sk = q.shape[0], q.shape[1], k.shape[1]
+    group = blocks // k.shape[0]
     nq = pl.cdiv(sq, block_q)
     nk = pl.cdiv(sk, block_k)
     band = None if window is None else _Band(window, block_q, block_k, nq, nk)
+    return blocks, sq, group, _Pack(d, group, *heads), nq, nk, band
+
+
+# ``_fwd`` and ``_bwd_calls`` are jitted so that the layers of a model share
+# one staged forward and one staged backward: equal shapes and statics hit
+# jit's cache, the kernels are traced once and lowered to Mosaic once a
+# program (a private function the layers call), not once a layer. The call
+# site's scopes stay in front of ``jit(_fwd)/flash_fwd`` in an op's name, and
+# XLA inlines the calls: the compiled program is the same. (Set-up of
+# gpt2-small.seq1024 from a warm compile cache, 36 call sites: staged a
+# layer 38-44 s with one head a block and 49-55 s with two, staged once
+# 32-35 s; PERF.md section 6, PR 28.)
+@functools.partial(jax.jit, static_argnums=tuple(range(5, 15)))
+def _fwd(q, k, v, lens, seed, sm_scale, causal, block_q, block_k,
+         use_kv_mask, dropout_rate, interpret, window, d, heads):
+    blocks, sq, group, pack, nq, nk, band = _geometry(
+        q, k, d, heads, block_q, block_k, window)
+    lanes, n = pack.lanes, pack.n
     steps = nk if band is None else band.k_steps
     kv_map = _kv_index_maps(group, band)
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
         block_k=block_k, num_k_blocks=steps, use_kv_mask=use_kv_mask,
-        dropout_rate=dropout_rate, band=band)
+        dropout_rate=dropout_rate, pack=pack, band=band)
     out, lse = pl.pallas_call(
         kernel,
-        grid=(bh, nq, steps),
+        grid=(blocks, nq, steps),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), kv_map),
-            pl.BlockSpec((1, block_k, d), kv_map),
+            pl.BlockSpec((1, block_q, lanes), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_k, lanes), kv_map),
+            pl.BlockSpec((1, block_k, lanes), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, STAT_LANES), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, lanes), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((n, block_q, STAT_LANES), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, sq, STAT_LANES), jnp.float32),
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((blocks * n, sq, STAT_LANES), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((n, block_q, LANES), jnp.float32),
+            pltpu.VMEM((n, block_q, LANES), jnp.float32),
+            pltpu.VMEM((block_q, lanes), jnp.float32),
         ],
         interpret=interpret,
         name=FWD,
@@ -321,8 +479,8 @@ def _fwd(q, k, v, lens, seed, sm_scale, causal, block_q, block_k,
 def _bwd_dq_kernel(lens_ref, seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                    delta_ref, dq_ref, dq_scr,
                    *, sm_scale, causal, block_q, block_k, num_k_blocks,
-                   use_kv_mask, dropout_rate, band=None):
-    b = pl.program_id(0)
+                   use_kv_mask, dropout_rate, pack, band=None):
+    hb = pl.program_id(0)
     iq = pl.program_id(1)
     step = pl.program_id(2)
     ik = step if band is None else band.k_first(iq) + step
@@ -339,23 +497,29 @@ def _bwd_dq_kernel(lens_ref, seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     @pl.when(run)
     def _compute():
-        k = k_ref[0]
-        s = _scores(q_ref[0], k, sm_scale)
-        if causal:
-            s = _causal_mask(s, iq, ik, block_q, block_k, window)
-        if use_kv_mask:
-            s = _kv_mask(s, ik, block_k, lens_ref[b])
-        p = jnp.exp(s - lse_ref[0][:, :1])
-        dp = jax.lax.dot_general(do_ref[0], v_ref[0],
-                                 (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        if dropout_rate > 0.0:
-            dp = dp * _dropout_mask(dp.shape, dropout_rate, seed_ref[0],
-                                    b, iq, ik)
-        ds = p * (dp - delta_ref[0][:, :1])
-        dq_scr[:] += sm_scale * jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        kv_len = lens_ref[hb] if use_kv_mask else None
+
+        def head(j, _):
+            q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+            keep = _visible(iq, ik, block_q, block_k, causal, window, kv_len)
+            t = pack.kv_slot(hb, j)
+            s = _scores(pack.place(q, j, t), k, sm_scale)
+            if keep is not None:
+                s = jnp.where(keep, s, NEG_INF)
+            p = jnp.exp(s - lse_ref[j, :, :1])
+            dp = jax.lax.dot_general(pack.place(do, j, t), v,
+                                     (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            if dropout_rate > 0.0:
+                dp = dp * _dropout_mask(dp.shape, dropout_rate, seed_ref[0],
+                                        pack.head_id(hb, j), iq, ik)
+            ds = p * (dp - delta_ref[j, :, :1])
+            dq = jax.lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dq_scr[:] += sm_scale * pack.place(dq, t, j)
+
+        pack.each_head(head)
 
     @pl.when(step == num_k_blocks - 1)
     def _finalize():
@@ -365,16 +529,17 @@ def _bwd_dq_kernel(lens_ref, seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 def _bwd_dkv_kernel(lens_ref, seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, dk_ref, dv_ref, dk_scr, dv_scr,
                     *, sm_scale, causal, block_q, block_k, num_q_blocks,
-                    use_kv_mask, dropout_rate, group=1, band=None):
-    # the grid's first dimension is the KV head; the inner one walks the
-    # group's query heads, and for each the q blocks (all of them, or the
-    # band a window leaves): ``num_q_blocks`` steps a query head
-    b = pl.program_id(0)
+                    use_kv_mask, dropout_rate, pack, band=None):
+    # the grid's first dimension is the KV lane block; the inner one walks
+    # the group's query lane blocks, and for each the q blocks (all of
+    # them, or the band a window leaves): ``num_q_blocks`` steps each
+    group = pack.group
+    hb = pl.program_id(0)
     ik = pl.program_id(1)
     step = pl.program_id(2)
     jq = step
     if group != 1:
-        b = b * group + step // num_q_blocks
+        hb = hb * group + step // num_q_blocks
         jq = step % num_q_blocks
     iq = jq if band is None else band.q_first(ik) + jq
     window = None if band is None else band.window
@@ -391,32 +556,39 @@ def _bwd_dkv_kernel(lens_ref, seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0]
-        s = _scores(q, k_ref[0], sm_scale)
-        if causal:
-            s = _causal_mask(s, iq, ik, block_q, block_k, window)
-        if use_kv_mask:
-            s = _kv_mask(s, ik, block_k, lens_ref[b])
-        p = jnp.exp(s - lse_ref[0][:, :1])          # (Bq, Bk)
-        if dropout_rate > 0.0:
-            m = _dropout_mask(p.shape, dropout_rate, seed_ref[0],
-                              b, iq, ik)
-            p_drop = p * m
-        else:
-            m = None
-            p_drop = p
-        do = do_ref[0]                              # (Bq, D)
-        dv_scr[:] += jax.lax.dot_general(p_drop.astype(do.dtype), do,
-                                         (((0,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v_ref[0], (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        if m is not None:
-            dp = dp * m
-        ds = p * (dp - delta_ref[0][:, :1])         # (Bq, Bk)
-        dk_scr[:] += sm_scale * jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        kv_len = lens_ref[hb] if use_kv_mask else None
+
+        def head(j, _):
+            q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+            keep = _visible(iq, ik, block_q, block_k, causal, window, kv_len)
+            # q and do of head j in its KV head's lanes, zeros elsewhere:
+            # dv and dk come out with the other slots' lanes zero
+            t = pack.kv_slot(hb, j)
+            q_j, do_j = pack.place(q, j, t), pack.place(do, j, t)
+            s = _scores(q_j, k, sm_scale)
+            if keep is not None:
+                s = jnp.where(keep, s, NEG_INF)
+            p = jnp.exp(s - lse_ref[j, :, :1])          # (Bq, Bk)
+            if dropout_rate > 0.0:
+                m = _dropout_mask(p.shape, dropout_rate, seed_ref[0],
+                                  pack.head_id(hb, j), iq, ik)
+                p_drop = p * m
+            else:
+                m = None
+                p_drop = p
+            dv_scr[:] += jax.lax.dot_general(
+                p_drop.astype(do.dtype), do_j, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(do_j, v, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            if m is not None:
+                dp = dp * m
+            ds = p * (dp - delta_ref[j, :, :1])         # (Bq, Bk)
+            dk_scr[:] += sm_scale * jax.lax.dot_general(
+                ds.astype(q.dtype), q_j, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        pack.each_head(head)
 
     @pl.when(step == group * num_q_blocks - 1)
     def _finalize():
@@ -425,124 +597,134 @@ def _bwd_dkv_kernel(lens_ref, seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 
 def _bwd(sm_scale, causal, block_q, block_k, use_kv_mask, dropout_rate,
-         interpret, window, res, do):
+         interpret, window, d, heads, res, do):
+    lens, seed = res[3], res[4]
+    dq, dk, dv = _bwd_calls(sm_scale, causal, block_q, block_k, use_kv_mask,
+                            dropout_rate, interpret, window, d, heads, res, do)
+    # int-array inputs (lens, seed) take float0 cotangents
+    return (dq, dk, dv, np.zeros(lens.shape, jax.dtypes.float0),
+            np.zeros(seed.shape, jax.dtypes.float0))
+
+
+@functools.partial(jax.jit, static_argnums=tuple(range(10)))
+def _bwd_calls(sm_scale, causal, block_q, block_k, use_kv_mask, dropout_rate,
+               interpret, window, d, heads, res, do):
     q, k, v, lens, seed, out, lse = res
-    bh, sq, d = q.shape
-    bkv, sk = k.shape[0], k.shape[1]
-    group = bh // bkv
-    nq = pl.cdiv(sq, block_q)
-    nk = pl.cdiv(sk, block_k)
-    band = None if window is None else _Band(window, block_q, block_k, nq, nk)
+    blocks, _, group, pack, nq, nk, band = _geometry(
+        q, k, d, heads, block_q, block_k, window)
+    lanes, n = pack.lanes, pack.n
     k_steps = nk if band is None else band.k_steps
     q_steps = nq if band is None else band.q_steps
     kv_map = _kv_index_maps(group, band)
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1, keepdims=True)               # (bh, sq, 1)
-    delta = jnp.broadcast_to(delta, (bh, sq, STAT_LANES))
+    # delta in the statistics' format, (batch x heads, seq, STAT_LANES)
+    delta = jnp.broadcast_to(pack.head_sums(
+        do.astype(jnp.float32) * out.astype(jnp.float32))[..., None],
+        lse.shape)
 
     lens_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     seed_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
-    stat_spec = pl.BlockSpec((1, block_q, STAT_LANES), lambda b, i, j: (b, i, 0))
-
-    # the dk/dv kernel's view of what lives per query head (q, do, lse,
-    # delta): KV head ``b``, key block ``j``, inner step ``t``
-    if group == 1 and band is None:
-        def q_map(b, j, t):
-            return (b, t, 0)
-    else:
-        def q_map(b, j, t):
-            jq = t if group == 1 else t % q_steps
-            head = b if group == 1 else b * group + t // q_steps
-            if band is not None:
-                jq = jnp.minimum(band.q_first(j) + jq, band.q_last(j))
-            return (head, jq, 0)
-    stat_spec_kv = pl.BlockSpec((1, block_q, STAT_LANES), q_map)
+    q_spec = pl.BlockSpec((1, block_q, lanes), lambda b, i, j: (b, i, 0))
+    stat_spec = pl.BlockSpec((n, block_q, STAT_LANES),
+                             lambda b, i, j: (b, i, 0))
+    common = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
+                  block_k=block_k, use_kv_mask=use_kv_mask,
+                  dropout_rate=dropout_rate, pack=pack, band=band)
 
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k,
-                          num_k_blocks=k_steps, use_kv_mask=use_kv_mask,
-                          dropout_rate=dropout_rate, band=band),
-        grid=(bh, nq, k_steps),
+        functools.partial(_bwd_dq_kernel, num_k_blocks=k_steps, **common),
+        grid=(blocks, nq, k_steps),
         in_specs=[
             lens_spec,
             seed_spec,
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), kv_map),
-            pl.BlockSpec((1, block_k, d), kv_map),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            q_spec,
+            pl.BlockSpec((1, block_k, lanes), kv_map),
+            pl.BlockSpec((1, block_k, lanes), kv_map),
+            q_spec,
             stat_spec,
             stat_spec,
         ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, lanes), jnp.float32)],
         interpret=interpret,
         name=BWD_DQ,
     )(lens, seed, q, k, v, do, lse, delta)
 
+    # the dk/dv kernel's view of what lives per query lane block (q, do,
+    # lse, delta): KV lane block ``b``, key block ``j``, inner step ``t``
+    def q_map(b, j, t):
+        jq = t if group == 1 else t % q_steps
+        block = b if group == 1 else b * group + t // q_steps
+        if band is not None:
+            jq = jnp.minimum(band.q_first(j) + jq, band.q_last(j))
+        return (block, jq, 0)
+    q_spec_kv = pl.BlockSpec((1, block_q, lanes), q_map)
+    stat_spec_kv = pl.BlockSpec((n, block_q, STAT_LANES), q_map)
+    kv_spec = pl.BlockSpec((1, block_k, lanes), lambda b, j, i: (b, j, 0))
+
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k,
-                          num_q_blocks=q_steps, use_kv_mask=use_kv_mask,
-                          dropout_rate=dropout_rate, group=group, band=band),
-        grid=(bkv, nk, group * q_steps),
+        functools.partial(_bwd_dkv_kernel, num_q_blocks=q_steps, **common),
+        grid=(k.shape[0], nk, group * q_steps),
         in_specs=[
             lens_spec,
             seed_spec,
-            pl.BlockSpec((1, block_q, d), q_map),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_q, d), q_map),
+            q_spec_kv,
+            kv_spec,
+            kv_spec,
+            q_spec_kv,
             stat_spec_kv,
             stat_spec_kv,
         ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        ],
+        out_specs=[kv_spec, kv_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((bkv, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((bkv, sk, d), v.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, lanes), jnp.float32),
+            pltpu.VMEM((block_k, lanes), jnp.float32),
         ],
         interpret=interpret,
         name=BWD_DKV,
     )(lens, seed, q, k, v, do, lse, delta)
-    # int-array inputs (lens, seed) take float0 cotangents
-    return (dq, dk, dv, np.zeros(lens.shape, jax.dtypes.float0),
-            np.zeros(seed.shape, jax.dtypes.float0))
+    return dq, dk, dv
 
 
 # ---------------------------------------------------------------------------
 # public API
 # ---------------------------------------------------------------------------
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12))
-def _flash_bhsd(q, k, v, lens, seed, sm_scale, causal, block_q, block_k,
-                use_kv_mask, dropout_rate, interpret, window):
+                   nondiff_argnums=tuple(range(5, 15)))
+def _flash(q, k, v, lens, seed, sm_scale, causal, block_q, block_k,
+           use_kv_mask, dropout_rate, interpret, window, d, heads):
+    """q ``(batch x lane blocks, seq, lanes)``, k and v likewise over the
+    KV heads' lane blocks (``_Pack``). ``d`` is the head width as stored,
+    ``heads`` the pair (the caller's count of query heads, the stored one):
+    the dropout hash is fed ``batch * heads + head`` in the caller's."""
     out, _ = _fwd(q, k, v, lens, seed, sm_scale, causal, block_q, block_k,
-                  use_kv_mask, dropout_rate, interpret, window)
+                  use_kv_mask, dropout_rate, interpret, window, d, heads)
     return out
 
 
-def _flash_fwd_rule(q, k, v, lens, seed, sm_scale, causal, block_q, block_k,
-                    use_kv_mask, dropout_rate, interpret, window):
-    out, lse = _fwd(q, k, v, lens, seed, sm_scale, causal, block_q, block_k,
-                    use_kv_mask, dropout_rate, interpret, window)
+def _flash_fwd_rule(q, k, v, lens, seed, *static):
+    out, lse = _fwd(q, k, v, lens, seed, *static)
     return out, (q, k, v, lens, seed, out, lse)
 
 
-def _flash_bwd_rule(sm_scale, causal, block_q, block_k, use_kv_mask,
-                    dropout_rate, interpret, window, res, do):
-    return _bwd(sm_scale, causal, block_q, block_k, use_kv_mask,
-                dropout_rate, interpret, window, res, do)
+_flash.defvjp(_flash_fwd_rule, _bwd)
 
 
-_flash_bhsd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+def dims_of_call(eqn):
+    """``(head_dim, seq_q, seq_k)`` of one of the three kernels' traced
+    ``pallas_call`` equations, as stored: the one place outside the calls
+    above that knows their operand format. q and k are operands 2 and 3,
+    ``(batch x lane blocks, seq, lanes)``; a lane block holds as many heads
+    as the statistics, a row a head (``lse``: the forward's second result,
+    operand 6 of the other two), have rows for each of q's."""
+    q, k = eqn.invars[2].aval, eqn.invars[3].aval
+    stats = (eqn.outvars[1] if eqn.params["name"] == FWD
+             else eqn.invars[6]).aval
+    return q.shape[2] // (stats.shape[0] // q.shape[0]), q.shape[1], k.shape[1]
 
 
 def flash_supported(q, k, min_seq=128):
@@ -554,11 +736,24 @@ def flash_supported(q, k, min_seq=128):
             q.shape[-1] in (64, 128, 256))
 
 
-def _pad_seq(x, to_len):
-    pad = to_len - x.shape[1]
-    if pad == 0:
-        return x
-    return jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+def _stored_width(d):
+    """The head width the kernels see: ``d`` where it divides a lane block
+    or is a whole number of them, else the next width that does."""
+    if d > LANES:
+        return -(-d // LANES) * LANES
+    return 1 << (d - 1).bit_length()
+
+
+def _to_lane_blocks(x, seq, heads, d):
+    """``(batch, s, h, w)``, zero-padded to ``(batch, seq, heads, d)``, as
+    ``(batch x lane blocks, seq, lanes)``: neighbouring heads side by side
+    in a block's lanes, the blocks ahead of the positions."""
+    b, s, h, w = x.shape
+    if (s, h, w) != (seq, heads, d):
+        x = jnp.pad(x, ((0, 0), (0, seq - s), (0, heads - h), (0, d - w)))
+    lanes = max(LANES, d)
+    x = jnp.swapaxes(jnp.reshape(x, (b, seq, heads * d // lanes, lanes)), 1, 2)
+    return jnp.reshape(x, (-1, seq, lanes))
 
 
 def flash_attention(q, k, v, causal=False, sm_scale=None, kv_lens=None,
@@ -568,6 +763,15 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, kv_lens=None,
     """q: (batch, seq, num_heads, head_dim), k/v: (batch, seq, kv_heads,
     head_dim) with ``num_heads % kv_heads == 0`` → output shaped like q.
     Query head ``i`` attends over KV head ``i // (num_heads // kv_heads)``.
+
+    The kernels see lane blocks (``_Pack``): one head at head width 128 or
+    256, ``128 // head_dim`` neighbouring heads below that, so no padded
+    row crosses HBM. Heads that do not fill whole lane blocks get zero
+    heads padded on and sliced off again: KV heads up to a multiple of the
+    heads a block holds (25 heads of width 64 run as 26), query heads with
+    them. A head width that neither divides 128 nor is a multiple of it is
+    zero-padded to the next that does; a ragged ``seq`` to a multiple of
+    128.
 
     window: optional int, causal only — query ``i`` sees key ``j`` iff
     ``0 <= i - j < window`` (its own position and the ``window - 1``
@@ -620,10 +824,17 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, kv_lens=None,
     if dropout_rate < 0.0:
         raise ValueError(f"dropout_rate must be in [0, 1], got {dropout_rate}")
 
-    # pad ragged tails to lane multiples; kernel masks padded key columns
+    # ragged tails go to lane multiples (the kernels mask the padded key
+    # columns), heads to whole lane blocks
     sq_pad = int(-(-sq // LANES) * LANES)
     sk_pad = int(-(-sk // LANES) * LANES)
-    qp, kp, vp = _pad_seq(q, sq_pad), _pad_seq(k, sk_pad), _pad_seq(v, sk_pad)
+    d_st = _stored_width(d)
+    per_block = _Pack(d_st, h // h_kv).n
+    kv_st = -(-h_kv // per_block) * per_block
+    h_st = kv_st * (h // h_kv)
+    qp = _to_lane_blocks(q, sq_pad, h_st, d_st)
+    kp = _to_lane_blocks(k, sk_pad, kv_st, d_st)
+    vp = _to_lane_blocks(v, sk_pad, kv_st, d_st)
 
     # clamp blocks for short sequences, keeping them LANES-aligned (a
     # non-128-multiple block like 200 would break Mosaic tiling); below one
@@ -644,21 +855,16 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, kv_lens=None,
         lens = jnp.full((b,), sk, dtype=jnp.int32)
     else:
         lens = jnp.minimum(jnp.asarray(kv_lens, jnp.int32).reshape(b), sk)
-    # per-(batch*head) scalars live in SMEM (dynamically indexed by the
-    # grid's b — the Mosaic-supported home for control scalars)
-    lens_bh = jnp.repeat(lens, h)
+    # per-(batch*lane block) scalars live in SMEM (dynamically indexed by
+    # the grid's b — the Mosaic-supported home for control scalars)
+    lens_blocks = jnp.repeat(lens, qp.shape[0] // b)
     if dropout_seed is None:
         seed_arr = jnp.zeros((1,), jnp.int32)
     else:
         seed_arr = jnp.asarray(dropout_seed, jnp.int32).reshape((1,))
 
-    def to_bhsd(x):
-        return jnp.reshape(jnp.swapaxes(x, 1, 2),
-                           (b * x.shape[2], x.shape[1], d))
-
-    out = _flash_bhsd(to_bhsd(qp), to_bhsd(kp), to_bhsd(vp), lens_bh,
-                      seed_arr, float(sm_scale), bool(causal), int(block_q),
-                      int(block_k), bool(use_kv_mask), float(dropout_rate),
-                      bool(interpret), window)
-    out = jnp.swapaxes(jnp.reshape(out, (b, h, sq_pad, d)), 1, 2)
-    return out[:, :sq]
+    out = _flash(qp, kp, vp, lens_blocks, seed_arr, float(sm_scale),
+                 bool(causal), int(block_q), int(block_k), bool(use_kv_mask),
+                 float(dropout_rate), bool(interpret), window, d_st, (h, h_st))
+    out = jnp.swapaxes(jnp.reshape(out, (b, -1, sq_pad, out.shape[-1])), 1, 2)
+    return jnp.reshape(out, (b, sq_pad, h_st, d_st))[:, :sq, :h, :d]

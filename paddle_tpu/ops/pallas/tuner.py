@@ -288,34 +288,31 @@ def record_fallback(kernel: str):
     _count(kernel, "fallback")
 
 
-def entry_for_traced_call(kernel_name: str, avals: List, grid) -> \
+def entry_for_traced_call(kernel_name: str, eqn) -> \
         Tuple[Optional[str], Optional[dict]]:
     """Map a traced ``pallas_call`` equation back to its DB entry — the
     analysis rule's hook (``pallas-config-untuned``).
 
     ``kernel_name`` is the pallas_call's ``name=`` (the flash kernels)
-    or its kernel function's name; ``avals``
-    the input abstract values; ``grid`` the launch grid.  Returns
+    or its kernel function's name; ``eqn`` the equation.  Returns
     ``(key, entry_or_None)``; ``(None, None)`` when the kernel is not
     one the tuner knows.  For fused CE the vocab seen in the jaxpr is
     the block-padded one, so the match accepts any DB entry whose true
     vocab pads to the traced width.
     """
     from .flash_attention import KERNEL_NAMES as flash_kernels
+    from .flash_attention import dims_of_call
     db = get_db()
+    avals = [getattr(v, "aval", None) for v in eqn.invars]
     if kernel_name in flash_kernels:
-        # flash attention: invars (lens, seed, q, k, v, ...) — q at 2
-        if len(avals) < 5:
-            return None, None
-        q, k = avals[2], avals[3]
-        dims = flash_dims(q.shape[-1], q.shape[1], k.shape[1])
+        dims = flash_dims(*dims_of_call(eqn))
+        dtype = avals[2].dtype
         for dev in (device_kind(), GENERIC_DEVICE):
-            key = make_key("flash_attention", dev, q.dtype, dims)
+            key = make_key("flash_attention", dev, dtype, dims)
             entry = db.lookup(key)
             if entry:
                 return key, entry
-        return make_key("flash_attention", device_kind(), q.dtype,
-                        dims), None
+        return make_key("flash_attention", device_kind(), dtype, dims), None
     if kernel_name in ("_ce_fwd_kernel", "_ce_bwd_dh_kernel",
                        "_ce_bwd_dw_kernel"):
         # fused CE: hid (N, H) and w (H, Vpad) are the two matrix invars,

@@ -274,8 +274,8 @@ def test_cells_bucket_takes_the_measured_row(tmp_path, monkeypatch):
     _assert_bf16_close(got, want)
 
 
-def _flash_grad_jaxpr(dtype, **kw):
-    q = jnp.zeros((1, 256, 1, 64), dtype)
+def _flash_grad_jaxpr(dtype, d=64, **kw):
+    q = jnp.zeros((1, 256, 128 // d, d), dtype)
 
     def f(q, k, v):
         return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
@@ -290,9 +290,12 @@ def _flash_grad_jaxpr(dtype, **kw):
     ids=["causal", "dropout", "kv_lens"])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
-def test_kernel_products_take_the_operand_dtype(dtype, kw):
+@pytest.mark.parametrize("d", [64, 128])
+def test_kernel_products_take_the_operand_dtype(d, dtype, kw):
     """Walks the three kernels' jaxprs through their ``pallas_call``s:
-    all nine ``dot_general``s multiply in the dtype q/k/v arrive in (bf16
+    all nine ``dot_general``s (staged once whatever the heads a lane block:
+    two heads at width 64 are the trips of one loop) multiply in the dtype
+    q/k/v arrive in (bf16
     stays bf16, the MXU's packed format; float32 callers keep float32
     products), accumulate in float32, and no operand is an upcast to
     float32 (directly or through a chain of casts): the guard that keeps
@@ -301,7 +304,7 @@ def test_kernel_products_take_the_operand_dtype(dtype, kw):
     from paddle_tpu.ops.pallas.flash_attention import KERNEL_NAMES
 
     dots = {name: 0 for name in KERNEL_NAMES}
-    for path, jaxpr in walker.iter_jaxprs(_flash_grad_jaxpr(dtype, **kw)):
+    for path, jaxpr in walker.iter_jaxprs(_flash_grad_jaxpr(dtype, d, **kw)):
         kernel = next((p.split(":", 1)[1] for p in path
                        if p.startswith("pallas_call:")), None)
         producer = {v: e for e in jaxpr.eqns for v in e.outvars}
@@ -319,3 +322,193 @@ def test_kernel_products_take_the_operand_dtype(dtype, kw):
                     assert src.params["new_dtype"] != jnp.float32, kernel
                     src = producer.get(src.invars[0])
     assert dots == {"flash_fwd": 2, "flash_bwd_dq": 3, "flash_bwd_dkv": 4}
+
+
+# ---------------------------------------------------------------------------
+# heads in lane blocks: the kernels' operands are (batch x lane blocks, seq,
+# lanes), 128 // head_dim neighbouring heads side by side below width 128
+# ---------------------------------------------------------------------------
+def _layout_case(seed, b, s, h, h_kv, d, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.key(seed), 4)
+    return (jax.random.normal(ks[0], (b, s, h, d), dtype),
+            jax.random.normal(ks[1], (b, s, h_kv, d), dtype),
+            jax.random.normal(ks[2], (b, s, h_kv, d), dtype),
+            jax.random.normal(ks[3], (b, s, h, d), dtype))
+
+
+@pytest.mark.parametrize("b, s, h, h_kv, d, window, kv_lens, blocks", [
+    (1, 256, 12, 12, 64, None, None, (128, 128)),   # two heads a lane block
+    (1, 256, 25, 25, 64, None, None, (128, 128)),   # a zero head padded on
+    (2, 256, 8, 2, 64, None, None, (128, 128)),     # groups of 4 at width 64
+    (1, 256, 6, 2, 64, 100, None, (128, 128)),      # odd groups, a window
+    (1, 256, 3, 1, 64, None, None, (128, 256)),     # KV heads padded too
+    (1, 1024, 6, 1, 128, None, None, (512, 512)),   # laguna's full layers
+    (1, 1024, 8, 1, 128, 512, None, (512, 512)),    # and its window layers
+    (1, 1024, 6, 1, 128, 512, None, (256, 512)),
+    (1, 1024, 8, 1, 128, None, None, (512, 256)),
+    (2, 300, 4, 4, 64, None, (150, 300), (128, 128)),   # ragged, kv_lens
+    (2, 200, 6, 2, 128, None, (200, 77), (128, 128)),
+    (1, 256, 2, 2, 32, None, None, (128, 128)),     # four heads a block
+    (1, 256, 3, 3, 80, None, None, (128, 128)),     # a width padded to 128
+    (1, 256, 2, 1, 256, None, None, (128, 128)),    # two lane blocks a head
+], ids=lambda x: "x".join(map(str, x)) if isinstance(x, tuple) else str(x))
+def test_lane_blocks_match_xla(b, s, h, h_kv, d, window, kv_lens, blocks):
+    """Forward and the three gradients of the kernels (interpret mode,
+    float32 callers) against ``_xla_attention`` for every way heads meet
+    lane blocks: ``128 // d`` heads side by side below width 128 (with the
+    zero heads that fill the last block, and grouped heads finding their
+    KV head's slot), one head a block at 128, several blocks a head above."""
+    q, k, v, do = _layout_case(3, b, s, h, h_kv, d)
+    kw, mask = {}, None
+    if kv_lens is not None:
+        kw["kv_lens"] = jnp.asarray(kv_lens, jnp.int32)
+        mask = (jnp.arange(s)[None, None, None, :] <
+                kw["kv_lens"].reshape(-1, 1, 1, 1))
+
+    def flash(q, k, v):
+        out = flash_attention(q, k, v, causal=True, window=window,
+                              block_q=blocks[0], block_k=blocks[1],
+                              interpret=True, **kw)
+        return jnp.sum(do * out), out
+
+    def xla(q, k, v):
+        out = _xla_attention(q, k, v, causal=True, window=window, mask=mask)
+        return jnp.sum(do * out), out
+
+    got, out = jax.grad(flash, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    want, ref = jax.grad(xla, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-4)
+    for name, a, w in zip(("dq", "dk", "dv"), got, want):
+        assert a.shape == w.shape, name
+        np.testing.assert_allclose(a, w, rtol=5e-4, atol=5e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("h, h_kv", [(12, 12), (25, 25), (8, 2)],
+                         ids=["12", "25_padded", "8_over_2"])
+def test_lane_blocks_bf16_match_float32_reference(h, h_kv):
+    """The cells' dtype at width 64: bf16 operands through a lane block of
+    two heads, under the tolerance of the test above it was derived for."""
+    q, k, v, _ = _layout_case(5, 1, 256, h, h_kv, 64, jnp.bfloat16)
+    got = _out_and_grads(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, interpret=True, block_q=128, block_k=128),
+        q, k, v)
+    want = _out_and_grads(
+        lambda q, k, v: _xla_attention(q, k, v, causal=True),
+        *(x.astype(jnp.float32) for x in (q, k, v)))
+    _assert_bf16_close(got, want)
+
+
+@pytest.mark.parametrize("h, h_kv", [(2, 2), (3, 3), (3, 1), (6, 3)], ids=[
+    "two_heads", "three_padded_to_four", "3_over_1_kv_padded",
+    "6_over_3_kv_padded"])
+def test_dropout_masks_bit_equal_for_every_head_of_a_lane_block(h, h_kv):
+    """The multiplier each head's probabilities meet is the one the hash
+    gives ``(seed, batch * heads + head, q block, k block)``, as before the
+    layout changed, with ``heads`` the caller's count where zero heads are
+    padded on: one query head, or under grouping a KV head and with it a
+    whole group of query heads (3 over 1 is stored as 6 over 2, 6 over 3
+    as 8 over 4), in the second batch row too. Read off the kernel itself:
+    with q = 0 every probability is 1/S, and with the rows of V an
+    identity's, ``out * S`` is the multiplier, exactly (rate 0.5: 0 or
+    2)."""
+    b, s, d, block, rate, seed = 2, 128, 64, 128, 0.5, 77
+    q = jnp.zeros((b, s, h, d), jnp.float32)
+    want = _kernel_dropout_mask(b * h, s, block, rate, seed).reshape(
+        b, h, s, s)
+    assert len({np.asarray(m).tobytes() for m in want.reshape(-1, s, s)}) \
+        == b * h                                # a mask of its own a head
+    for half in range(s // d):
+        v = jnp.zeros((s, d), jnp.float32).at[
+            half * d + jnp.arange(d), jnp.arange(d)].set(1.0)
+        v = jnp.broadcast_to(v[None, :, None, :], (b, s, h_kv, d))
+        out = flash_attention(q, q[:, :, :h_kv], v, dropout_rate=rate,
+                              dropout_seed=seed, block_q=block,
+                              block_k=block, interpret=True)
+        np.testing.assert_array_equal(
+            np.asarray(jnp.swapaxes(out, 1, 2)) * s,
+            np.asarray(want[..., half * d:(half + 1) * d]))
+    assert 0.4 < float(jnp.mean(want == 0.0)) < 0.6
+
+
+def _transposes_and_kernels(jaxpr, scope, stack=""):
+    """Under name-stack ``scope``: the operand shapes of every ``transpose``
+    and the ``pallas_call`` equations, nested jaxprs included (an inner
+    jaxpr's name stacks continue its equation's)."""
+    from paddle_tpu.analysis import walker
+    transposes, calls = [], []
+    for eqn in walker.unwrap(jaxpr)[0].eqns:
+        here = f"{stack}/{eqn.source_info.name_stack}"
+        if scope in here and eqn.primitive.name == "transpose":
+            transposes.append(eqn.invars[0].aval.shape)
+        elif scope in here and eqn.primitive.name == "pallas_call":
+            calls.append(eqn)
+        for sub in walker.subjaxprs(eqn):
+            t, c = _transposes_and_kernels(sub.jaxpr, scope, here)
+            transposes += t
+            calls += c
+    return transposes, calls
+
+
+def _gpt_block():
+    from paddle_tpu.text.models.gpt import GPTBlock
+    block = GPTBlock(256, 4, attn_dropout=0.0, resid_dropout=0.0,
+                     tensor_parallel=False)
+    return block, (2, 512, 256), 4, 64
+
+
+def _laguna_block():
+    from paddle_tpu import nn
+    from paddle_tpu.text.models.mixed_decoder import (GroupedQueryAttention,
+                                                      MixedDecoderBlock)
+    attn = GroupedQueryAttention(
+        256, 4, 2, 128, {"theta": 10000.0, "rotary_dim": 128}, window=256,
+        gated=True)
+    block = MixedDecoderBlock(attn, nn.GatedSiluFFN(256, 512), False, 256,
+                              1e-6)
+    return block, (2, 512, 256), 4, 128
+
+
+@pytest.mark.parametrize("make", [_gpt_block, _laguna_block],
+                         ids=["gpt_block", "laguna_block"])
+def test_a_block_on_the_flash_path_moves_no_padded_row(make, monkeypatch):
+    """The staged forward + backward of one block with the flash gate held
+    open: under ``sdpa/flash`` three ``pallas_call``s (by ``name=``), each
+    with ``lens`` int32 ``(n,)`` and ``seed`` int32 ``(1,)`` as its first two
+    operands (what the benchmark's readers match: ``custom-call(s32[N],
+    s32[1], ...``); every other operand and result is whole 128-lane rows,
+    ``(batch x lane blocks, seq, lanes)``, or the 8-lane statistics with a
+    row a head; and what is transposed around them (q, k, v in, the
+    output back, and their four cotangents) is such rows too, never a
+    64-wide one that HBM would pad to 128."""
+    import importlib
+
+    from paddle_tpu.jit.functionalization import functional_call, state_of
+    from paddle_tpu.ops.pallas.flash_attention import (KERNEL_NAMES, LANES,
+                                                       STAT_LANES)
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(fa, "flash_supported", lambda q, k, min_seq=128: True)
+    block, shape, heads, d = make()
+    params, buffers = state_of(block)
+
+    def loss(params, x):
+        return jnp.sum(functional_call(block, params, buffers, x)[0]
+                       .astype(jnp.float32))
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(
+        params, jnp.zeros(shape, jnp.bfloat16))
+    transposes, calls = _transposes_and_kernels(jaxpr, "sdpa/flash")
+    assert sorted(c.params["name"] for c in calls) == sorted(KERNEL_NAMES)
+    b, s, _ = shape
+    blocks = b * heads * d // LANES
+    for call in calls:
+        lens, seed = (v.aval for v in call.invars[:2])
+        assert (lens.dtype, lens.shape) == (jnp.int32, (blocks,)), call
+        assert (seed.dtype, seed.shape) == (jnp.int32, (1,)), call
+        for v in list(call.invars[2:]) + list(call.outvars):
+            if v.aval.shape[-1] == STAT_LANES:
+                assert v.aval.shape == (b * heads, s, STAT_LANES)
+            else:
+                assert v.aval.shape[1:] == (s, LANES), v.aval
+    assert len(transposes) <= 8 and all(
+        t[-1] == LANES and len(t) == 4 for t in transposes), transposes

@@ -66,8 +66,11 @@ def test_flash_kernels_keep_name_and_scope_on_the_tpu(v5e, kernel, transform):
              if "tpu_custom_call" in line and f"%{kernel}" in line]
     assert calls, kernel
     op_name = re.search(r'op_name="([^"]+)"', calls[0]).group(1)
+    # the layers of a model share one staged forward and one staged
+    # backward (an inner jit each); the call site's scopes stay in front
+    shared = "jit(_fwd)" if kernel == "flash_fwd" else "jit(_bwd_calls)"
     assert op_name == f"jit(f)/{transform}attn{')' * transform.count('(')}" \
-                      f"/{kernel}/pallas_call"
+                      f"/{shared}/{kernel}/pallas_call"
 
 
 @pytest.mark.parametrize("shape", [
@@ -134,6 +137,26 @@ def test_flash_grouped_heads_and_window_at_the_mixed_decoder_shapes(
     assert dkv and re.search(
         r"= \(bf16\[16,4096,128\]\S*, bf16\[16,4096,128\]", dkv[0])
     assert f"bf16[{2 * heads},4096,128]" in dkv[0]
+
+
+@pytest.mark.parametrize("heads, kv_heads, kw", [
+    (8, 2, {}), (3, 1, {"dropout_rate": 0.1, "dropout_seed": 5})],
+    ids=["8_over_2", "3_over_1_dropout"])
+def test_flash_grouped_heads_at_width_64(v5e, heads, kv_heads, kw):
+    """Grouped heads at head width 64: a query head's KV head lies in
+    another slot of its lane block, found by a traced index (a 32-bit
+    lane rotation and a select in ``_Pack.place``), and with a KV head
+    padded on the dropout hash's head id is a scalar division: neither
+    runs in any cell, so Mosaic sees them here."""
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+
+    def f(q, k, v):
+        return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, **kw).astype(jnp.float32) ** 2),
+            argnums=(0, 1, 2))(q, k, v)
+
+    kv = ((2, 1024, kv_heads, 64), jnp.bfloat16)
+    _compile(f, v5e, ((2, 1024, heads, 64), jnp.bfloat16), kv, kv)
 
 
 @pytest.mark.parametrize("h,dtype", [(768, jnp.bfloat16),

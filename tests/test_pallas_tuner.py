@@ -220,6 +220,35 @@ class TestAnalysisRule:
         assert "flash_attention" in fs[0].message
         assert "d128" in fs[0].message
 
+    @pytest.mark.parametrize("h, h_kv, d, seq, row", [
+        (12, 12, 64, 1024, "d64,sk1024,sq1024"),    # two heads a lane block
+        (25, 25, 64, 1024, "d64,sk1024,sq1024"),    # a zero head padded on
+        (6, 1, 128, 4096, "d128,sk4096,sq4096"),    # grouped, one a block
+    ])
+    def test_traced_kernels_map_to_their_row(self, h, h_kv, d, seq, row):
+        """All three kernels' traced calls, operands (batch x lane blocks,
+        seq, lanes) with a row a head in the statistics, give the bucket
+        the call resolved (``flash_attention.dims_of_call``): the two rows
+        the benchmark's cells take."""
+        from paddle_tpu.analysis.walker import walk
+        from paddle_tpu.ops.pallas.flash_attention import (KERNEL_NAMES,
+                                                           flash_attention)
+        q = jax.ShapeDtypeStruct((2, seq, h, d), jnp.bfloat16)
+        kv = jax.ShapeDtypeStruct((2, seq, h_kv, d), jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
+            flash_attention(q, k, v, causal=True).astype(jnp.float32)),
+            argnums=(0, 1, 2)))(q, kv, kv)
+        seen = {}
+        for site in walk(jaxpr):
+            if site.eqn.primitive.name != "pallas_call":
+                continue
+            key, entry = tuner.entry_for_traced_call(
+                site.eqn.params["name"], site.eqn)
+            seen[site.eqn.params["name"]] = key
+            assert entry["config"] == {"block_q": 1024, "block_k": 1024}
+        assert sorted(seen) == sorted(KERNEL_NAMES)
+        assert all(key.endswith("|bfloat16|" + row) for key in seen.values())
+
     def test_fused_ce_untuned_vocab_fires(self):
         from paddle_tpu.ops.pallas.fused_ce import fused_lm_ce
         rs = np.random.RandomState(1)

@@ -219,8 +219,14 @@ def test_flash_kernels_have_a_name_and_a_scope(kernel, transform):
                                    interpret=True).sum()
 
     jaxpr = jax.make_jaxpr(jax.grad(loss))(q).jaxpr
-    calls = {str(e.source_info.name_stack): e.params["name"]
-             for e in jaxpr.eqns if e.primitive.name == "pallas_call"}
+    # every layer of a model shares one staged forward and one staged
+    # backward (an inner jit each, traced and lowered once a program): the
+    # kernels' calls lie in those, under the scope they were called in
+    calls = {f"{e.source_info.name_stack}/{inner.source_info.name_stack}":
+             inner.params["name"]
+             for e in jaxpr.eqns if e.primitive.name in ("pjit", "jit")
+             for inner in e.params["jaxpr"].jaxpr.eqns
+             if inner.primitive.name == "pallas_call"}
     stack = next(s for s in calls if holds(s, kernel))
     # the backward kernels nest under the scope the forward was staged in
     assert stack.startswith(transform + "attn)") and calls[stack] == kernel
