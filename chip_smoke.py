@@ -33,9 +33,10 @@ Phases (each through the entry points a user calls, weights from a seed):
 
 ``--rehearse-cpu`` walks the same code at toy sizes with the kernels
 interpreted, to debug the script without spending chip time. Its lines
-say ``cpu`` and carry no time or rate, its last line says ``"ok":
-false`` and it never exits 0. The times a chip run prints are a
-smoke's, not a benchmark's.
+say ``cpu`` and carry no time, its last line says ``"ok": false`` and
+it never exits 0. A chip run prints step and phase times so that a
+builder sees the chip ran; no line holds a rate or a utilization:
+``benchmark/run.py`` is the one place that states a speed.
 """
 from __future__ import annotations
 
@@ -51,8 +52,8 @@ import traceback
 T0 = time.perf_counter()
 PHASES = ("train", "kernels", "serve", "multichip")
 
-# GPT-base as bench.py's dense_b8 runs it; the rehearsal keeps the code
-# path and drops the size
+# GPT-2 small at full width, batch 8; the rehearsal keeps the code path
+# and drops the size
 FULL = dict(vocab=50304, seq=1024, layers=12, hidden=768, heads=12,
             batch=8)
 TINY = dict(vocab=512, seq=64, layers=2, hidden=64, heads=4, batch=4)
@@ -87,8 +88,8 @@ class Run:
         self.interpret = rehearsal
 
     def say(self, phase: str, times=None, **fields):
-        """``times`` holds the fields that are a time or a rate: a CPU
-        run states counts and leaves them out."""
+        """``times`` holds the fields that are a time: a CPU run states
+        counts and leaves them out."""
         line = {"phase": phase, "device": self.device, **fields}
         if self.rehearsal:
             line["rehearsal"] = True
@@ -102,8 +103,9 @@ class Run:
 # ---------------------------------------------------------------------------
 
 def _gpt_trainer(cfg, mesh, tensor_parallel=False, **trainer_kw):
-    """bench.py's dense_b8 construction: library defaults, dense logits
-    into nn.functional.cross_entropy."""
+    """The recipe of the benchmark's gpt2-small cells: bf16 parameters,
+    AdamW at library defaults, dense logits into
+    nn.functional.cross_entropy."""
     import paddle_tpu as paddle
     from paddle_tpu import nn
     from paddle_tpu.distributed.engine import ParallelTrainer
@@ -197,7 +199,9 @@ def _config_origins(run, entries):
     out = {}
     for label, (kernel, dtype, dims) in entries.items():
         key, path = tuner.entry_origin(kernel, dtype, dims)
-        out[label] = {"key": key, "db": path or "compiled-in defaults"}
+        out[label] = {"key": key, "db": path or "compiled-in defaults",
+                      "config": tuner.get_db().lookup(key)["config"]
+                      if key else None}
         check(path is None or os.path.realpath(path).startswith(root),
               f"{label}: config {key!r} comes from {path}, outside the "
               f"checkout {root}")
@@ -237,7 +241,6 @@ def phase_train(run: Run):
     losses = [l for l, _ in steps]
     steady = sorted(t for _, t in steps[2:])
     step_s = steady[len(steady) // 2]
-    tokens = cfg["batch"] * cfg["seq"]
     n_params = sum(int(np.prod(p.shape))
                    for p in trainer.model.parameters())
     stats = jax.devices()[0].memory_stats() or {}
@@ -256,14 +259,9 @@ def phase_train(run: Run):
                 tuner.flash_dims(cfg["hidden"] // cfg["heads"],
                                  cfg["seq"], cfg["seq"]))}),
     }
-    times = {"steps_3_to_10_median_ms": round(step_s * 1e3, 2),
-             "tokens_per_s": round(tokens / step_s, 1)}
-    if not run.rehearsal:
-        # utilization only from the published peak of the device it ran on
-        peak = telemetry.published_peak(
-            run.device["kind"])["bf16_flops_per_sec"]
-        times["mfu_6N_vs_published_peak"] = round(
-            tokens / step_s * 6 * n_params / peak, 4)
+    # the median step time tells a builder the chip ran; rates and
+    # utilization are the benchmark's to state (benchmark/run.py)
+    times = {"steps_3_to_10_median_ms": round(step_s * 1e3, 2)}
     run.say("train", event="result", times=times, **out)
 
     check(all(math.isfinite(l) for l in losses), f"non-finite loss: {losses}")

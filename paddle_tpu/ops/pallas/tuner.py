@@ -26,8 +26,7 @@ search for the repo's Pallas tier:
   (no TPU) the sweep runs the kernels in interpret mode and is
   correctness-only — winners are the validated defaults with a null
   timing, so the first real-TPU run only has to refresh timings, not
-  re-establish correctness.  Timing reuses ``tools/op_bench.py``'s
-  steady-state loop.
+  re-establish correctness.
 
 CLI (writes the overlay by default)::
 
@@ -420,14 +419,8 @@ def paged_candidates(sq: Optional[int] = None) -> List[Dict[str, int]]:
 # ---------------------------------------------------------------------------
 
 def _time_op(fn, args, iters: int = 20, warmup: int = 3) -> float:
-    """tools/op_bench.py's steady-state timing loop (shared so op
-    timings and tuner timings are the same measurement); inline twin
-    when the tools dir is not importable (installed package)."""
-    try:
-        from tools.op_bench import time_op
-        return time_op(fn, args, iters=iters, warmup=warmup)
-    except ImportError:
-        pass
+    """Mean seconds a call of the jitted ``fn`` in steady state; the
+    host fetch of the first output leaf ends each region."""
     import jax
     import numpy as np
     jfn = jax.jit(fn)

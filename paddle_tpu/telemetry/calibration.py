@@ -28,8 +28,8 @@ key                    prediction vs measurement
                        the same entry (ops.pallas.tuner.tune)
 ``planner_step_time``  ``auto.plan_search`` winner's predicted step time
                        vs the measured step time of running that chosen
-                       config (tools/bench_plan.py, bench.py planner
-                       block) — closes the loop on the planner itself
+                       config (tools/bench_plan.py) — closes the loop
+                       on the planner itself
 =====================  ====================================================
 
 Every record exports ``calibration_drift_ratio{key}`` (= measured /

@@ -1,5 +1,5 @@
 """``telemetry.scope(run_dir)`` — one context wiring registry + profiler +
-JSONL sink together for a run (bench.py, tools/ CLIs, tests).
+JSONL sink together for a run (chip_smoke.py, tools/ CLIs, tests).
 
 On entry: swaps in a fresh default registry (unless ``fresh=False``),
 flips the global enabled flag, starts the host profiler (unless one is

@@ -1,12 +1,8 @@
-"""Smoke the bench + numerics capture code on CPU so it cannot rot.
-
-Round 1 lost its on-chip number to a plain bench.py bug; the capture
-code executes for real ONCE per round, so this test runs the ACTUAL
-parent orchestration (fresh subprocesses per config, interim emission,
-final JSON contract) end-to-end with ``JAX_PLATFORMS=cpu`` at the tiny
-CPU shapes, plus the numerics smoke script and ``chip_smoke.py``'s
-no-chip contract. A KeyError in the sweep logic fails HERE, not at
-snapshot time.
+"""Smoke the repo's scripts on CPU so they cannot rot: each runs as a
+fresh process with ``JAX_PLATFORMS=cpu`` at its tiny CPU shapes and is
+held to its one-line JSON contract and exit code — ``chip_smoke.py``'s
+no-chip contract and rehearsal, the ``tools/`` CLIs, the chaos
+scenarios. A KeyError in a script fails HERE, not on the chip.
 """
 import json
 import os
@@ -16,7 +12,6 @@ import sys
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(REPO, "bench.py")
 
 # NOTE: these tests intentionally do NOT inherit conftest's in-process jax
 # config — the children are fresh processes that read JAX_PLATFORMS.
@@ -26,74 +21,6 @@ def _env():
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     return env
-
-
-@pytest.mark.slow
-def test_bench_parent_orchestration_all_configs_cpu():
-    """`python bench.py` end-to-end: every config in a fresh child + the
-    single-JSON-line stdout contract the driver parses."""
-    proc = subprocess.run([sys.executable, BENCH], capture_output=True,
-                          text=True, timeout=1500, env=_env())
-    lines = [l for l in proc.stdout.strip().splitlines() if l.strip()]
-    assert lines, f"no stdout; stderr: {proc.stderr[-2000:]}"
-    res = json.loads(lines[-1])  # driver contract: ONE json line
-    assert res["metric"] == "gpt_base_train_tokens_per_sec_per_chip"
-    assert proc.returncode == 0, (
-        f"bench rc={proc.returncode}; result={res}; "
-        f"stderr tail: {proc.stderr[-2000:]}")
-    assert res["value"] > 0
-    assert res["backend"] == "cpu"
-    for name in ("numerics", "op_pallas", "gpt_base", "resnet50",
-                 "bert_base_amp", "widedeep_ctr", "gpt_1p3b", "heter_ctr"):
-        cfg = res["extra"][name]
-        assert "error" not in cfg, f"{name} failed: {cfg}"
-        assert not cfg.get("partial"), f"{name} stuck partial: {cfg}"
-    assert res["extra"]["numerics"]["numerics_ok"] is True
-    assert res["extra"]["heter_ctr"]["speedup_x"] > 0
-    # the pallas kernel suite ran and resolved configs from the DB
-    assert res["extra"]["op_pallas"]["config_resolutions"]
-    # the sweep recorded every CPU variant and picked a best
-    sweep = res["extra"]["gpt_base"]["sweep"]
-    assert set(sweep) == {"fused_b4", "dense_b4", "fused_b4_int8dp",
-                          "fused_b4_int4dp", "fused_b4_pallas_ce"}
-    assert res["extra"]["gpt_base"]["variant"] in sweep
-    # telemetry harvested from the winning variant's scoped registry
-    tel = res["extra"]["gpt_base"]["telemetry"]
-    assert tel["recompiles"] >= 1
-    assert tel["mfu"] > 0
-    assert tel["step_time_avg_s"] > 0
-    assert tel["wire_bytes"] >= 0  # 0 on the single-device CPU data mesh
-    # the auto-parallel planner ran its pick and closed the drift loop
-    planner = res["extra"]["gpt_base"]["planner"]
-    assert "error" not in planner, f"planner block failed: {planner}"
-    assert planner["measured_s"] > 0
-    assert planner["calibration"]["key"] == "planner_step_time"
-    assert planner["calibration"]["n"] >= 1
-    assert planner["baselines"]["pick_beats_all_dp"] in (True, False)
-
-
-def test_bench_child_failure_is_isolated():
-    """A bogus config child emits an error payload and exits nonzero
-    without tracebacking the parent-side parsing."""
-    proc = subprocess.run([sys.executable, BENCH, "--child", "nosuch"],
-                          capture_output=True, text=True, timeout=240,
-                          env=_env())
-    assert proc.returncode == 1
-    marks = [l for l in proc.stdout.splitlines()
-             if l.startswith("##BENCHJSON## ")]
-    assert marks and "error" in json.loads(marks[-1][len("##BENCHJSON## "):])
-
-
-def test_bench_parent_timeout_path():
-    """_run_child reports a timeout as data, not an exception."""
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-        payload, err = bench._run_child("numerics", 0.01)
-    finally:
-        sys.path.remove(REPO)
-    assert payload is None
-    assert "timed out" in err
 
 
 def test_chip_smoke_without_a_chip_fails_and_prints_no_result():

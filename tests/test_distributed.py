@@ -287,6 +287,95 @@ class TestEngine:
         l = float(tr.train_step(x, y))
         np.testing.assert_allclose(l, ref_loss, rtol=1e-4)
 
+    @staticmethod
+    def _resnet50_bf16():
+        from paddle_tpu.distributed.engine import ParallelTrainer
+        from paddle_tpu.vision.models import resnet50
+        model = resnet50(num_classes=10)
+        model.bfloat16()
+        opt = paddle.optimizer.Momentum(0.01, momentum=0.9,
+                                        parameters=model.parameters())
+        tr = ParallelTrainer(
+            model, opt, lambda o, y: nn.functional.cross_entropy(o, y))
+        rng = np.random.RandomState(0)
+        # XLA convolutions want the input in the weights' dtype
+        imgs = jnp.asarray(rng.randn(4, 3, 32, 32), jnp.bfloat16)
+        return tr, imgs, rng.randint(0, 10, (4,)).astype("int32")
+
+    @staticmethod
+    def _bert_bf16_grad_scaler():
+        from paddle_tpu.amp import GradScaler
+        from paddle_tpu.distributed.engine import ParallelTrainer
+        from paddle_tpu.text.models import BertForPretraining
+        model = BertForPretraining(
+            tensor_parallel=False, vocab_size=128, hidden_size=32,
+            num_layers=1, num_heads=4, max_position_embeddings=64,
+            attn_dropout=0.0, hidden_dropout=0.0)
+        model.bfloat16()
+        opt = paddle.optimizer.AdamW(5e-3, parameters=model.parameters())
+        tr = ParallelTrainer(
+            model, opt, lambda out, lbl: model.loss(*out, *lbl),
+            scaler=GradScaler(enable=True, init_loss_scaling=1024.0))
+        rng = np.random.RandomState(0)
+        ids = rng.randint(0, 128, (4, 16)).astype("int32")
+        mlm = np.full((4, 16), -100, dtype="int32")
+        mlm[:, ::4] = rng.randint(0, 128, (4, 4))
+        return tr, ids, (mlm, rng.randint(0, 2, (4,)).astype("int32"))
+
+    @staticmethod
+    def _gpt_fused_ce_remat_bf16_moments():
+        from paddle_tpu.distributed.engine import ParallelTrainer
+        from paddle_tpu.text.models import GPTForPretraining
+        model = GPTForPretraining(
+            tensor_parallel=False, vocab_size=256, hidden_size=32,
+            num_layers=2, num_heads=2, max_position_embeddings=32,
+            attn_dropout=0.0, hidden_dropout=0.0)
+        model.bfloat16()
+
+        class FusedLoss(nn.Layer):
+            """(ids, labels) -> loss: the logits never materialize."""
+
+            def __init__(self, inner):
+                super().__init__()
+                self.inner = inner
+
+            def forward(self, batch):
+                return self.inner.fused_head_loss(*batch, chunk=64)
+
+        opt = paddle.optimizer.AdamW(5e-3, parameters=model.parameters(),
+                                     slot_dtype="bfloat16")
+        tr = ParallelTrainer(FusedLoss(model), opt,
+                             lambda out, _lbl: out, remat=True)
+        rng = np.random.RandomState(0)
+        ids = rng.randint(0, 256, (4, 32)).astype("int32")
+        labels = rng.randint(0, 256, (4, 32)).astype("int32")
+        return tr, (ids, labels), 0.0
+
+    @pytest.mark.slow  # 70 s, 13 s, 15 s: each compiles its step twice
+    @pytest.mark.parametrize("build", ["_resnet50_bf16",
+                                       "_bert_bf16_grad_scaler",
+                                       "_gpt_fused_ce_remat_bf16_moments"])
+    def test_model_walks_through_trainer(self, build):
+        """The model families no benchmark cell trains still meet
+        ParallelTrainer: five steps on one fixed batch, loss finite and
+        falling, nothing staged or compiled after the second step."""
+        from paddle_tpu import telemetry
+        make_mesh(data=1)
+        paddle.seed(0)
+        with telemetry.scope(profile=False) as tel:
+            tr, inputs, labels = getattr(self, build)()
+            losses = [float(tr.train_step(inputs, labels))
+                      for _ in range(2)]
+            compiled = tel.registry.get("recompiles_total").value()
+            losses += [float(tr.train_step(inputs, labels))
+                       for _ in range(3)]
+            assert tel.registry.get("recompiles_total").value() == compiled
+        assert np.all(np.isfinite(losses)), losses
+        assert losses[-1] < losses[0], losses
+        if tr.scaler is not None:
+            amp = tr.state["guard"]["amp"]
+            assert int(amp["good"]) == 5 and int(amp["bad"]) == 0
+
 
 class TestRingAttention:
     def test_matches_full_attention(self):

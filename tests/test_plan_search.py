@@ -115,8 +115,8 @@ def test_plan_apply_emits_trainer_kwargs():
 
 def test_planner_pick_beats_all_dp_and_memory_pick_at_8_chips():
     """The ISSUE 20 acceptance criterion, on the analytic calibrated
-    scale all three candidates share: bench-config GPT (the bench.py
-    CPU gpt_base shape), 8 chips."""
+    scale all three candidates share: tools/bench_plan.py's toy GPT
+    spec, 8 chips."""
     from tools import bench_plan
 
     spec = bench_plan._gpt_spec(smoke=False)

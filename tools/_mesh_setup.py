@@ -1,4 +1,4 @@
-"""Shared setup for the repo's CLI tools (chip_smoke.py, bench.py,
+"""Shared setup for the repo's CLI tools (chip_smoke.py,
 bench_collectives, lint_program): repo-root path handling,
 forced-host-device env, the persistent compile cache, and the plain data
 mesh every tool was rebuilding by hand.

@@ -68,7 +68,7 @@ def _overlap_trainer(buckets: int, smoke: bool, devices: int, policy: str):
 
     if smoke:
         vocab, h, layers, heads, seq, batch = 256, 64, 1, 2, 32, 4
-    else:  # the bench.py CPU gpt_base shape
+    else:  # the toy GPT shape bench_plan and lint_program share
         vocab, h, layers, heads, seq, batch = 1024, 128, 2, 4, 128, 4
     paddle.seed(0)
     model = GPTForPretraining(
@@ -103,7 +103,7 @@ def overlap_case(buckets: int, smoke: bool, devices: int,
 def _timed_trainer_steps(trainer, ids, labels, warmup: int,
                          iters: int) -> float:
     """Median per-step wall seconds of real train steps (loss fetch is
-    the sync point, like bench.py's _timed_steps)."""
+    the sync point)."""
     for _ in range(max(1, warmup)):
         loss = trainer.train_step(ids, labels)
     float(loss)

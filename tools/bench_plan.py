@@ -54,7 +54,7 @@ def _gpt_spec(smoke: bool):
     if smoke:
         return dict(vocab=256, h=64, layers=1, heads=2, seq=32,
                     batch_per_device=4)
-    # the bench.py CPU gpt_base shape
+    # the toy GPT shape bench_collectives and lint_program share
     return dict(vocab=1024, h=128, layers=2, heads=4, seq=128,
                 batch_per_device=4)
 

@@ -47,7 +47,7 @@ def _build_gpt(smoke: bool):
 
     if smoke:
         vocab, h, layers, heads, seq, batch = 256, 64, 1, 2, 32, 4
-    else:  # the bench.py CPU gpt_base shape
+    else:  # the toy GPT shape bench_plan and bench_collectives share
         vocab, h, layers, heads, seq, batch = 1024, 128, 2, 4, 128, 4
     paddle.seed(0)
     model = GPTForPretraining(
@@ -93,7 +93,7 @@ def _build_bert(smoke: bool):
         cfg = dict(vocab_size=256, hidden_size=64, num_layers=1,
                    num_heads=2, max_position_embeddings=32)
         batch, seq = 4, 32
-    else:  # the bench.py CPU bert_base_amp shape
+    else:  # a two-layer toy BERT
         cfg = dict(vocab_size=1024, hidden_size=128, num_layers=2,
                    num_heads=4, max_position_embeddings=128)
         batch, seq = 4, 64
