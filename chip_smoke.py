@@ -18,9 +18,13 @@ Phases (each through the entry points a user calls, weights from a seed):
   x 12 heads, bf16, batch 8): ``GPTForPretraining`` + ``AdamW`` +
   ``ParallelTrainer.train_step`` on one fixed batch. Loss starts near
   ln(vocab), stays finite and falls; nothing compiles after step 2; the
-  staged step holds the flash-attention Pallas calls and none fell back.
+  staged step holds the flash-attention Pallas calls, none fell back, and
+  the backward kernels cut their one causal tile into sub-blocks
+  (``flash_tiles_staged_total``).
 - ``kernels`` — each Pallas kernel compiled (``interpret=False``) against
-  its reference: flash fwd/bwd, fused LM-head CE fwd/bwd at the bench
+  its reference: flash fwd/bwd (the geometries whose tiles are computed by
+  sub-blocks among them, with the counter's kinds), fused LM-head CE
+  fwd/bwd at the bench
   shape against the chunked scan, paged decode and Tq=5 verify at
   h12/d64/page 16 bf16 against the XLA gather.
 - ``serve``  — ``DecodeServer`` over ``PagedKVCache`` with
@@ -188,6 +192,17 @@ def _check_flash_calls(kernels, layers):
           f"{len(KERNEL_NAMES) * layers} flash-attention Pallas calls")
 
 
+def _flash_tiles(registry):
+    """``flash_tiles_staged_total`` as ``{kernel: {kind: tiles}}``: how the
+    staged flash kernels compute the tiles a lane block's grid walks."""
+    from paddle_tpu.ops.pallas.flash_attention import KERNEL_NAMES, TILE_KINDS
+
+    staged = registry.get("flash_tiles_staged_total")
+    return {kernel: {kind: int(staged.value(kernel=kernel, kind=kind))
+                     for kind in TILE_KINDS}
+            for kernel in KERNEL_NAMES} if staged else {}
+
+
 def _config_origins(run, entries):
     """Where each kernel config used here resolves from. A tuning-DB
     file outside the checkout would make the run depend on what an
@@ -237,6 +252,7 @@ def phase_train(run: Run):
         flash_fallbacks = resolved.value(
             kernel="flash_attention", source="fallback") if resolved else 0
         recompiles = int(tel.registry.get("recompiles_total").value())
+        flash_tiles = _flash_tiles(tel.registry)
 
     losses = [l for l, _ in steps]
     steady = sorted(t for _, t in steps[2:])
@@ -252,6 +268,7 @@ def phase_train(run: Run):
         "recompiles_total": recompiles,
         "pallas_calls": kernels,
         "flash_fallbacks": int(flash_fallbacks),
+        "flash_tiles_staged": flash_tiles,
         "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
         "config_origins": _config_origins(run, {
             "flash_attention": (
@@ -275,12 +292,22 @@ def phase_train(run: Run):
         _check_flash_calls(kernels, cfg["layers"])
         check(flash_fallbacks == 0,
               f"flash attention fell back {flash_fallbacks} times")
+        # one causal (1024, 1024) tile a lane block: the backward kernels
+        # cut it into sub-blocks over the keys that can be seen, the
+        # forward computes it whole under its mask (ops/pallas/
+        # flash_attention.py, _SUB_ROWS)
+        want = {"flash_fwd": {"dense": 0, "triangular": 0, "masked": 1}}
+        for kernel in ("flash_bwd_dq", "flash_bwd_dkv"):
+            want[kernel] = {"dense": 0, "triangular": 1, "masked": 0}
+        check(flash_tiles == want,
+              f"flash tiles staged as {flash_tiles}, expected {want}")
         check(out["peak_bytes_in_use"], "backend reports no memory stats")
 
 
 def phase_kernels(run: Run):
     import jax.numpy as jnp
 
+    from paddle_tpu import telemetry
     from paddle_tpu.ops.pallas import paged_attention as pa
     from paddle_tpu.ops.pallas import tuner
     from tools import numerics_smoke as ns
@@ -292,9 +319,12 @@ def phase_kernels(run: Run):
         ce = dict(tokens=FULL["batch"] * FULL["seq"],
                   hidden=FULL["hidden"], vocab=FULL["vocab"])
         paged = dict(heads=12, head_dim=64, page_size=16, dtype="bfloat16")
-    checks = (ns.check_flash_attention(run.interpret)
-              + ns.check_fused_ce(run.interpret, **ce)
-              + ns.check_paged_attention(run.interpret, **paged))
+    with telemetry.scope(profile=False) as tel:
+        checks = ns.check_flash_tile_kinds(run.interpret)
+        flash_tiles = _flash_tiles(tel.registry)
+    checks += (ns.check_flash_attention(run.interpret)
+               + ns.check_fused_ce(run.interpret, **ce)
+               + ns.check_paged_attention(run.interpret, **paged))
     for c in checks:
         run.say("kernels", **c)
     entries = {"fused_ce": ("fused_ce", jnp.bfloat16, tuner.ce_dims(
@@ -306,9 +336,21 @@ def phase_kernels(run: Run):
                                        paged["page_size"], 8, tq=tq))
     origins = _config_origins(run, entries)
     run.say("kernels", event="result", n_checks=len(checks),
-            interpret=run.interpret, config_origins=origins)
+            interpret=run.interpret, config_origins=origins,
+            flash_tiles_staged=flash_tiles)
     bad = [c["check"] for c in checks if not c["ok"]]
     check(not bad, f"kernel checks out of tolerance: {bad}")
+    # the three geometries of check_flash_tile_kinds, (dense, triangular,
+    # masked): one 1024-row tile; two of them on the diagonal and one dense;
+    # 4 tiles of 512 rows on the diagonal and 3 band edges. A triangle is
+    # cut where a sub-block has 128 rows (forward), 128 or more (dq), 256
+    # or more (dk/dv); else it is whole under its mask
+    want = {"flash_fwd": (1, 7, 3), "flash_bwd_dq": (1, 10, 0),
+            "flash_bwd_dkv": (1, 3, 7)}
+    got = {kernel: tuple(kinds.values())
+           for kernel, kinds in flash_tiles.items()}
+    check(got == want, f"flash tiles staged as {flash_tiles}, expected "
+                       f"(dense, triangular, masked) {want}")
 
 
 def phase_serve(run: Run):

@@ -40,6 +40,31 @@ runs over the band of blocks a window leaves (first block from the index
 map) instead of over all of them, so blocks wholly outside the window cost
 neither a grid step nor a DMA.
 
+Tile kinds (ISSUE 30): ``_visible`` masks scores that the MXU has already
+formed, and the two backward kernels are bound by the MXU's passes, so a
+tile half of which the causal mask throws away pays for twice what it
+uses. What can be visible in tile ``(iq, ik)`` follows from its position
+and the static geometry (``_Tiles``), and each grid step takes the branch
+of its tile's kind: a *dense* tile (every pair visible: all of a call
+without ``causal`` and masked keys, and what lies strictly between a
+window's first tile and the diagonal) is one product over the whole tile
+with no mask; a *triangular* tile (the diagonal one, and the first tile
+of a band whose window is a whole number of blocks) is computed by
+``SUB_BLOCKS`` strips a side, static slices of the blocks already in VMEM,
+each over the rows and columns that can be visible: (n + 1) / 2n of the
+tile's products, the same sums without their exact zeros; every other
+tile is *masked*, computed whole under a mask as before. The kinds are
+known only for square blocks, ``sq == sk``, a window of whole blocks and
+no masked keys (``kv_lens``, a ragged tail); anything else is the masked
+path under ``_visible``, the general case of which the other two are what
+the kernel can see to be special. A kernel cuts a triangle only where the
+sub-blocks are of a size that pays in it (``_SUB_ROWS``, from the chip:
+the dq kernel from 128 rows up, the dk/dv kernel from 256 up, the
+forward, which the MXU does not bound, at 128 only); elsewhere the
+triangle is one strip, the whole tile under the triangle's mask. The
+tiles of each kind a staged kernel walks are counted in
+``flash_tiles_staged_total{kernel, kind}``.
+
 Trace names: each ``pallas_call`` carries ``name=`` (``flash_fwd``,
 ``flash_bwd_dq``, ``flash_bwd_dkv``). That names the kernel's op in a
 device trace and stages it under a ``jax.named_scope`` of the same string
@@ -154,13 +179,16 @@ class _Band:
                     self.nq - 1)
 
 
-def _dropout_mask(shape, rate, seed, b, iq, ik):
+def _dropout_mask(shape, rate, seed, b, iq, ik, row0=0, col0=0):
     """Deterministic per-block inverted-dropout multiplier in {0, 1/keep}.
 
     Counter-based hash PRNG (murmur3-style finalizer over
     (seed, block ids, element coords)) built from plain integer ops — the
     SAME bits on the CPU interpreter and on TPU, and trivially regenerated
-    by the backward kernels (pltpu.prng_* has no CPU-interpret lowering)."""
+    by the backward kernels (pltpu.prng_* has no CPU-interpret lowering).
+    ``shape`` is the whole tile ``(iq, ik)`` or the part of it that starts
+    at row ``row0``, column ``col0``: an element's bits follow from its
+    coordinates in the tile, however the tile is cut."""
     u32 = jnp.uint32
 
     def _u(x):
@@ -168,8 +196,8 @@ def _dropout_mask(shape, rate, seed, b, iq, ik):
         # (Mosaic cannot bitcast scalars)
         return jnp.asarray(x).astype(u32)
 
-    rows = jax.lax.broadcasted_iota(u32, shape, 0)
-    cols = jax.lax.broadcasted_iota(u32, shape, 1)
+    rows = jax.lax.broadcasted_iota(u32, shape, 0) + u32(row0)
+    cols = jax.lax.broadcasted_iota(u32, shape, 1) + u32(col0)
     h = (_u(seed) * u32(2654435761)
          ^ _u(b) * u32(0x9E3779B1)
          ^ _u(iq) * u32(0x85EBCA77)
@@ -313,6 +341,141 @@ def _visible(iq, ik, block_q, block_k, causal, window, kv_len):
     return keep
 
 
+DENSE, TRIANGULAR, MASKED = TILE_KINDS = ("dense", "triangular", "masked")
+# Sub-blocks a side of a triangular tile: 4 leave (4 + 1) / (2 x 4) = 62.5%
+# of the tile's MXU passes (2: 75%, 8: 56%).
+SUB_BLOCKS = 4
+# The rows of a sub-block (``block // SUB_BLOCKS``) between which each kernel
+# cuts a triangle; outside them it computes the tile whole under its mask.
+# Measured on a v5e (PERF.md section 6, PR 30; device ms of one layer, bf16,
+# (16, 1024, 12, 64) in (1024, 1024) tiles and laguna-xs2's window layer,
+# (4, 4096, 64 over 8, 128) in (512, 512) tiles; whole tile -> cut):
+# - flash_bwd_dq follows the MXU's passes: 0.834 -> 0.541 with 256 rows
+#   (0.640 with 512, 0.535 with 128), 5.99 -> 5.49 with 128.
+# - flash_bwd_dkv: 1.119 -> 0.900 with 256 rows and 0.891 with 512, but
+#   1.480 with 128 and 7.94 -> 10.23 in the window layer: its two products
+#   with a transposed left operand (dv, dk) want 256 rows a strip.
+# - flash_fwd is not bound by the MXU, and cutting costs it more than the
+#   passes give back wherever a strip's scores (sub x block float32)
+#   overflow the 64 vector registers: 0.817 -> 0.996 with 256 rows, 0.948
+#   with 512, 0.971 with 128; where they fit, 128 rows of a 512-row tile,
+#   9.41 -> 6.75.
+_SUB_ROWS = {FWD: (128, 128), BWD_DQ: (128, None), BWD_DKV: (256, None)}
+
+
+class _Tiles:
+    """What of each tile ``(iq, ik)`` of the grid can be visible, as far as
+    the static geometry says, and so how ``kernel`` computes it:
+
+    - ``dense``: every pair is visible, one product over the whole tile and
+      no mask. Every tile of a call without ``causal`` and without masked
+      keys; under ``causal`` the tiles strictly between the band's first
+      tile and the diagonal.
+    - ``triangular``: the tile on the diagonal (``ik == iq``, visible where
+      ``row >= col``) and, under a window of ``reach`` whole blocks, the
+      band's first tile (``ik == iq - reach``, visible where ``col >
+      row``), computed by ``SUB_BLOCKS`` strips a side, each over the rows
+      and columns that can be visible and masked in its one sub-block on
+      the diagonal (``strips``): (n + 1) / 2n of the tile's products.
+    - ``masked``: all of the tile is computed, under a mask. The general
+      case: blocks that are not square, ``sq != sk``, a window that is no
+      whole number of blocks, masked keys (``kv_lens``, a ragged tail),
+      where what a tile shows cannot be told from its position and
+      ``_visible`` says it; and a triangle whose sub-blocks are not whole
+      lane tiles or not of a size that pays in this kernel (``_SUB_ROWS``):
+      one strip, the whole tile under the triangle's mask.
+
+    A tile no query of which sees any key is skipped as before (``run`` in
+    the kernels) and has no kind. ``counts`` is the number of tiles of each
+    kind one lane block's grid walks."""
+
+    def __init__(self, kernel, causal, masked_keys, block_q, block_k, nq, nk,
+                 band, square):
+        self.causal, self.masked_keys, self.band = causal, masked_keys, band
+        self.bq, self.bk = block_q, block_k
+        window = None if band is None else band.window
+        # the kinds are known from a tile's position
+        self.exact = (causal and not masked_keys and square
+                      and block_q == block_k
+                      and (window is None or window % block_q == 0))
+        # under a window the band's first tile is ``reach`` blocks before
+        # the diagonal
+        self.reach = window // block_q if self.exact and window else None
+        # the rows of a strip: a sub-block where cutting pays, else the tile
+        sub, (least, most) = block_q // SUB_BLOCKS, _SUB_ROWS[kernel]
+        self.sub = sub if (sub % LANES == 0 and sub >= least
+                           and (most is None or sub <= most)) else block_q
+        self.counts = dict.fromkeys(TILE_KINDS, 0)
+        for iq in range(nq):
+            for ik in range(nk):
+                kind = self.kind(iq, ik)
+                if kind is not None:
+                    self.counts[kind] += 1
+
+    def kind(self, iq, ik):
+        """The kind of tile ``(iq, ik)`` (Python ints), None if skipped."""
+        if self.band is not None:
+            run = self.band.k_first(iq) <= ik <= self.band.k_last(iq)
+        else:
+            run = ik * self.bk < (iq + 1) * self.bq or not self.causal
+        if not run:
+            return None
+        if not self.exact:
+            return MASKED if self.causal or self.masked_keys else DENSE
+        if ik == iq or (self.reach is not None and ik == iq - self.reach):
+            return TRIANGULAR if self.sub < self.bq else MASKED
+        return DENSE
+
+    def branches(self, iq, ik, run):
+        """``(predicate, shape)`` of each way the kernel computes a tile of
+        this geometry, for traced ``iq``, ``ik`` and ``run`` (the tile is
+        not skipped): ``shape`` is ``"diagonal"``, ``"edge"`` (the two
+        triangles), ``DENSE`` or ``MASKED``. Only what the geometry has is
+        staged."""
+        if not self.exact:
+            return [(run, MASKED if self.counts[MASKED] else DENSE)]
+        out, below = [(ik == iq, "diagonal")], ik < iq
+        if self.reach is not None:
+            out.append((ik == iq - self.reach, "edge"))
+            below = below & (ik > iq - self.reach)
+        if self.counts[DENSE]:
+            out.append((below, DENSE))
+        return [(run & when, shape) for when, shape in out]
+
+    def strips(self, shape, by):
+        """``(row0, rows, col0, cols)`` of each product over a tile of
+        ``shape``: the whole tile where it is dense or masked; of a
+        triangle one strip a sub-block of query rows (``by="rows"``: the
+        forward and dq kernels, which write rows) or of key columns
+        (``by="cols"``: the dk/dv kernel), over the other axis' part that
+        the strip can see."""
+        b, sub = self.bq, self.sub
+        if shape in (DENSE, MASKED):
+            return [(0, self.bq, 0, self.bk)]
+        # one strip, the whole triangle, where this kernel does not cut
+        cut = [(i, sub) for i in range(0, b, sub)]
+        if shape == "diagonal":         # visible: row >= col
+            if by == "rows":
+                return [(r0, n, 0, r0 + n) for r0, n in cut]
+            return [(c0, b - c0, c0, n) for c0, n in cut]
+        if by == "rows":                # the band's edge, visible: col > row
+            return [(r0, n, r0, b - r0) for r0, n in cut]
+        return [(0, c0 + n, c0, n) for c0, n in cut]
+
+    def keep(self, shape, strip, iq, ik, kv_len):
+        """Which pairs of a strip may meet, None where all do."""
+        row0, rows, col0, cols = strip
+        if shape == DENSE:
+            return None
+        if shape == MASKED:
+            window = None if self.band is None else self.band.window
+            return _visible(iq, ik, self.bq, self.bk, self.causal, window,
+                            kv_len)
+        row = row0 + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+        col = col0 + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+        return row >= col if shape == "diagonal" else col > row
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
@@ -320,14 +483,14 @@ def _fwd_kernel(lens_ref, seed_ref,       # (blocks,) i32, (1,) i32 in SMEM
                 q_ref, k_ref, v_ref,      # (1,Bq,L), (1,Bk,L), (1,Bk,L)
                 o_ref, lse_ref,           # (1,Bq,L), (n,Bq,STAT_LANES)
                 m_scr, l_scr, acc_scr,    # (n,Bq,LANES) x 2, (Bq,L)
-                *, sm_scale, causal, block_q, block_k, num_k_blocks,
-                use_kv_mask, dropout_rate, pack, band=None):
+                *, sm_scale, num_k_blocks, use_kv_mask, dropout_rate, pack,
+                tiles):
     hb = pl.program_id(0)
     iq = pl.program_id(1)
     step = pl.program_id(2)
+    band, block_q = tiles.band, tiles.bq
     # under a window the inner dimension walks the band's key blocks only
     ik = step if band is None else band.k_first(iq) + step
-    window = None if band is None else band.window
 
     @pl.when(step == 0)
     def _init():
@@ -335,50 +498,57 @@ def _fwd_kernel(lens_ref, seed_ref,       # (blocks,) i32, (1,) i32 in SMEM
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    if band is not None:
-        run = ik <= band.k_last(iq)
-    else:
-        run = (ik * block_k < (iq + 1) * block_q) if causal else True
-
-    @pl.when(run)
-    def _compute():
+    def compute(shape):
         kv_len = lens_ref[hb] if use_kv_mask else None
 
         def head(j, _):
-            q, k, v = q_ref[0], k_ref[0], v_ref[0]
-            keep = _visible(iq, ik, block_q, block_k, causal, window, kv_len)
             t = pack.kv_slot(hb, j)
-            s = _scores(pack.place(q, j, t), k, sm_scale)
-            if keep is not None:
-                s = jnp.where(keep, s, NEG_INF)
-            m_prev = m_scr[j, :, :1]
-            m_cur = jnp.max(s, axis=1, keepdims=True)
-            m_new = jnp.maximum(m_prev, m_cur)
-            p = jnp.exp(s - m_new)
-            if keep is not None:
-                # NEG_INF is finite, so a FULLY-masked row has m_new == s
-                # and p == exp(0) == 1 — zero masked entries explicitly so
-                # l is 0 for such rows (out = 0, lse pinned to 0, no K/V
-                # grad leak)
-                p = p * (s > NEG_INF * 0.5)
-            alpha = jnp.exp(m_prev - m_new)
-            l_new = alpha * l_scr[j, :, :1] + jnp.sum(p, axis=1,
-                                                      keepdims=True)
-            if dropout_rate > 0.0:
-                # normalizer l uses the UNdropped p (softmax semantics);
-                # only the value accumulation is dropped
-                p = p * _dropout_mask(p.shape, dropout_rate, seed_ref[0],
-                                      pack.head_id(hb, j), iq, ik)
-            pv = jax.lax.dot_general(p.astype(v.dtype), v,
-                                     (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            acc = acc_scr[:]
-            acc_scr[:] = (acc * pack.where(j, alpha, 1.0, acc.shape)
-                          + pack.place(pv, t, j))
-            m_scr[j] = jnp.broadcast_to(m_new, m_scr.shape[1:])
-            l_scr[j] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+            for strip in tiles.strips(shape, "rows"):
+                row0, nr, col0, nc = strip
+                rows, cols = pl.ds(row0, nr), pl.ds(col0, nc)
+                q, k, v = q_ref[0, rows], k_ref[0, cols], v_ref[0, cols]
+                keep = tiles.keep(shape, strip, iq, ik, kv_len)
+                s = _scores(pack.place(q, j, t), k, sm_scale)
+                if keep is not None:
+                    s = jnp.where(keep, s, NEG_INF)
+                m_prev = m_scr[j, rows, :1]
+                m_cur = jnp.max(s, axis=1, keepdims=True)
+                m_new = jnp.maximum(m_prev, m_cur)
+                p = jnp.exp(s - m_new)
+                if keep is not None and shape != "diagonal":
+                    # NEG_INF is finite, so a FULLY-masked row has m_new ==
+                    # s and p == exp(0) == 1 — zero masked entries
+                    # explicitly so l is 0 for such rows (out = 0, lse
+                    # pinned to 0, no K/V grad leak). A row of the
+                    # diagonal tile sees its own key at least: its m_new
+                    # is a score and exp(NEG_INF - m_new) is 0 already
+                    p = p * (s > NEG_INF * 0.5)
+                alpha = jnp.exp(m_prev - m_new)
+                l_new = alpha * l_scr[j, rows, :1] + jnp.sum(
+                    p, axis=1, keepdims=True)
+                if dropout_rate > 0.0:
+                    # normalizer l uses the UNdropped p (softmax
+                    # semantics); only the value accumulation is dropped
+                    p = p * _dropout_mask(p.shape, dropout_rate, seed_ref[0],
+                                          pack.head_id(hb, j), iq, ik,
+                                          row0, col0)
+                pv = jax.lax.dot_general(p.astype(v.dtype), v,
+                                         (((1,), (0,)), ((), ())),
+                                         preferred_element_type=jnp.float32)
+                acc = acc_scr[rows]
+                acc_scr[rows] = (acc * pack.where(j, alpha, 1.0, acc.shape)
+                                 + pack.place(pv, t, j))
+                m_scr[j, rows] = jnp.broadcast_to(m_new, (nr, LANES))
+                l_scr[j, rows] = jnp.broadcast_to(l_new, (nr, LANES))
 
         pack.each_head(head)
+
+    if band is not None:
+        run = ik <= band.k_last(iq)
+    else:
+        run = (ik * tiles.bk < (iq + 1) * block_q) if tiles.causal else True
+    for when, shape in tiles.branches(iq, ik, run):
+        pl.when(when)(functools.partial(compute, shape))
 
     @pl.when(step == num_k_blocks - 1)
     def _finalize():
@@ -412,7 +582,8 @@ def _kv_index_maps(group, band):
         head(b), jnp.minimum(band.k_first(i) + j, band.k_last(i)), 0)
 
 
-def _geometry(q, k, d, heads, block_q, block_k, window):
+def _geometry(q, k, d, heads, block_q, block_k, window, causal,
+              use_kv_mask):
     """What the three calls share, from the operands' shapes; ``heads`` is
     the pair (the caller's query heads, the stored ones)."""
     blocks, sq, sk = q.shape[0], q.shape[1], k.shape[1]
@@ -420,7 +591,23 @@ def _geometry(q, k, d, heads, block_q, block_k, window):
     nq = pl.cdiv(sq, block_q)
     nk = pl.cdiv(sk, block_k)
     band = None if window is None else _Band(window, block_q, block_k, nq, nk)
-    return blocks, sq, group, _Pack(d, group, *heads), nq, nk, band
+    tiles = {kernel: _Tiles(kernel, causal, use_kv_mask, block_q, block_k,
+                            nq, nk, band, sq == sk)
+             for kernel in KERNEL_NAMES}
+    return blocks, sq, group, _Pack(d, group, *heads), nq, nk, band, tiles
+
+
+def _count_tiles(kernel, tiles):
+    """``flash_tiles_staged_total{kernel, kind}``: the tiles of each kind
+    one lane block's grid walks in a kernel that is being staged."""
+    from ... import telemetry
+    if telemetry.enabled():
+        counter = telemetry.counter(
+            "flash_tiles_staged_total",
+            "Tiles a lane block's grid walks in a staged flash kernel, by "
+            "how they are computed")
+        for kind, n in tiles.counts.items():
+            counter.inc(n, kernel=kernel, kind=kind)
 
 
 # ``_fwd`` and ``_bwd_calls`` are jitted so that the layers of a model share
@@ -435,15 +622,16 @@ def _geometry(q, k, d, heads, block_q, block_k, window):
 @functools.partial(jax.jit, static_argnums=tuple(range(5, 15)))
 def _fwd(q, k, v, lens, seed, sm_scale, causal, block_q, block_k,
          use_kv_mask, dropout_rate, interpret, window, d, heads):
-    blocks, sq, group, pack, nq, nk, band = _geometry(
-        q, k, d, heads, block_q, block_k, window)
+    blocks, sq, group, pack, nq, nk, band, tiles = _geometry(
+        q, k, d, heads, block_q, block_k, window, causal, use_kv_mask)
     lanes, n = pack.lanes, pack.n
     steps = nk if band is None else band.k_steps
     kv_map = _kv_index_maps(group, band)
+    _count_tiles(FWD, tiles[FWD])
     kernel = functools.partial(
-        _fwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
-        block_k=block_k, num_k_blocks=steps, use_kv_mask=use_kv_mask,
-        dropout_rate=dropout_rate, pack=pack, band=band)
+        _fwd_kernel, sm_scale=sm_scale, num_k_blocks=steps,
+        use_kv_mask=use_kv_mask, dropout_rate=dropout_rate, pack=pack,
+        tiles=tiles[FWD])
     out, lse = pl.pallas_call(
         kernel,
         grid=(blocks, nq, steps),
@@ -478,48 +666,54 @@ def _fwd(q, k, v, lens, seed, sm_scale, causal, block_q, block_k,
 # ---------------------------------------------------------------------------
 def _bwd_dq_kernel(lens_ref, seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                    delta_ref, dq_ref, dq_scr,
-                   *, sm_scale, causal, block_q, block_k, num_k_blocks,
-                   use_kv_mask, dropout_rate, pack, band=None):
+                   *, sm_scale, num_k_blocks, use_kv_mask, dropout_rate,
+                   pack, tiles):
     hb = pl.program_id(0)
     iq = pl.program_id(1)
     step = pl.program_id(2)
+    band, block_q = tiles.band, tiles.bq
     ik = step if band is None else band.k_first(iq) + step
-    window = None if band is None else band.window
 
     @pl.when(step == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    if band is not None:
-        run = ik <= band.k_last(iq)
-    else:
-        run = (ik * block_k < (iq + 1) * block_q) if causal else True
-
-    @pl.when(run)
-    def _compute():
+    def compute(shape):
         kv_len = lens_ref[hb] if use_kv_mask else None
 
         def head(j, _):
-            q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-            keep = _visible(iq, ik, block_q, block_k, causal, window, kv_len)
             t = pack.kv_slot(hb, j)
-            s = _scores(pack.place(q, j, t), k, sm_scale)
-            if keep is not None:
-                s = jnp.where(keep, s, NEG_INF)
-            p = jnp.exp(s - lse_ref[j, :, :1])
-            dp = jax.lax.dot_general(pack.place(do, j, t), v,
-                                     (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            if dropout_rate > 0.0:
-                dp = dp * _dropout_mask(dp.shape, dropout_rate, seed_ref[0],
-                                        pack.head_id(hb, j), iq, ik)
-            ds = p * (dp - delta_ref[j, :, :1])
-            dq = jax.lax.dot_general(
-                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dq_scr[:] += sm_scale * pack.place(dq, t, j)
+            for strip in tiles.strips(shape, "rows"):
+                row0, nr, col0, nc = strip
+                rows, cols = pl.ds(row0, nr), pl.ds(col0, nc)
+                q, do = q_ref[0, rows], do_ref[0, rows]
+                k, v = k_ref[0, cols], v_ref[0, cols]
+                keep = tiles.keep(shape, strip, iq, ik, kv_len)
+                s = _scores(pack.place(q, j, t), k, sm_scale)
+                if keep is not None:
+                    s = jnp.where(keep, s, NEG_INF)
+                p = jnp.exp(s - lse_ref[j, rows, :1])
+                dp = jax.lax.dot_general(pack.place(do, j, t), v,
+                                         (((1,), (1,)), ((), ())),
+                                         preferred_element_type=jnp.float32)
+                if dropout_rate > 0.0:
+                    dp = dp * _dropout_mask(
+                        dp.shape, dropout_rate, seed_ref[0],
+                        pack.head_id(hb, j), iq, ik, row0, col0)
+                ds = p * (dp - delta_ref[j, rows, :1])
+                dq = jax.lax.dot_general(
+                    ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                dq_scr[rows] += sm_scale * pack.place(dq, t, j)
 
         pack.each_head(head)
+
+    if band is not None:
+        run = ik <= band.k_last(iq)
+    else:
+        run = (ik * tiles.bk < (iq + 1) * block_q) if tiles.causal else True
+    for when, shape in tiles.branches(iq, ik, run):
+        pl.when(when)(functools.partial(compute, shape))
 
     @pl.when(step == num_k_blocks - 1)
     def _finalize():
@@ -528,12 +722,12 @@ def _bwd_dq_kernel(lens_ref, seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 def _bwd_dkv_kernel(lens_ref, seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, dk_ref, dv_ref, dk_scr, dv_scr,
-                    *, sm_scale, causal, block_q, block_k, num_q_blocks,
-                    use_kv_mask, dropout_rate, pack, band=None):
+                    *, sm_scale, num_q_blocks, use_kv_mask, dropout_rate,
+                    pack, tiles):
     # the grid's first dimension is the KV lane block; the inner one walks
     # the group's query lane blocks, and for each the q blocks (all of
     # them, or the band a window leaves): ``num_q_blocks`` steps each
-    group = pack.group
+    group, band, block_q = pack.group, tiles.band, tiles.bq
     hb = pl.program_id(0)
     ik = pl.program_id(1)
     step = pl.program_id(2)
@@ -542,53 +736,58 @@ def _bwd_dkv_kernel(lens_ref, seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         hb = hb * group + step // num_q_blocks
         jq = step % num_q_blocks
     iq = jq if band is None else band.q_first(ik) + jq
-    window = None if band is None else band.window
 
     @pl.when(step == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    if band is not None:
-        run = iq <= band.q_last(ik)
-    else:
-        run = ((iq + 1) * block_q > ik * block_k) if causal else True
-
-    @pl.when(run)
-    def _compute():
+    def compute(shape):
         kv_len = lens_ref[hb] if use_kv_mask else None
 
         def head(j, _):
-            q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-            keep = _visible(iq, ik, block_q, block_k, causal, window, kv_len)
-            # q and do of head j in its KV head's lanes, zeros elsewhere:
-            # dv and dk come out with the other slots' lanes zero
             t = pack.kv_slot(hb, j)
-            q_j, do_j = pack.place(q, j, t), pack.place(do, j, t)
-            s = _scores(q_j, k, sm_scale)
-            if keep is not None:
-                s = jnp.where(keep, s, NEG_INF)
-            p = jnp.exp(s - lse_ref[j, :, :1])          # (Bq, Bk)
-            if dropout_rate > 0.0:
-                m = _dropout_mask(p.shape, dropout_rate, seed_ref[0],
-                                  pack.head_id(hb, j), iq, ik)
-                p_drop = p * m
-            else:
-                m = None
-                p_drop = p
-            dv_scr[:] += jax.lax.dot_general(
-                p_drop.astype(do.dtype), do_j, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dp = jax.lax.dot_general(do_j, v, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            if m is not None:
-                dp = dp * m
-            ds = p * (dp - delta_ref[j, :, :1])         # (Bq, Bk)
-            dk_scr[:] += sm_scale * jax.lax.dot_general(
-                ds.astype(q.dtype), q_j, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            for strip in tiles.strips(shape, "cols"):
+                row0, nr, col0, nc = strip
+                rows, cols = pl.ds(row0, nr), pl.ds(col0, nc)
+                q, do = q_ref[0, rows], do_ref[0, rows]
+                k, v = k_ref[0, cols], v_ref[0, cols]
+                keep = tiles.keep(shape, strip, iq, ik, kv_len)
+                # q and do of head j in its KV head's lanes, zeros
+                # elsewhere: dv and dk come out with the other slots' lanes
+                # zero
+                q_j, do_j = pack.place(q, j, t), pack.place(do, j, t)
+                s = _scores(q_j, k, sm_scale)
+                if keep is not None:
+                    s = jnp.where(keep, s, NEG_INF)
+                p = jnp.exp(s - lse_ref[j, rows, :1])       # (rows, cols)
+                if dropout_rate > 0.0:
+                    m = _dropout_mask(p.shape, dropout_rate, seed_ref[0],
+                                      pack.head_id(hb, j), iq, ik, row0, col0)
+                    p_drop = p * m
+                else:
+                    m = None
+                    p_drop = p
+                dv_scr[cols] += jax.lax.dot_general(
+                    p_drop.astype(do.dtype), do_j, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                dp = jax.lax.dot_general(do_j, v, (((1,), (1,)), ((), ())),
+                                         preferred_element_type=jnp.float32)
+                if m is not None:
+                    dp = dp * m
+                ds = p * (dp - delta_ref[j, rows, :1])      # (rows, cols)
+                dk_scr[cols] += sm_scale * jax.lax.dot_general(
+                    ds.astype(q.dtype), q_j, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
 
         pack.each_head(head)
+
+    if band is not None:
+        run = iq <= band.q_last(ik)
+    else:
+        run = ((iq + 1) * block_q > ik * tiles.bk) if tiles.causal else True
+    for when, shape in tiles.branches(iq, ik, run):
+        pl.when(when)(functools.partial(compute, shape))
 
     @pl.when(step == group * num_q_blocks - 1)
     def _finalize():
@@ -610,9 +809,11 @@ def _bwd(sm_scale, causal, block_q, block_k, use_kv_mask, dropout_rate,
 def _bwd_calls(sm_scale, causal, block_q, block_k, use_kv_mask, dropout_rate,
                interpret, window, d, heads, res, do):
     q, k, v, lens, seed, out, lse = res
-    blocks, _, group, pack, nq, nk, band = _geometry(
-        q, k, d, heads, block_q, block_k, window)
+    blocks, _, group, pack, nq, nk, band, tiles = _geometry(
+        q, k, d, heads, block_q, block_k, window, causal, use_kv_mask)
     lanes, n = pack.lanes, pack.n
+    _count_tiles(BWD_DQ, tiles[BWD_DQ])
+    _count_tiles(BWD_DKV, tiles[BWD_DKV])
     k_steps = nk if band is None else band.k_steps
     q_steps = nq if band is None else band.q_steps
     kv_map = _kv_index_maps(group, band)
@@ -626,12 +827,12 @@ def _bwd_calls(sm_scale, causal, block_q, block_k, use_kv_mask, dropout_rate,
     q_spec = pl.BlockSpec((1, block_q, lanes), lambda b, i, j: (b, i, 0))
     stat_spec = pl.BlockSpec((n, block_q, STAT_LANES),
                              lambda b, i, j: (b, i, 0))
-    common = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
-                  block_k=block_k, use_kv_mask=use_kv_mask,
-                  dropout_rate=dropout_rate, pack=pack, band=band)
+    common = dict(sm_scale=sm_scale, use_kv_mask=use_kv_mask,
+                  dropout_rate=dropout_rate, pack=pack)
 
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, num_k_blocks=k_steps, **common),
+        functools.partial(_bwd_dq_kernel, num_k_blocks=k_steps,
+                          tiles=tiles[BWD_DQ], **common),
         grid=(blocks, nq, k_steps),
         in_specs=[
             lens_spec,
@@ -663,7 +864,8 @@ def _bwd_calls(sm_scale, causal, block_q, block_k, use_kv_mask, dropout_rate,
     kv_spec = pl.BlockSpec((1, block_k, lanes), lambda b, j, i: (b, j, 0))
 
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, num_q_blocks=q_steps, **common),
+        functools.partial(_bwd_dkv_kernel, num_q_blocks=q_steps,
+                          tiles=tiles[BWD_DKV], **common),
         grid=(k.shape[0], nk, group * q_steps),
         in_specs=[
             lens_spec,
@@ -776,6 +978,16 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, kv_lens=None,
     window: optional int, causal only — query ``i`` sees key ``j`` iff
     ``0 <= i - j < window`` (its own position and the ``window - 1``
     before it).
+
+    How a tile is computed follows from what the kernels can observe
+    (``_Tiles``), not from an argument: with ``causal``, square blocks,
+    ``sq == sk``, no ``kv_lens`` or ragged tail and a window of whole
+    blocks (or none), the tiles between a band's first tile and the
+    diagonal are computed without a mask, and those two by sub-blocks
+    over the keys that can be seen where the sub-blocks are of a size that
+    pays in the kernel (``_SUB_ROWS``); any other call runs every tile
+    whole under ``_visible``. The values are the same: only exact zeros
+    leave the sums, and dropout draws the same bits.
 
     kv_lens: optional (batch,) int32 — per-row count of VALID key/value
     positions (a trailing-padding key mask, the (B,1,1,T) boolean

@@ -55,6 +55,12 @@ checkpoint_bytes_total         counter    distributed.checkpoint {op=...}
 pallas_config_resolved_total   counter    ops.pallas.tuner.resolve, trace
                                           time {kernel=...,
                                           source=db|default|fallback}
+flash_tiles_staged_total       counter    ops.pallas.flash_attention, where
+                                          a kernel is staged: the tiles one
+                                          lane block's grid walks
+                                          {kernel=flash_fwd|flash_bwd_dq|
+                                          flash_bwd_dkv, kind=dense|
+                                          triangular|masked}
 moe_tokens_routed_total        counter    incubate.moe DroplessMoELayer.
                                           publish_routing: tokens routed
 moe_held_assignments_total     counter    (token, expert) assignments on

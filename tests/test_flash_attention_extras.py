@@ -293,7 +293,8 @@ def _flash_grad_jaxpr(dtype, d=64, **kw):
 @pytest.mark.parametrize("d", [64, 128])
 def test_kernel_products_take_the_operand_dtype(d, dtype, kw):
     """Walks the three kernels' jaxprs through their ``pallas_call``s:
-    all nine ``dot_general``s (staged once whatever the heads a lane block:
+    all nine ``dot_general``s of each way a tile is computed (staged once
+    whatever the heads a lane block:
     two heads at width 64 are the trips of one loop) multiply in the dtype
     q/k/v arrive in (bf16
     stays bf16, the MXU's packed format; float32 callers keep float32
@@ -321,7 +322,12 @@ def test_kernel_products_take_the_operand_dtype(d, dtype, kw):
                         src.primitive.name == "convert_element_type":
                     assert src.params["new_dtype"] != jnp.float32, kernel
                     src = producer.get(src.invars[0])
-    assert dots == {"flash_fwd": 2, "flash_bwd_dq": 3, "flash_bwd_dkv": 4}
+    # once a way a tile is computed: a causal call of two blocks a side has
+    # the diagonal tile (whole here: 128 rows are too few to cut) and the
+    # dense one before it
+    ways = 2 if kw.get("causal") else 1
+    assert dots == {"flash_fwd": 2 * ways, "flash_bwd_dq": 3 * ways,
+                    "flash_bwd_dkv": 4 * ways}
 
 
 # ---------------------------------------------------------------------------
@@ -351,6 +357,17 @@ def _layout_case(seed, b, s, h, h_kv, d, dtype=jnp.float32):
     (1, 256, 2, 2, 32, None, None, (128, 128)),     # four heads a block
     (1, 256, 3, 3, 80, None, None, (128, 128)),     # a width padded to 128
     (1, 256, 2, 1, 256, None, None, (128, 128)),    # two lane blocks a head
+    # tiles computed by sub-blocks (ISSUE 30): one causal tile, two heads a
+    # lane block; four tiles a side, 6 heads a KV head; a window of one
+    # block, 8 heads a KV head: both triangles, no tile between them
+    (1, 1024, 2, 2, 64, None, None, (1024, 1024)),
+    (1, 2048, 6, 1, 128, None, None, (512, 512)),
+    (1, 2048, 8, 1, 128, 512, None, (512, 512)),
+    (1, 2048, 2, 1, 128, 1024, None, (512, 512)),   # and one dense between
+    # what falls to the masked path: a ragged length and a kv_lens that
+    # cuts a diagonal tile; blocks that are not square
+    (2, 1000, 2, 2, 64, None, (1000, 700), (512, 512)),
+    (1, 1024, 2, 2, 64, None, None, (512, 1024)),
 ], ids=lambda x: "x".join(map(str, x)) if isinstance(x, tuple) else str(x))
 def test_lane_blocks_match_xla(b, s, h, h_kv, d, window, kv_lens, blocks):
     """Forward and the three gradients of the kernels (interpret mode,
@@ -512,3 +529,146 @@ def test_a_block_on_the_flash_path_moves_no_padded_row(make, monkeypatch):
                 assert v.aval.shape[1:] == (s, LANES), v.aval
     assert len(transposes) <= 8 and all(
         t[-1] == LANES and len(t) == 4 for t in transposes), transposes
+
+
+# ---------------------------------------------------------------------------
+# tile kinds (ISSUE 30): a tile whose visible part is a triangle is computed
+# by sub-blocks over the rows and columns that can be visible
+# ---------------------------------------------------------------------------
+# name: (b, s, h, h_kv, d, kw of the call, blocks, the (dense, triangular,
+#        masked) tiles a lane block's grid walks in flash_fwd, flash_bwd_dq
+#        and flash_bwd_dkv, or one triple for all three). A triangle is
+#        cut by the forward where a sub-block has 128 rows, by the dq kernel
+#        from 128 up, by the dk/dv kernel from 256 up (``_SUB_ROWS``); where
+#        it is not cut it is computed whole under its mask: ``masked``
+_TILE_CASES = {
+    "one_causal_tile_two_heads_a_block": (
+        1, 1024, 2, 2, 64, dict(causal=True), (1024, 1024),
+        ((0, 0, 1), (0, 1, 0), (0, 1, 0))),
+    "four_tiles_a_side_6_over_1": (
+        1, 2048, 6, 1, 128, dict(causal=True), (512, 512),
+        ((6, 4, 0), (6, 4, 0), (6, 0, 4))),
+    "window_of_a_block_8_over_1": (
+        1, 2048, 8, 1, 128, dict(causal=True, window=512), (512, 512),
+        ((0, 7, 0), (0, 7, 0), (0, 0, 7))),
+    "window_of_two_blocks": (
+        1, 2048, 2, 1, 128, dict(causal=True, window=1024), (512, 512),
+        ((3, 6, 0), (3, 6, 0), (3, 0, 6))),
+    "two_tiles_a_side_of_1024_rows": (
+        1, 2048, 1, 1, 128, dict(causal=True), (1024, 1024),
+        ((1, 0, 2), (1, 2, 0), (1, 2, 0))),
+    "dropout_draws_the_same_bits": (
+        1, 1024, 2, 2, 64, dict(causal=True, dropout_rate=0.1,
+                                dropout_seed=77), (512, 512),
+        ((1, 2, 0), (1, 2, 0), (1, 0, 2))),
+    "dropout_in_a_tile_of_1024_rows": (
+        1, 1024, 2, 2, 64, dict(causal=True, dropout_rate=0.1,
+                                dropout_seed=78), (1024, 1024),
+        ((0, 0, 1), (0, 1, 0), (0, 1, 0))),
+    "ragged_and_kv_lens_cut_a_diagonal_tile": (
+        2, 1000, 2, 2, 64, dict(causal=True, kv_lens=(1000, 700)),
+        (512, 512), (0, 0, 3)),
+    "blocks_not_square": (
+        1, 1024, 2, 2, 64, dict(causal=True), (512, 1024), (0, 0, 2)),
+    "window_no_whole_number_of_blocks": (
+        1, 1024, 2, 1, 128, dict(causal=True, window=700), (512, 512),
+        (0, 0, 3)),
+    "block_too_small_to_cut": (
+        1, 512, 2, 2, 64, dict(causal=True), (256, 256), (1, 0, 2)),
+    "non_causal": (
+        1, 1024, 2, 2, 64, dict(), (512, 512), (4, 0, 0)),
+}
+
+
+@pytest.fixture
+def tile_counter():
+    """A fresh telemetry registry, and the two staged functions' caches
+    dropped: the tiles are counted where a kernel is staged, and a shape an
+    earlier test staged would count nothing."""
+    import importlib
+
+    from paddle_tpu import telemetry
+    from paddle_tpu.telemetry.metrics import Registry
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    prev, reg = telemetry.get_registry(), Registry()
+    telemetry._set_registry(reg)
+    telemetry.enable()
+    fa._fwd.clear_cache()
+    fa._bwd_calls.clear_cache()
+    yield lambda: reg.get("flash_tiles_staged_total")
+    telemetry.disable()
+    telemetry._set_registry(prev)
+
+
+@pytest.mark.parametrize("case", list(_TILE_CASES))
+def test_tile_kinds_match_the_masked_path(case, tile_counter):
+    """Output and the three gradients of each geometry against
+    ``_xla_attention`` (where the reference can say it) and against the same
+    call forced onto the masked dense path, which a ``kv_lens`` of the full
+    length does without masking a key: equal to float32 rounding, with
+    dropout too, so the sub-blocks draw the bits the whole tile draws. The
+    counter reads the tiles of each kind for each of the three kernels."""
+    from paddle_tpu.ops.pallas.flash_attention import KERNEL_NAMES, TILE_KINDS
+    b, s, h, h_kv, d, kw, blocks, kinds = _TILE_CASES[case]
+    kw = dict(kw)
+    q, k, v, do = _layout_case(11, b, s, h, h_kv, d)
+    mask = None
+    if "kv_lens" in kw:
+        kw["kv_lens"] = jnp.asarray(kw["kv_lens"], jnp.int32)
+        mask = (jnp.arange(s)[None, None, None, :] <
+                kw["kv_lens"].reshape(-1, 1, 1, 1))
+
+    def run(**more):
+        def loss(q, k, v):
+            out = flash_attention(q, k, v, block_q=blocks[0],
+                                  block_k=blocks[1], interpret=True,
+                                  **dict(kw, **more))
+            return jnp.sum(do * out), out
+        grads, out = jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return (out,) + grads
+
+    if not isinstance(kinds[0], tuple):
+        kinds = (kinds,) * 3        # the same in all three kernels
+    got = run()
+    counter = tile_counter()
+
+    def staged():
+        return tuple(tuple(int(counter.value(kernel=kernel, kind=kind))
+                           for kind in TILE_KINDS) for kernel in KERNEL_NAMES)
+
+    assert staged() == kinds
+    names = ("out", "dq", "dk", "dv")
+    if "kv_lens" not in kw:
+        forced = run(kv_lens=jnp.full((b,), s, jnp.int32))
+        assert staged() == tuple(
+            (dense, cut, masked + dense + cut + masked)
+            for dense, cut, masked in kinds)
+        for name, a, w in zip(names, got, forced):
+            np.testing.assert_allclose(a, w, rtol=1e-5, atol=1e-5,
+                                       err_msg=name)
+    if "dropout_rate" not in kw:
+        def xla(q, k, v):
+            out = _xla_attention(q, k, v, mask=mask,
+                                 causal=kw.get("causal", False),
+                                 window=kw.get("window"))
+            return jnp.sum(do * out), out
+        want, ref = jax.grad(xla, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        for name, a, w in zip(names, got, (ref,) + want):
+            np.testing.assert_allclose(a, w, rtol=5e-4, atol=5e-4,
+                                       err_msg=name)
+
+
+@pytest.mark.parametrize("row0, rows, col0, cols", [
+    (0, 256, 0, 256), (256, 256, 0, 512), (768, 256, 0, 1024),
+    (256, 768, 256, 256), (128, 128, 128, 384)])
+def test_dropout_bits_of_a_part_are_the_tiles(row0, rows, col0, cols):
+    """The multiplier of a part of a tile is that part of the tile's
+    multiplier, bit for bit: the hash is fed an element's coordinates in
+    the tile, not in the array it is drawn for."""
+    from paddle_tpu.ops.pallas.flash_attention import _dropout_mask
+    whole = _dropout_mask((1024, 1024), 0.1, 1234, 5, 2, 1)
+    part = _dropout_mask((rows, cols), 0.1, 1234, 5, 2, 1, row0, col0)
+    np.testing.assert_array_equal(
+        np.asarray(part),
+        np.asarray(whole[row0:row0 + rows, col0:col0 + cols]))
+    assert 0.05 < float(jnp.mean(part == 0.0)) < 0.15

@@ -40,6 +40,20 @@ def _compile(fn, sharding, *shapes):
     return text
 
 
+def _tile_kinds(seq, block, window):
+    """(dense, triangular, masked) tiles a lane block's grid walks in each
+    kernel of a causal call, as ``flash_tiles_staged_total`` counts them."""
+    import importlib
+
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    n = seq // block
+    band = None if window is None else fa._Band(window, block, block, n, n)
+    return {kernel: tuple(fa._Tiles(kernel, True, False, block, block, n, n,
+                                    band, True).counts[kind]
+                          for kind in fa.TILE_KINDS)
+            for kernel in fa.KERNEL_NAMES}
+
+
 @pytest.mark.parametrize("kernel, transform", [
     ("flash_fwd", "jvp("), ("flash_bwd_dq", "transpose(jvp("),
     ("flash_bwd_dkv", "transpose(jvp(")])
@@ -93,6 +107,11 @@ def test_flash_attention_fwd_bwd_gpt_base(v5e, shape):
 
     qkv = (shape, jnp.bfloat16)
     _compile(f, v5e, qkv, qkv, qkv)
+    # what lowered: the one causal tile of a lane block cut into sub-blocks
+    # in the two backward kernels, whole under its mask in the forward
+    assert _tile_kinds(1024, 1024, None) == {
+        "flash_fwd": (0, 0, 1), "flash_bwd_dq": (0, 1, 0),
+        "flash_bwd_dkv": (0, 1, 0)}
 
 
 @pytest.mark.parametrize("heads, window, blocks", [
@@ -137,6 +156,14 @@ def test_flash_grouped_heads_and_window_at_the_mixed_decoder_shapes(
     assert dkv and re.search(
         r"= \(bf16\[16,4096,128\]\S*, bf16\[16,4096,128\]", dkv[0])
     assert f"bf16[{2 * heads},4096,128]" in dkv[0]
+    # what lowered. Full layers: 4 tiles on the diagonal, 6 dense before
+    # them. Window layers: 8 on the diagonal and the 7 band edges, cut in
+    # the forward and the dq kernel (sub-blocks of 128 rows); the dk/dv
+    # kernel cuts from 256 rows up
+    cut, whole = ((0, 15, 0), (0, 0, 15)) if window else ((6, 4, 0), (6, 0, 4))
+    assert _tile_kinds(4096, blocks[0], window) == {
+        "flash_fwd": cut if window else whole, "flash_bwd_dq": cut,
+        "flash_bwd_dkv": whole if window else cut}
 
 
 @pytest.mark.parametrize("heads, kv_heads, kw", [

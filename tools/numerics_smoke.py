@@ -5,7 +5,7 @@ Every Pallas kernel is trajectory-tested on the CPU interpreter, but TPU
 hardware rounds differently (bf16 MXU accumulation, revectorized
 reductions) and the interpreter never meets the TPU lowering. This
 script runs the hot kernels — flash attention fwd/bwd (causal,
-kv-masked), the fused LM-head CE fwd/bwd, paged decode/verify attention,
+kv-masked, and the geometries whose tiles are computed by sub-blocks), the fused LM-head CE fwd/bwd, paged decode/verify attention,
 chunked LM cross-entropy fwd/bwd, bf16 matmul — on whatever backend is
 live and checks errors against references with bf16-appropriate
 tolerances.
@@ -91,6 +91,55 @@ def check_flash_attention(interpret):
     # backward accumulates over seq: looser than fwd
     results.append({"check": "flash_bwd_causal", "max_abs_err": gerr,
                     "tol": 0.5, "ok": gerr < 0.5})
+    return results
+
+
+def check_flash_tile_kinds(interpret):
+    """The geometries whose tiles the kernels tell apart, as the cells run
+    them (fewer rows and heads): one causal tile at head width 64 with two
+    heads a lane block (blocks from the tuning DB), tiles of 1,024 rows at
+    width 128 with grouped heads (two on the diagonal, a dense one before
+    them), and a window of one 512-row block (both triangles, none
+    between). Output and the three gradients against float32 XLA attention
+    of the same inputs, each within 1% of the reference tensor's largest
+    magnitude (the kernels' own roundings to bf16;
+    tests/test_flash_attention_extras.py derives the figure)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.nn.functional.attention import _xla_attention
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+
+    results = []
+    for name, (s, h, h_kv, d, kw) in {
+            "causal_tile_d64": (1024, 4, 4, 64, {}),
+            "tiles_of_1024_d128_6_over_1": (
+                2048, 6, 1, 128, dict(block_q=1024, block_k=1024)),
+            "window_512_d128_8_over_1": (
+                2048, 8, 1, 128, dict(window=512, block_q=512, block_k=512)),
+    }.items():
+        ks = jax.random.split(jax.random.key(len(name)), 3)
+        q = jax.random.normal(ks[0], (2, s, h, d), jnp.bfloat16)
+        k, v = (jax.random.normal(key, (2, s, h_kv, d), jnp.bfloat16)
+                for key in ks[1:])
+        window = kw.get("window")
+
+        def flash(q, k, v):
+            out = flash_attention(q, k, v, causal=True, interpret=interpret,
+                                  **kw)
+            return jnp.sum(out.astype(jnp.float32) ** 2), out
+
+        def ref(q, k, v):
+            out = _xla_attention(q, k, v, causal=True, window=window)
+            return jnp.sum(out ** 2), out
+
+        got, out = jax.grad(flash, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        want, out_ref = jax.grad(ref, argnums=(0, 1, 2), has_aux=True)(
+            *(x.astype(jnp.float32) for x in (q, k, v)))
+        rel = max(_max_err(a, w)[1]
+                  for a, w in zip((out,) + got, (out_ref,) + want))
+        results.append({"check": f"flash_{name}", "max_rel_err": rel,
+                        "tol": 1e-2, "ok": rel < 1e-2})
     return results
 
 
@@ -228,7 +277,7 @@ def main():
     backend = jax.default_backend()
     interpret = backend != "tpu"
     checks = []
-    for fn in (check_flash_attention, check_fused_ce,
+    for fn in (check_flash_attention, check_flash_tile_kinds, check_fused_ce,
                check_paged_attention):
         checks.extend(fn(interpret))
     for fn in (check_chunked_ce, check_bf16_matmul):
