@@ -255,6 +255,11 @@ class ParallelTrainer:
         self.buffer_specs = OrderedDict(
             (n, bspecs.get(n, P())) for n in buffers)
         self.trainable = OrderedDict((n, boxes[n].trainable) for n in params)
+        # ParamAttr(learning_rate=) multipliers, as Optimizer.step honours
+        # them; where every one is 1 the staged update is what it was
+        self.lr_scales = {n: boxes[n].optimize_attr["learning_rate"]
+                          for n in params if boxes[n].optimize_attr.get(
+                              "learning_rate", 1.0) != 1.0} or None
         tparams = OrderedDict((k, v) for k, v in params.items()
                               if self.trainable[k])
         opt_state = self.optimizer.init_state(tparams)
@@ -932,8 +937,9 @@ class ParallelTrainer:
                 # one op per leaf, so the device cannot tell them apart.
                 # Read by the benchmark's update_ms_per_step.
                 with jax.named_scope("update"):
-                    new_t, new_opt = opt.apply_gradients(tparams, grads,
-                                                         opt_state, lr=lr)
+                    new_t, new_opt = opt.apply_gradients(
+                        tparams, grads, opt_state, lr=lr,
+                        lr_scales=self.lr_scales)
                     new_params = dict(params)
                     new_params.update(new_t)
                     # keep optimizer slots on their ZeRO shardings

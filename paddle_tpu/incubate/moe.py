@@ -284,11 +284,17 @@ class DroplessMoELayer(Layer):
     mean per held expert); ``publish_routing`` writes them to the telemetry
     registry. ``scoring`` is the router's rule, ``"sigmoid"`` or
     ``"softmax"``; either way the chosen scores are divided by their sum and
-    multiplied by ``routed_scaling_factor``.
+    multiplied by ``routed_scaling_factor``. ``router_attr`` is the router
+    weight's ``ParamAttr`` (initialiser, learning-rate multiplier): AdamW
+    moves a fresh router a full step whatever its gradient, and at a rate
+    the rest of a model trains at, the held experts' load runs to nothing or
+    to twice its expectation within twenty steps (PERF.md section 6, PRs 27
+    and 31).
     """
 
     def __init__(self, d_model, d_expert, num_experts, top_k, held=None,
-                 routed_scaling_factor=1.0, scoring="sigmoid", d_shared=None):
+                 routed_scaling_factor=1.0, scoring="sigmoid", d_shared=None,
+                 router_attr=None):
         super().__init__()
         first, count = held if held is not None else (0, num_experts)
         if not (0 <= first and count >= 1 and first + count <= num_experts):
@@ -300,7 +306,8 @@ class DroplessMoELayer(Layer):
         self.first, self.count = first, count
         self.routed_scaling_factor = routed_scaling_factor
         self.scoring = scoring
-        self.router = Linear(d_model, num_experts, bias_attr=False)
+        self.router = Linear(d_model, num_experts, bias_attr=False,
+                             weight_attr=router_attr)
         self.shared_expert = (GatedSiluFFN(d_model, d_shared)
                               if d_shared else None)
         self.experts = GroupedExperts(count, d_model, d_expert)

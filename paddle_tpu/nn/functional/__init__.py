@@ -28,5 +28,6 @@ from .pooling import (  # noqa: F401
     avg_pool2d, avg_pool3d, max_pool1d, max_pool2d, max_pool3d)
 from .vision import (  # noqa: F401
     affine_grid, channel_shuffle, grid_sample, pixel_shuffle, pixel_unshuffle)
-from .attention import scaled_dot_product_attention  # noqa: F401
+from .attention import (  # noqa: F401
+    block_diffusion_mask, scaled_dot_product_attention)
 from .rotary import rope_frequencies, rotary_embedding  # noqa: F401

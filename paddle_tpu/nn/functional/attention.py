@@ -13,8 +13,22 @@ import jax
 import jax.numpy as jnp
 
 
+def block_diffusion_mask(seq: int, block_length: int):
+    """The block-diffusion training mask (BD3-LM) as a boolean ``(seq,
+    seq)`` matrix, True where query ``i`` sees key ``j``. A row holds a
+    sequence twice, ``[noised ; clean]``, ``L = seq // 2`` positions each in
+    blocks of ``block_length``: a noised query sees the noised keys of its
+    own block and the clean keys of the blocks before it; a clean query the
+    clean keys of its own block and of those before it; nobody sees a
+    noised key of another block."""
+    from ...ops.pallas.flash_attention import block_diffusion_visible
+    index = jnp.arange(seq)
+    return block_diffusion_visible(index[:, None], index[None, :], seq // 2,
+                                   lambda p: p // block_length)
+
+
 def _xla_attention(q, k, v, mask=None, scale=None, causal=False, dropout_p=0.0,
-                   training=True, window=None):
+                   training=True, window=None, block_diffusion=None):
     # q: (B, S, H, D); k, v: (B, T, H_kv, D) with H % H_kv == 0
     d = q.shape[-1]
     scale = scale if scale is not None else d ** -0.5
@@ -34,6 +48,10 @@ def _xla_attention(q, k, v, mask=None, scale=None, causal=False, dropout_p=0.0,
             # key j is visible to query i iff 0 <= i - j < window
             cm = cm & ~jnp.tril(jnp.ones((s, t), dtype=bool), -int(window))
         logits = jnp.where(cm, logits, jnp.finfo(logits.dtype).min)
+    if block_diffusion is not None:
+        logits = jnp.where(
+            block_diffusion_mask(logits.shape[-1], block_diffusion), logits,
+            jnp.finfo(logits.dtype).min)
     if mask is not None:
         if mask.dtype == jnp.bool_:
             logits = jnp.where(mask, logits, jnp.finfo(logits.dtype).min)
@@ -53,13 +71,21 @@ def _xla_attention(q, k, v, mask=None, scale=None, causal=False, dropout_p=0.0,
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False, training=True,
-                                 kv_lens=None, name=None, window=None):
+                                 kv_lens=None, name=None, window=None,
+                                 block_diffusion=None):
     """query: (batch, seq, num_heads, head_dim); key/value: (batch, seq,
     kv_heads, head_dim), where ``kv_heads`` divides ``num_heads`` and query
     head ``i`` reads KV head ``i // (num_heads // kv_heads)``.
 
     window: optional causal window (needs ``is_causal``): query ``i`` sees
     key ``j`` iff ``0 <= i - j < window``.
+
+    block_diffusion: optional block length ``B``: the block-diffusion
+    training mask over rows ``[noised ; clean]`` of ``2L`` positions
+    (``block_diffusion_mask``), on the flash kernels and on the XLA path
+    alike. It is a mask of its own: with ``is_causal``, ``window``,
+    ``kv_lens``, ``attn_mask`` or dropout, with keys that are not the
+    queries' own positions, an odd ``seq`` or ``L % B != 0`` it raises.
 
     kv_lens: optional (batch,) valid key/value counts — the O(B) form of a
     trailing-padding key mask; keeps padded batches on the flash kernel
@@ -72,13 +98,39 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     # attn_path_ms_per_step through the device trace's tf_op.
     if window is not None and not is_causal:
         raise ValueError("window is a causal window: it needs is_causal")
+    if block_diffusion is not None:
+        _check_block_diffusion(query, key, block_diffusion, attn_mask,
+                               dropout_p if training else 0.0, is_causal,
+                               kv_lens, window)
     with jax.named_scope("sdpa"):
         return _sdpa(query, key, value, attn_mask, dropout_p, is_causal,
-                     training, kv_lens, window)
+                     training, kv_lens, window, block_diffusion)
+
+
+def _check_block_diffusion(query, key, block_length, attn_mask, dropout_p,
+                           is_causal, kv_lens, window):
+    """What the block-diffusion mask does not define is refused, not
+    computed as something else."""
+    seq = query.shape[1]
+    given = [name for name, on in (
+        ("is_causal", is_causal), ("window", window is not None),
+        ("kv_lens", kv_lens is not None), ("attn_mask", attn_mask is not None),
+        ("dropout", dropout_p > 0.0)) if on]
+    if given:
+        raise ValueError(f"block_diffusion is a mask of its own: it cannot "
+                         f"be combined with {', '.join(given)}")
+    if key.shape[1] != seq or seq % 2:
+        raise ValueError(
+            f"block_diffusion masks self-attention over [noised ; clean] "
+            f"rows, two equal halves: got {seq} queries and {key.shape[1]} "
+            f"keys")
+    if int(block_length) < 1 or (seq // 2) % int(block_length):
+        raise ValueError(f"a half of {seq // 2} positions is not a whole "
+                         f"number of blocks of {block_length}")
 
 
 def _sdpa(query, key, value, attn_mask, dropout_p, is_causal, training,
-          kv_lens, window):
+          kv_lens, window, block_diffusion=None):
     from ...ops.pallas.flash_attention import flash_attention, flash_supported
     # The gate at 512 positions has no run at 512 on record. What the
     # benchmark's cells measured (PERF.md sections 5 and 6, PRs 26, 28): at
@@ -101,7 +153,8 @@ def _sdpa(query, key, value, attn_mask, dropout_p, is_causal, training,
         with jax.named_scope("flash"):
             return flash_attention(query, key, value, causal=is_causal,
                                    kv_lens=kv_lens, dropout_rate=rate,
-                                   dropout_seed=seed, window=window)
+                                   dropout_seed=seed, window=window,
+                                   block_diffusion=block_diffusion)
     from ...ops.pallas.tuner import record_fallback
     record_fallback("flash_attention")
     if kv_lens is not None:
@@ -118,4 +171,5 @@ def _sdpa(query, key, value, attn_mask, dropout_p, is_causal, training,
     with jax.named_scope("xla"):
         return _xla_attention(query, key, value, mask=attn_mask,
                               causal=is_causal, dropout_p=dropout_p,
-                              training=training, window=window)
+                              training=training, window=window,
+                              block_diffusion=block_diffusion)

@@ -70,10 +70,12 @@ def _rotate_half_matrix(half: int, d: int) -> np.ndarray:
     return p
 
 
-def rotary_embedding(x, inv_freq, scale: float = 1.0):
+def rotary_embedding(x, inv_freq, scale: float = 1.0, positions=None):
     """Rotate the first ``2 * len(inv_freq)`` lanes of ``x`` (batch, seq,
-    heads, head_dim) by its positions ``arange(seq)``; the other lanes pass
-    through. Angles, cos and sin are float32; the result has ``x``'s dtype.
+    heads, head_dim) by its positions, ``arange(seq)`` or the ``(seq,)``
+    integers ``positions`` (a row that holds a sequence twice gives both
+    copies of token ``i`` position ``i``); the other lanes pass through.
+    Angles, cos and sin are float32; the result has ``x``'s dtype.
 
     ``x * C + (x @ P) * S`` over whole head vectors: ``C`` holds cos on the
     rotated lanes and 1 on the others, ``S`` sin and 0, and ``P`` is the
@@ -86,7 +88,9 @@ def rotary_embedding(x, inv_freq, scale: float = 1.0):
     ``rope_ms_per_step`` reads."""
     with jax.named_scope("rope"):
         half, d = len(inv_freq), x.shape[-1]
-        angles = jnp.arange(x.shape[1]).astype(jnp.float32)[:, None] \
+        if positions is None:
+            positions = jnp.arange(x.shape[1])
+        angles = jnp.asarray(positions).astype(jnp.float32)[:, None] \
             * jnp.asarray(inv_freq, jnp.float32)              # (seq, r/2)
         rest = angles.shape[:-1] + (d - 2 * half,)
         cos = jnp.concatenate(

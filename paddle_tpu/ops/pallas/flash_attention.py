@@ -65,6 +65,21 @@ triangle is one strip, the whole tile under the triangle's mask. The
 tiles of each kind a staged kernel walks are counted in
 ``flash_tiles_staged_total{kernel, kind}``.
 
+Block diffusion (ISSUE 31): ``block_diffusion=B`` is a third mask, over
+rows that hold a sequence twice, ``[noised ; clean]``, ``L`` positions each
+in blocks of ``B``: a noised query sees the noised keys of its own block and
+the clean keys of the blocks before it, a clean query the clean keys of its
+own block and of those before it, and no query a noised key of another
+block (BD3-LM's training mask). Of the ``(2L / block)^2`` tiles the mask
+leaves those on the noised half's diagonal, and the lower triangle, diagonal
+included, of the two halves' clean keys (80 of 256 at ``L`` = 4,096 in
+512-blocks). The grid's inner dimension walks exactly those: the tiles a
+lane block computes are a static list (``_DiffusionTiles``), handed to the
+kernels as scalar-prefetch tables that the index maps read, so a hidden tile
+costs no grid step and no DMA. The tiles strictly under the diagonal are
+dense; the three on a diagonal are cut into strips like a causal tile,
+under masks on ``position // B``.
+
 Trace names: each ``pallas_call`` carries ``name=`` (``flash_fwd``,
 ``flash_bwd_dq``, ``flash_bwd_dkv``). That names the kernel's op in a
 device trace and stages it under a ``jax.named_scope`` of the same string
@@ -97,6 +112,7 @@ with q: (batch, seq, heads, head_dim), k/v: (batch, seq, kv_heads, head_dim).
 """
 from __future__ import annotations
 
+import collections
 import functools
 import math
 
@@ -389,6 +405,10 @@ class _Tiles:
     the kernels) and has no kind. ``counts`` is the number of tiles of each
     kind one lane block's grid walks."""
 
+    # scalar-prefetch tables the grid is walked by: none, the grid's
+    # indices say where a step is (``walk_keys``, ``walk_queries``)
+    tables = ()
+
     def __init__(self, kernel, causal, masked_keys, block_q, block_k, nq, nk,
                  band, square):
         self.causal, self.masked_keys, self.band = causal, masked_keys, band
@@ -475,24 +495,238 @@ class _Tiles:
         col = col0 + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
         return row >= col if shape == "diagonal" else col > row
 
+    # where a grid step is: ``(hb, iq, ik, first, last, branches)``, the
+    # lane block of the queries, the tile, whether it is the first or the
+    # last the step's accumulators see, and ``[(predicate, shape)]``
+    def walk_keys(self, hb, i, step, steps, table):
+        """The forward and dq kernels: grid (lane blocks, q blocks, key
+        steps); under a window the steps walk the band's key blocks only."""
+        band = self.band
+        ik = step if band is None else band.k_first(i) + step
+        if band is not None:
+            run = ik <= band.k_last(i)
+        else:
+            run = (ik * self.bk < (i + 1) * self.bq) if self.causal else True
+        return (hb, i, ik, step == 0, step == steps - 1,
+                self.branches(i, ik, run))
+
+    def walk_queries(self, hb, j, step, steps, group, table):
+        """The dk/dv kernel: grid (KV lane blocks, key blocks, the group's
+        query lane blocks x the q blocks, all of them or a window's
+        band)."""
+        band, jq = self.band, step
+        if group != 1:
+            hb = hb * group + step // steps
+            jq = step % steps
+        iq = jq if band is None else band.q_first(j) + jq
+        if band is not None:
+            run = iq <= band.q_last(j)
+        else:
+            run = ((iq + 1) * self.bq > j * self.bk) if self.causal else True
+        return (hb, iq, j, step == 0, step == group * steps - 1,
+                self.branches(iq, j, run))
+
+
+def block_diffusion_visible(rows, cols, half, block):
+    """Which (query index, key index) pairs of a ``[noised ; clean]`` row of
+    ``2 * half`` positions meet, from index arrays that broadcast (numpy or
+    jax alike); ``block(p)`` is the diffusion block of position ``p``. A
+    noised query (index below ``half``): the noised keys of its own block,
+    the clean keys of the blocks before it. A clean query: the clean keys
+    of its own block and of those before it. No noised key otherwise."""
+    q_noised, k_noised = rows < half, cols < half
+    qb = block(rows - half * (1 - q_noised.astype(rows.dtype)))
+    kb = block(cols - half * (1 - k_noised.astype(cols.dtype)))
+    return ((q_noised & k_noised & (qb == kb))
+            | (q_noised & ~k_noised & (kb < qb))
+            | (~q_noised & ~k_noised & (kb <= qb)))
+
+
+ON_DIAGONAL, UNDER_DIAGONAL = "block_diagonal", "block_triangle"
+# a block triangle as a kernel's branch sees it: ``strict`` (a traced 0 or
+# 1) says whether a query's own block is hidden (noised x clean) or not
+_Under = collections.namedtuple("_Under", "strict")
+
+
+class _DiffusionTiles:
+    """``_Tiles`` for the block-diffusion mask: which tiles of the ``2L x
+    2L`` scores a lane block computes, in which order, and how.
+
+    With square blocks ``b``, ``L % b == 0``, ``b % B == 0`` and no padded
+    key (``exact``), ``n = L / b`` tiles a half a side, the mask leaves
+
+    - noised x noised: the ``n`` tiles on the diagonal, visible where
+      ``row // B == col // B`` (``block_diagonal``: strip ``r`` is the
+      sub-block ``(r, r)`` alone);
+    - noised x clean: the tiles under the diagonal, dense, and those on it,
+      visible where ``col // B < row // B``;
+    - clean x clean: the same with ``<=`` (both ``block_triangle``, cut like
+      a causal tile's triangle; ``strict`` tells them apart at run time, so
+      that one branch of the kernel serves both);
+    - clean x noised: nothing.
+
+    That is ``n + n (n + 1)`` tiles of ``4 n^2``: 80 of 256 at ``L`` = 4,096
+    in 512-blocks. Anything else (a ragged ``2L``, blocks that do not divide
+    ``L``) is every tile that shows a pair, whole, under
+    ``block_diffusion_visible``.
+
+    The kernels' grids walk the list and nothing else, q block by q block
+    with its key blocks in order (forward and dq) or key block by key block
+    and for each the group's query heads and for each the q blocks (dk/dv),
+    as int32 scalar-prefetch ``tables`` of one entry a grid step: the q block, the key block, ``how`` (bit 0: the
+    first tile of its accumulator, bit 1: the last, bit 2: ``strict``, the
+    rest: the index of the shape in ``shapes``) and for dk/dv the head of
+    the group. The index maps read the same tables, so a block that stays
+    is not fetched again and a tile that is not listed is never touched."""
+
+    def __init__(self, kernel, half, block_len, masked_keys, block_q, block_k,
+                 nq, nk, group):
+        self.half, self.block_len = half, block_len
+        self.bq, self.bk = block_q, block_k
+        b = block_q
+        self.exact = (not masked_keys and block_q == block_k
+                      and half % b == 0 and b % block_len == 0)
+        sub, (least, most) = b // SUB_BLOCKS, _SUB_ROWS[kernel]
+        self.sub = sub if (sub % LANES == 0 and sub % block_len == 0
+                           and sub >= least
+                           and (most is None or sub <= most)) else b
+        if self.exact:
+            self.shapes = (DENSE, ON_DIAGONAL, UNDER_DIAGONAL)
+        else:
+            self.shapes = (MASKED,)
+        tiles = []                      # (iq, ik, shape, strict)
+        for iq in range(nq):
+            for ik in range(nk):
+                found = self._tile(iq, ik)
+                if found is not None:
+                    tiles.append((iq, ik) + found)
+        self.counts = dict.fromkeys(TILE_KINDS, 0)
+        for _, _, shape, _ in tiles:
+            self.counts[self._kind(shape)] += 1
+        rows = [t + (0,) for t in tiles]                      # iq-major
+        cols = [t + (g,) for ik in range(nk) for g in range(group)
+                for t in tiles if t[1] == ik]
+        walk = rows if kernel != BWD_DKV else cols
+        # the accumulator a step adds to: the q block's, or the key block's
+        owner = [t[0] if kernel != BWD_DKV else t[1] for t in walk]
+        how = []
+        for i, (_, _, shape, strict, _) in enumerate(walk):
+            first = i == 0 or owner[i - 1] != owner[i]
+            last = i == len(walk) - 1 or owner[i + 1] != owner[i]
+            how.append(first | last << 1 | strict << 2
+                       | self.shapes.index(shape) << 3)
+        self.steps = len(walk)
+        fields = [[t[0] for t in walk], [t[1] for t in walk], how]
+        if kernel == BWD_DKV:
+            fields.append([t[4] for t in walk])
+        self.tables = tuple(np.asarray(f, np.int32) for f in fields)
+
+    def _block(self, p):
+        """The diffusion block of position ``p`` (a shift where the block
+        length is a power of two: an integer ``//`` costs Pallas' TPU
+        lowering a traced helper, see ``_Pack._slot``)."""
+        n = self.block_len
+        if isinstance(p, np.ndarray):
+            return p // n
+        if n & (n - 1) == 0:
+            return jax.lax.shift_right_logical(p, n.bit_length() - 1)
+        return jax.lax.div(p, jnp.int32(n))
+
+    def _tile(self, iq, ik):
+        """``(shape, strict)`` of tile ``(iq, ik)``, None where the mask
+        hides all of it."""
+        if self.exact:
+            n = self.half // self.bq
+            if iq < n and ik < n:
+                return (ON_DIAGONAL, 0) if ik == iq else None
+            if ik < n:
+                return None
+            r, c = iq % n, ik - n
+            if c > r:
+                return None
+            return (DENSE, 0) if c < r else (UNDER_DIAGONAL, int(iq < n))
+        rows = np.arange(iq * self.bq, (iq + 1) * self.bq)
+        cols = np.arange(ik * self.bk, (ik + 1) * self.bk)
+        cols = cols[cols < 2 * self.half]
+        seen = block_diffusion_visible(rows[:, None], cols[None, :],
+                                       self.half, self._block)
+        return (MASKED, 0) if seen.any() else None
+
+    def _kind(self, shape):
+        if shape in (ON_DIAGONAL, UNDER_DIAGONAL):
+            return TRIANGULAR if self.sub < self.bq else MASKED
+        return shape
+
+    def kind(self, iq, ik):
+        """The kind of tile ``(iq, ik)`` (Python ints), None if hidden."""
+        found = self._tile(iq, ik)
+        return None if found is None else self._kind(found[0])
+
+    def _walk(self, how):
+        """``(first, last, branches)`` of a step from its ``how``."""
+        code = jax.lax.shift_right_logical(how, 3)
+        under = _Under(jax.lax.shift_right_logical(how, 2) & 1)
+        return ((how & 1) == 1, (how & 2) == 2,
+                [(code == i, under if shape == UNDER_DIAGONAL else shape)
+                 for i, shape in enumerate(self.shapes)])
+
+    def walk_keys(self, hb, i, step, steps, table):
+        iq, ik, how = (ref[step] for ref in table)
+        return (hb, iq, ik) + self._walk(how)
+
+    def walk_queries(self, hb, j, step, steps, group, table):
+        iq, ik, how, head = (ref[step] for ref in table)
+        if group != 1:
+            hb = hb * group + head
+        return (hb, iq, ik) + self._walk(how)
+
+    def strips(self, shape, by):
+        b, sub = self.bq, self.sub
+        if shape in (DENSE, MASKED):
+            return [(0, self.bq, 0, self.bk)]
+        cut = [(i, sub) for i in range(0, b, sub)]
+        if shape == ON_DIAGONAL:
+            return [(i, n, i, n) for i, n in cut]
+        if by == "rows":                # a block triangle: as a causal one
+            return [(r0, n, 0, r0 + n) for r0, n in cut]
+        return [(c0, b - c0, c0, n) for c0, n in cut]
+
+    def keep(self, shape, strip, iq, ik, kv_len):
+        row0, rows, col0, cols = strip
+        if shape == DENSE:
+            return None
+        row = row0 + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+        col = col0 + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+        if shape == MASKED:
+            col = ik * self.bk + col
+            keep = block_diffusion_visible(iq * self.bq + row, col,
+                                           self.half, self._block)
+            return keep if kv_len is None else keep & (col < kv_len)
+        # a tile on a diagonal starts at a whole block of both axes
+        qb, kb = self._block(row), self._block(col)
+        if shape == ON_DIAGONAL:
+            return qb == kb
+        return kb <= qb - shape.strict
+
 
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 def _fwd_kernel(lens_ref, seed_ref,       # (blocks,) i32, (1,) i32 in SMEM
-                q_ref, k_ref, v_ref,      # (1,Bq,L), (1,Bk,L), (1,Bk,L)
-                o_ref, lse_ref,           # (1,Bq,L), (n,Bq,STAT_LANES)
-                m_scr, l_scr, acc_scr,    # (n,Bq,LANES) x 2, (Bq,L)
-                *, sm_scale, num_k_blocks, use_kv_mask, dropout_rate, pack,
-                tiles):
-    hb = pl.program_id(0)
-    iq = pl.program_id(1)
-    step = pl.program_id(2)
-    band, block_q = tiles.band, tiles.bq
-    # under a window the inner dimension walks the band's key blocks only
-    ik = step if band is None else band.k_first(iq) + step
+                *refs, sm_scale, num_k_blocks, use_kv_mask, dropout_rate,
+                pack, tiles):
+    # the tiles' tables (none but under block diffusion), then
+    # q_ref, k_ref, v_ref      (1,Bq,L), (1,Bk,L), (1,Bk,L)
+    # o_ref, lse_ref           (1,Bq,L), (n,Bq,STAT_LANES)
+    # m_scr, l_scr, acc_scr    (n,Bq,LANES) x 2, (Bq,L)
+    table, refs = refs[:len(tiles.tables)], refs[len(tiles.tables):]
+    q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
+    block_q = tiles.bq
+    hb, iq, ik, first, last, branches = tiles.walk_keys(
+        pl.program_id(0), pl.program_id(1), pl.program_id(2), num_k_blocks,
+        table)
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -543,14 +777,10 @@ def _fwd_kernel(lens_ref, seed_ref,       # (blocks,) i32, (1,) i32 in SMEM
 
         pack.each_head(head)
 
-    if band is not None:
-        run = ik <= band.k_last(iq)
-    else:
-        run = (ik * tiles.bk < (iq + 1) * block_q) if tiles.causal else True
-    for when, shape in tiles.branches(iq, ik, run):
+    for when, shape in branches:
         pl.when(when)(functools.partial(compute, shape))
 
-    @pl.when(step == num_k_blocks - 1)
+    @pl.when(last)
     def _finalize():
         def head(j, l_all):
             l = l_scr[j, :, :1]
@@ -566,35 +796,67 @@ def _fwd_kernel(lens_ref, seed_ref,       # (blocks,) i32, (1,) i32 in SMEM
         o_ref[0] = (acc_scr[:] / l_all).astype(o_ref.dtype)
 
 
-def _kv_index_maps(group, band):
-    """The K/V block index map of the two kernels whose grid is (q lane
-    blocks, q blocks, key steps). Query lane block ``b`` reads KV lane block
-    ``b // group``; under a window step ``j`` is key block ``k_first(i) +
-    j``, held at the band's last block once past it so that the skipped
-    steps fetch nothing new. Ungrouped and unwindowed it is the plain
-    ``(b, j, 0)``."""
+def _index_maps(group, band, tiles):
+    """The q and the K/V block index maps of the two kernels whose grid is
+    (q lane blocks, q blocks, key steps). Query lane block ``b`` reads KV
+    lane block ``b // group``; under a window step ``j`` is key block
+    ``k_first(i) + j``, held at the band's last block once past it so that
+    the skipped steps fetch nothing new. Ungrouped and unwindowed it is the
+    plain ``(b, j, 0)``. Where the grid walks a list of tiles (block
+    diffusion) step ``j``'s blocks are the tables' entries."""
     def head(b):
         return b if group == 1 else b // group
 
+    if tiles.tables:
+        return (lambda b, i, j, lens, seed, tq, tk, how: (b, tq[j], 0),
+                lambda b, i, j, lens, seed, tq, tk, how: (head(b), tk[j], 0))
+    q_map = lambda b, i, j: (b, i, 0)  # noqa: E731
     if band is None:
-        return lambda b, i, j: (head(b), j, 0)
-    return lambda b, i, j: (
+        return q_map, lambda b, i, j: (head(b), j, 0)
+    return q_map, lambda b, i, j: (
         head(b), jnp.minimum(band.k_first(i) + j, band.k_last(i)), 0)
 
 
 def _geometry(q, k, d, heads, block_q, block_k, window, causal,
-              use_kv_mask):
+              use_kv_mask, diffusion):
     """What the three calls share, from the operands' shapes; ``heads`` is
-    the pair (the caller's query heads, the stored ones)."""
+    the pair (the caller's query heads, the stored ones), ``diffusion``
+    None or the block-diffusion mask's ``(L, B)``."""
     blocks, sq, sk = q.shape[0], q.shape[1], k.shape[1]
     group = blocks // k.shape[0]
     nq = pl.cdiv(sq, block_q)
     nk = pl.cdiv(sk, block_k)
     band = None if window is None else _Band(window, block_q, block_k, nq, nk)
-    tiles = {kernel: _Tiles(kernel, causal, use_kv_mask, block_q, block_k,
-                            nq, nk, band, sq == sk)
-             for kernel in KERNEL_NAMES}
+    if diffusion is None:
+        tiles = {kernel: _Tiles(kernel, causal, use_kv_mask, block_q,
+                                block_k, nq, nk, band, sq == sk)
+                 for kernel in KERNEL_NAMES}
+    else:
+        tiles = {kernel: _DiffusionTiles(kernel, *diffusion, use_kv_mask,
+                                         block_q, block_k, nq, nk, group)
+                 for kernel in KERNEL_NAMES}
     return blocks, sq, group, _Pack(d, group, *heads), nq, nk, band, tiles
+
+
+def _call(kernel, name, tiles, grid, in_specs, out_specs, out_shape, scratch,
+          interpret, lens, seed, *operands):
+    """One of the three ``pallas_call``s. ``lens`` and ``seed`` are its
+    first two operands either way (the benchmark finds the kernels by
+    them): SMEM inputs, or, where the grid walks ``tiles.tables``, scalar
+    prefetch with the tables after them, which the index maps then take as
+    trailing arguments."""
+    if not tiles.tables:
+        smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+        return pl.pallas_call(
+            kernel, grid=grid, in_specs=[smem, smem] + in_specs,
+            out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch,
+            interpret=interpret, name=name)(lens, seed, *operands)
+    spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2 + len(tiles.tables), grid=grid,
+        in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch)
+    return pl.pallas_call(kernel, grid_spec=spec, out_shape=out_shape,
+                          interpret=interpret, name=name)(
+        lens, seed, *(jnp.asarray(t) for t in tiles.tables), *operands)
 
 
 def _count_tiles(kernel, tiles):
@@ -619,62 +881,59 @@ def _count_tiles(kernel, tiles):
 # gpt2-small.seq1024 from a warm compile cache, 36 call sites: staged a
 # layer 38-44 s with one head a block and 49-55 s with two, staged once
 # 32-35 s; PERF.md section 6, PR 28.)
-@functools.partial(jax.jit, static_argnums=tuple(range(5, 15)))
+@functools.partial(jax.jit, static_argnums=tuple(range(5, 16)))
 def _fwd(q, k, v, lens, seed, sm_scale, causal, block_q, block_k,
-         use_kv_mask, dropout_rate, interpret, window, d, heads):
+         use_kv_mask, dropout_rate, interpret, window, d, heads, diffusion):
     blocks, sq, group, pack, nq, nk, band, tiles = _geometry(
-        q, k, d, heads, block_q, block_k, window, causal, use_kv_mask)
+        q, k, d, heads, block_q, block_k, window, causal, use_kv_mask,
+        diffusion)
     lanes, n = pack.lanes, pack.n
     steps = nk if band is None else band.k_steps
-    kv_map = _kv_index_maps(group, band)
+    q_map, kv_map = _index_maps(group, band, tiles[FWD])
+    grid = (blocks, nq, steps)
+    if diffusion is not None:
+        grid = (blocks, 1, tiles[FWD].steps)
     _count_tiles(FWD, tiles[FWD])
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, num_k_blocks=steps,
         use_kv_mask=use_kv_mask, dropout_rate=dropout_rate, pack=pack,
         tiles=tiles[FWD])
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(blocks, nq, steps),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block_q, lanes), lambda b, i, j: (b, i, 0)),
+    out, lse = _call(
+        kernel, FWD, tiles[FWD], grid,
+        [
+            pl.BlockSpec((1, block_q, lanes), q_map),
             pl.BlockSpec((1, block_k, lanes), kv_map),
             pl.BlockSpec((1, block_k, lanes), kv_map),
         ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, lanes), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((n, block_q, STAT_LANES), lambda b, i, j: (b, i, 0)),
+        [
+            pl.BlockSpec((1, block_q, lanes), q_map),
+            pl.BlockSpec((n, block_q, STAT_LANES), q_map),
         ],
-        out_shape=[
+        [
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct((blocks * n, sq, STAT_LANES), jnp.float32),
         ],
-        scratch_shapes=[
+        [
             pltpu.VMEM((n, block_q, LANES), jnp.float32),
             pltpu.VMEM((n, block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, lanes), jnp.float32),
         ],
-        interpret=interpret,
-        name=FWD,
-    )(lens, seed, q, k, v)
+        interpret, lens, seed, q, k, v)
     return out, lse
 
 
 # ---------------------------------------------------------------------------
 # backward
 # ---------------------------------------------------------------------------
-def _bwd_dq_kernel(lens_ref, seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                   delta_ref, dq_ref, dq_scr,
-                   *, sm_scale, num_k_blocks, use_kv_mask, dropout_rate,
-                   pack, tiles):
-    hb = pl.program_id(0)
-    iq = pl.program_id(1)
-    step = pl.program_id(2)
-    band, block_q = tiles.band, tiles.bq
-    ik = step if band is None else band.k_first(iq) + step
+def _bwd_dq_kernel(lens_ref, seed_ref, *refs, sm_scale, num_k_blocks,
+                   use_kv_mask, dropout_rate, pack, tiles):
+    table, refs = refs[:len(tiles.tables)], refs[len(tiles.tables):]
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr = refs
+    hb, iq, ik, first, last, branches = tiles.walk_keys(
+        pl.program_id(0), pl.program_id(1), pl.program_id(2), num_k_blocks,
+        table)
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
@@ -708,36 +967,27 @@ def _bwd_dq_kernel(lens_ref, seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
         pack.each_head(head)
 
-    if band is not None:
-        run = ik <= band.k_last(iq)
-    else:
-        run = (ik * tiles.bk < (iq + 1) * block_q) if tiles.causal else True
-    for when, shape in tiles.branches(iq, ik, run):
+    for when, shape in branches:
         pl.when(when)(functools.partial(compute, shape))
 
-    @pl.when(step == num_k_blocks - 1)
+    @pl.when(last)
     def _finalize():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(lens_ref, seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                    delta_ref, dk_ref, dv_ref, dk_scr, dv_scr,
-                    *, sm_scale, num_q_blocks, use_kv_mask, dropout_rate,
-                    pack, tiles):
+def _bwd_dkv_kernel(lens_ref, seed_ref, *refs, sm_scale, num_q_blocks,
+                    use_kv_mask, dropout_rate, pack, tiles):
     # the grid's first dimension is the KV lane block; the inner one walks
     # the group's query lane blocks, and for each the q blocks (all of
     # them, or the band a window leaves): ``num_q_blocks`` steps each
-    group, band, block_q = pack.group, tiles.band, tiles.bq
-    hb = pl.program_id(0)
-    ik = pl.program_id(1)
-    step = pl.program_id(2)
-    jq = step
-    if group != 1:
-        hb = hb * group + step // num_q_blocks
-        jq = step % num_q_blocks
-    iq = jq if band is None else band.q_first(ik) + jq
+    table, refs = refs[:len(tiles.tables)], refs[len(tiles.tables):]
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+     dk_scr, dv_scr) = refs
+    hb, iq, ik, first, last, branches = tiles.walk_queries(
+        pl.program_id(0), pl.program_id(1), pl.program_id(2), num_q_blocks,
+        pack.group, table)
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
@@ -782,61 +1032,59 @@ def _bwd_dkv_kernel(lens_ref, seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
         pack.each_head(head)
 
-    if band is not None:
-        run = iq <= band.q_last(ik)
-    else:
-        run = ((iq + 1) * block_q > ik * tiles.bk) if tiles.causal else True
-    for when, shape in tiles.branches(iq, ik, run):
+    for when, shape in branches:
         pl.when(when)(functools.partial(compute, shape))
 
-    @pl.when(step == group * num_q_blocks - 1)
+    @pl.when(last)
     def _finalize():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
 def _bwd(sm_scale, causal, block_q, block_k, use_kv_mask, dropout_rate,
-         interpret, window, d, heads, res, do):
+         interpret, window, d, heads, diffusion, res, do):
     lens, seed = res[3], res[4]
     dq, dk, dv = _bwd_calls(sm_scale, causal, block_q, block_k, use_kv_mask,
-                            dropout_rate, interpret, window, d, heads, res, do)
+                            dropout_rate, interpret, window, d, heads,
+                            diffusion, res, do)
     # int-array inputs (lens, seed) take float0 cotangents
     return (dq, dk, dv, np.zeros(lens.shape, jax.dtypes.float0),
             np.zeros(seed.shape, jax.dtypes.float0))
 
 
-@functools.partial(jax.jit, static_argnums=tuple(range(10)))
+@functools.partial(jax.jit, static_argnums=tuple(range(11)))
 def _bwd_calls(sm_scale, causal, block_q, block_k, use_kv_mask, dropout_rate,
-               interpret, window, d, heads, res, do):
+               interpret, window, d, heads, diffusion, res, do):
     q, k, v, lens, seed, out, lse = res
     blocks, _, group, pack, nq, nk, band, tiles = _geometry(
-        q, k, d, heads, block_q, block_k, window, causal, use_kv_mask)
+        q, k, d, heads, block_q, block_k, window, causal, use_kv_mask,
+        diffusion)
     lanes, n = pack.lanes, pack.n
     _count_tiles(BWD_DQ, tiles[BWD_DQ])
     _count_tiles(BWD_DKV, tiles[BWD_DKV])
     k_steps = nk if band is None else band.k_steps
     q_steps = nq if band is None else band.q_steps
-    kv_map = _kv_index_maps(group, band)
+    q_map_dq, kv_map = _index_maps(group, band, tiles[BWD_DQ])
+    dq_grid, dkv_grid = (blocks, nq, k_steps), (k.shape[0], nk,
+                                                group * q_steps)
+    if diffusion is not None:
+        dq_grid = (blocks, 1, tiles[BWD_DQ].steps)
+        dkv_grid = (k.shape[0], 1, tiles[BWD_DKV].steps)
     # delta in the statistics' format, (batch x heads, seq, STAT_LANES)
     delta = jnp.broadcast_to(pack.head_sums(
         do.astype(jnp.float32) * out.astype(jnp.float32))[..., None],
         lse.shape)
 
-    lens_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
-    seed_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
-    q_spec = pl.BlockSpec((1, block_q, lanes), lambda b, i, j: (b, i, 0))
-    stat_spec = pl.BlockSpec((n, block_q, STAT_LANES),
-                             lambda b, i, j: (b, i, 0))
+    q_spec = pl.BlockSpec((1, block_q, lanes), q_map_dq)
+    stat_spec = pl.BlockSpec((n, block_q, STAT_LANES), q_map_dq)
     common = dict(sm_scale=sm_scale, use_kv_mask=use_kv_mask,
                   dropout_rate=dropout_rate, pack=pack)
 
-    dq = pl.pallas_call(
+    dq = _call(
         functools.partial(_bwd_dq_kernel, num_k_blocks=k_steps,
                           tiles=tiles[BWD_DQ], **common),
-        grid=(blocks, nq, k_steps),
-        in_specs=[
-            lens_spec,
-            seed_spec,
+        BWD_DQ, tiles[BWD_DQ], dq_grid,
+        [
             q_spec,
             pl.BlockSpec((1, block_k, lanes), kv_map),
             pl.BlockSpec((1, block_k, lanes), kv_map),
@@ -844,12 +1092,9 @@ def _bwd_calls(sm_scale, causal, block_q, block_k, use_kv_mask, dropout_rate,
             stat_spec,
             stat_spec,
         ],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, lanes), jnp.float32)],
-        interpret=interpret,
-        name=BWD_DQ,
-    )(lens, seed, q, k, v, do, lse, delta)
+        q_spec, jax.ShapeDtypeStruct(q.shape, q.dtype),
+        [pltpu.VMEM((block_q, lanes), jnp.float32)],
+        interpret, lens, seed, q, k, v, do, lse, delta)
 
     # the dk/dv kernel's view of what lives per query lane block (q, do,
     # lse, delta): KV lane block ``b``, key block ``j``, inner step ``t``
@@ -859,17 +1104,23 @@ def _bwd_calls(sm_scale, causal, block_q, block_k, use_kv_mask, dropout_rate,
         if band is not None:
             jq = jnp.minimum(band.q_first(j) + jq, band.q_last(j))
         return (block, jq, 0)
+    kv_own = lambda b, j, i: (b, j, 0)  # noqa: E731
+    if diffusion is not None:
+        # step ``t`` of the walk: the tables' q block, key block and head
+        def q_map(b, j, t, lens, seed, tq, tk, how, head):
+            return (b if group == 1 else b * group + head[t], tq[t], 0)
+
+        def kv_own(b, j, t, lens, seed, tq, tk, how, head):
+            return (b, tk[t], 0)
     q_spec_kv = pl.BlockSpec((1, block_q, lanes), q_map)
     stat_spec_kv = pl.BlockSpec((n, block_q, STAT_LANES), q_map)
-    kv_spec = pl.BlockSpec((1, block_k, lanes), lambda b, j, i: (b, j, 0))
+    kv_spec = pl.BlockSpec((1, block_k, lanes), kv_own)
 
-    dk, dv = pl.pallas_call(
+    dk, dv = _call(
         functools.partial(_bwd_dkv_kernel, num_q_blocks=q_steps,
                           tiles=tiles[BWD_DKV], **common),
-        grid=(k.shape[0], nk, group * q_steps),
-        in_specs=[
-            lens_spec,
-            seed_spec,
+        BWD_DKV, tiles[BWD_DKV], dkv_grid,
+        [
             q_spec_kv,
             kv_spec,
             kv_spec,
@@ -877,18 +1128,16 @@ def _bwd_calls(sm_scale, causal, block_q, block_k, use_kv_mask, dropout_rate,
             stat_spec_kv,
             stat_spec_kv,
         ],
-        out_specs=[kv_spec, kv_spec],
-        out_shape=[
+        [kv_spec, kv_spec],
+        [
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
-        scratch_shapes=[
+        [
             pltpu.VMEM((block_k, lanes), jnp.float32),
             pltpu.VMEM((block_k, lanes), jnp.float32),
         ],
-        interpret=interpret,
-        name=BWD_DKV,
-    )(lens, seed, q, k, v, do, lse, delta)
+        interpret, lens, seed, q, k, v, do, lse, delta)
     return dq, dk, dv
 
 
@@ -896,15 +1145,16 @@ def _bwd_calls(sm_scale, causal, block_q, block_k, use_kv_mask, dropout_rate,
 # public API
 # ---------------------------------------------------------------------------
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=tuple(range(5, 15)))
+                   nondiff_argnums=tuple(range(5, 16)))
 def _flash(q, k, v, lens, seed, sm_scale, causal, block_q, block_k,
-           use_kv_mask, dropout_rate, interpret, window, d, heads):
+           use_kv_mask, dropout_rate, interpret, window, d, heads, diffusion):
     """q ``(batch x lane blocks, seq, lanes)``, k and v likewise over the
     KV heads' lane blocks (``_Pack``). ``d`` is the head width as stored,
     ``heads`` the pair (the caller's count of query heads, the stored one):
     the dropout hash is fed ``batch * heads + head`` in the caller's."""
     out, _ = _fwd(q, k, v, lens, seed, sm_scale, causal, block_q, block_k,
-                  use_kv_mask, dropout_rate, interpret, window, d, heads)
+                  use_kv_mask, dropout_rate, interpret, window, d, heads,
+                  diffusion)
     return out
 
 
@@ -919,13 +1169,16 @@ _flash.defvjp(_flash_fwd_rule, _bwd)
 def dims_of_call(eqn):
     """``(head_dim, seq_q, seq_k)`` of one of the three kernels' traced
     ``pallas_call`` equations, as stored: the one place outside the calls
-    above that knows their operand format. q and k are operands 2 and 3,
-    ``(batch x lane blocks, seq, lanes)``; a lane block holds as many heads
-    as the statistics, a row a head (``lse``: the forward's second result,
-    operand 6 of the other two), have rows for each of q's."""
-    q, k = eqn.invars[2].aval, eqn.invars[3].aval
-    stats = (eqn.outvars[1] if eqn.params["name"] == FWD
-             else eqn.invars[6]).aval
+    above that knows their operand format. q and k are the first two
+    three-dimensional operands, ``(batch x lane blocks, seq, lanes)``; a
+    lane block holds as many heads as the statistics, a row a head (``lse``:
+    the forward's second result, the fifth such operand of the other two),
+    have rows for each of q's."""
+    # after lens, seed and, under block diffusion, the tiles' tables: all
+    # one-dimensional
+    tensors = [v.aval for v in eqn.invars if len(v.aval.shape) == 3]
+    q, k = tensors[0], tensors[1]
+    stats = eqn.outvars[1].aval if eqn.params["name"] == FWD else tensors[4]
     return q.shape[2] // (stats.shape[0] // q.shape[0]), q.shape[1], k.shape[1]
 
 
@@ -961,7 +1214,7 @@ def _to_lane_blocks(x, seq, heads, d):
 def flash_attention(q, k, v, causal=False, sm_scale=None, kv_lens=None,
                     dropout_rate=0.0, dropout_seed=None,
                     block_q=None, block_k=None, interpret=False,
-                    window=None):
+                    window=None, block_diffusion=None):
     """q: (batch, seq, num_heads, head_dim), k/v: (batch, seq, kv_heads,
     head_dim) with ``num_heads % kv_heads == 0`` → output shaped like q.
     Query head ``i`` attends over KV head ``i // (num_heads // kv_heads)``.
@@ -989,6 +1242,12 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, kv_lens=None,
     whole under ``_visible``. The values are the same: only exact zeros
     leave the sums, and dropout draws the same bits.
 
+    block_diffusion: optional int ``B``, the block-diffusion training mask
+    over rows ``[noised ; clean]`` of ``2L`` positions (``seq = 2L``, ``L %
+    B == 0``; ``block_diffusion_visible``): self-attention only, and not
+    with ``causal``, ``window``, ``kv_lens`` or dropout. The grid walks the
+    tiles the mask leaves and no other (``_DiffusionTiles``).
+
     kv_lens: optional (batch,) int32 — per-row count of VALID key/value
     positions (a trailing-padding key mask, the (B,1,1,T) boolean
     ``attn_mask`` of padded batches in O(B) form). dropout_rate/seed:
@@ -1014,6 +1273,19 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, kv_lens=None,
                              f"window >= 1, got {window}")
         if window >= sk:
             window = None               # it cuts nothing: plain causal
+    diffusion = None
+    if block_diffusion is not None:
+        diffusion = (sq // 2, int(block_diffusion))
+        if (causal or window is not None or kv_lens is not None
+                or dropout_rate > 0.0 or sq != sk or sq % 2
+                or diffusion[1] < 1 or diffusion[0] % diffusion[1]):
+            raise ValueError(
+                "block_diffusion=B masks self-attention over [noised ; "
+                "clean] rows of 2L positions, L a multiple of B, and "
+                "nothing else: no causal, window, kv_lens or dropout "
+                f"(sq={sq}, sk={sk}, B={block_diffusion}, causal={causal}, "
+                f"window={window}, kv_lens given={kv_lens is not None}, "
+                f"dropout_rate={dropout_rate})")
     # the kernels multiply in their operands' dtype: one dtype, q's
     k, v = k.astype(q.dtype), v.astype(q.dtype)
     if block_q is None or block_k is None:
@@ -1077,6 +1349,7 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, kv_lens=None,
 
     out = _flash(qp, kp, vp, lens_blocks, seed_arr, float(sm_scale),
                  bool(causal), int(block_q), int(block_k), bool(use_kv_mask),
-                 float(dropout_rate), bool(interpret), window, d_st, (h, h_st))
+                 float(dropout_rate), bool(interpret), window, d_st, (h, h_st),
+                 diffusion)
     out = jnp.swapaxes(jnp.reshape(out, (b, -1, sq_pad, out.shape[-1])), 1, 2)
     return jnp.reshape(out, (b, sq_pad, h_st, d_st))[:, :sq, :h, :d]

@@ -305,7 +305,8 @@ def entry_for_traced_call(kernel_name: str, eqn) -> \
     avals = [getattr(v, "aval", None) for v in eqn.invars]
     if kernel_name in flash_kernels:
         dims = flash_dims(*dims_of_call(eqn))
-        dtype = avals[2].dtype
+        # q: the first operand after lens, seed and any table of tiles
+        dtype = next(a.dtype for a in avals if len(a.shape) == 3)
         for dev in (device_kind(), GENERIC_DEVICE):
             key = make_key("flash_attention", dev, dtype, dims)
             entry = db.lookup(key)

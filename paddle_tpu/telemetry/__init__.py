@@ -69,6 +69,10 @@ moe_held_assignments_total     counter    (token, expert) assignments on
 moe_max_load_over_mean         gauge      fullest held expert's
                                           assignments over the mean per
                                           held expert, last call
+block_diffusion_masked_share   gauge      text.models MixedDecoderFor
+                                          BlockDiffusion.publish_noise:
+                                          share of a step's clean tokens
+                                          the noise masked, last call
 retries_total                  counter    resilience.retry {site=...}
 retry_exhausted_total          counter    resilience.retry {site=...}
 retry_bytes_abandoned_total    counter    resilience.retry byte budget

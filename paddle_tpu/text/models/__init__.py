@@ -9,5 +9,5 @@ from .bert import (  # noqa: F401
 from .transformer import (  # noqa: F401
     InferTransformerModel, TransformerModel, position_encoding_init)
 from .mixed_decoder import (  # noqa: F401
-    GroupedQueryAttention, MixedDecoderBlock, MixedDecoderForPretraining,
-    MixedDecoderModel)
+    GroupedQueryAttention, MixedDecoderBlock, MixedDecoderForBlockDiffusion,
+    MixedDecoderForPretraining, MixedDecoderModel)

@@ -261,9 +261,11 @@ F_NONDIFF = {"one_hot", "sequence_mask", "gather_tree",
                                      # test_nn_extras.py)
 F_STOCHASTIC = {"dropout", "dropout2d", "dropout3d", "alpha_dropout",
                 "rrelu", "gumbel_softmax"}
-F_UTILITY = {"rope_frequencies"}  # host-side table (numpy) from a layer's
-                                  # RoPE parameters; hand values in
-                                  # test_mixed_decoder.py
+F_UTILITY = {"rope_frequencies",     # host-side table (numpy) from a layer's
+                                     # RoPE parameters; hand values in
+                                     # test_mixed_decoder.py
+             "block_diffusion_mask"}  # boolean table from two sizes; the four
+                                     # rules in test_block_diffusion.py
 
 F_CONFIGS = {
     "adaptive_avg_pool1d": lambda: (lambda x: F.adaptive_avg_pool1d(x, 2),

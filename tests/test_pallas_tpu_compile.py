@@ -166,6 +166,57 @@ def test_flash_grouped_heads_and_window_at_the_mixed_decoder_shapes(
         "flash_bwd_dkv": whole if window else cut}
 
 
+@pytest.mark.parametrize("block, non_power", [(512, False), (1024, False),
+                                              (384, True)],
+                         ids=["512", "1024", "384_block_length_12"])
+def test_flash_block_diffusion_at_the_sdar_cells_shape(v5e, block, non_power):
+    """sdar-30b-a3b-chat.seq4096's attention, [noised ; clean] rows of 8,192
+    positions at head width 128 with 8 query heads a KV head under the
+    block-diffusion mask: the three kernels lower and fit, their grids walk
+    the tables' 80 (24 in 1,024-blocks) tiles and nothing else, and their
+    first two operands are still ``lens`` and ``seed``, by which the
+    benchmark's ``flash_attn_ms_per_step`` finds them. A block length that
+    is no power of two divides by ``lax.div`` in the mask."""
+    import re
+
+    from paddle_tpu.analysis.walker import walk
+    from paddle_tpu.ops.pallas.flash_attention import (KERNEL_NAMES,
+                                                        flash_attention)
+
+    length = 12 if non_power else 4
+
+    def attend(q, k, v):
+        return flash_attention(q, k, v, block_diffusion=length,
+                               block_q=block, block_k=block)
+
+    def f(q, k, v):
+        return jax.grad(lambda q, k, v: jnp.sum(
+            attend(q, k, v).astype(jnp.float32) ** 2),
+            argnums=(0, 1, 2))(q, k, v)
+
+    seq = 6144 if non_power else 8192
+    q, kv = ((1, seq, 8, 128), jnp.bfloat16), ((1, seq, 1, 128), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(f)(*(jax.ShapeDtypeStruct(*a) for a in (q, kv, kv)))
+    grids = {site.eqn.params["name"]: site.eqn.params["grid_mapping"].grid
+             for site in walk(jaxpr)
+             if site.eqn.primitive.name == "pallas_call"}
+    n = seq // 2 // block
+    tiles = n + n * (n + 1)
+    assert (seq, tiles) in ((8192, 80), (8192, 24), (6144, 80))
+    assert grids == {"flash_fwd": (8, 1, tiles), "flash_bwd_dq": (8, 1, tiles),
+                     "flash_bwd_dkv": (1, 1, 8 * tiles)}
+    text = _compile(f, v5e, q, kv, kv)
+    pattern = re.compile(
+        r"operand_layout_constraints=\{s32\[8\]\{0\}, s32\[1\]\{0\}, "
+        r"s32\[(\d+)\]\{0\}")
+    for kernel in KERNEL_NAMES:
+        calls = [line for line in text.splitlines()
+                 if "tpu_custom_call" in line and f"%{kernel}" in line]
+        assert calls, kernel
+        steps = int(pattern.search(calls[0]).group(1))
+        assert steps == grids[kernel][2]
+
+
 @pytest.mark.parametrize("heads, kv_heads, kw", [
     (8, 2, {}), (3, 1, {"dropout_rate": 0.1, "dropout_seed": 5})],
     ids=["8_over_2", "3_over_1_dropout"])
