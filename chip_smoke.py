@@ -23,8 +23,10 @@ Phases (each through the entry points a user calls, weights from a seed):
   (``flash_tiles_staged_total``).
 - ``kernels`` — each Pallas kernel compiled (``interpret=False``) against
   its reference: flash fwd/bwd (the geometries whose tiles are computed by
-  sub-blocks among them, with the counter's kinds), fused LM-head CE
-  fwd/bwd at the bench
+  sub-blocks among them, with the counter's kinds), QK norm with RoPE
+  fwd/bwd at the two mixed-decoder cells' shapes against ``F.rms_norm``
+  and the XLA formula (``rope_calls_staged_total`` says the entry point
+  took the kernels), fused LM-head CE fwd/bwd at the bench
   shape against the chunked scan, paged decode and Tq=5 verify at
   h12/d64/page 16 bf16 against the XLA gather.
 - ``serve``  — ``DecodeServer`` over ``PagedKVCache`` with
@@ -203,6 +205,15 @@ def _flash_tiles(registry):
             for kernel in KERNEL_NAMES} if staged else {}
 
 
+def _rope_calls(registry):
+    """``rope_calls_staged_total`` as ``{path: {norm: calls}}``: which path
+    the staged calls of ``F.rotary_embedding`` took."""
+    staged = registry.get("rope_calls_staged_total")
+    return {path: {f"norm={norm}": int(staged.value(path=path, norm=norm))
+                   for norm in (0, 1)}
+            for path in ("pallas", "xla")} if staged else {}
+
+
 def _config_origins(run, entries):
     """Where each kernel config used here resolves from. A tuning-DB
     file outside the checkout would make the run depend on what an
@@ -322,6 +333,8 @@ def phase_kernels(run: Run):
     with telemetry.scope(profile=False) as tel:
         checks = ns.check_flash_tile_kinds(run.interpret)
         flash_tiles = _flash_tiles(tel.registry)
+        checks += ns.check_rope(run.interpret)
+        rope_calls = _rope_calls(tel.registry)
     checks += (ns.check_flash_attention(run.interpret)
                + ns.check_fused_ce(run.interpret, **ce)
                + ns.check_paged_attention(run.interpret, **paged))
@@ -337,9 +350,16 @@ def phase_kernels(run: Run):
     origins = _config_origins(run, entries)
     run.say("kernels", event="result", n_checks=len(checks),
             interpret=run.interpret, config_origins=origins,
-            flash_tiles_staged=flash_tiles)
+            flash_tiles_staged=flash_tiles, rope_calls_staged=rope_calls)
     bad = [c["check"] for c in checks if not c["ok"]]
     check(not bad, f"kernel checks out of tolerance: {bad}")
+    # on the chip check_rope goes through F.rotary_embedding: two cases
+    # without a norm weight and one with, each staged once, all by the
+    # kernels (rehearsed, it calls the interpreted kernels itself)
+    want = {} if run.rehearsal else {"pallas": {"norm=0": 2, "norm=1": 1},
+                                     "xla": {"norm=0": 0, "norm=1": 0}}
+    check(rope_calls == want, f"rotary_embedding staged as {rope_calls}, "
+                              f"expected {want}")
     # the three geometries of check_flash_tile_kinds, (dense, triangular,
     # masked): one 1024-row tile; two of them on the diagonal and one dense;
     # 4 tiles of 512 rows on the diagonal and 3 band edges. A triangle is

@@ -70,37 +70,89 @@ def _rotate_half_matrix(half: int, d: int) -> np.ndarray:
     return p
 
 
-def rotary_embedding(x, inv_freq, scale: float = 1.0, positions=None):
+def _rotate_xla(x, cos, sin, half: int):
+    """``x * C + (x @ P) * S`` over the heads of ``x`` (batch, seq, heads,
+    head_dim), the product exact (one +-1 a column) into float32."""
+    rotated = jnp.matmul(
+        x, jnp.asarray(_rotate_half_matrix(half, x.shape[-1]), x.dtype),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    out = x.astype(jnp.float32) * cos[..., None, :] \
+        + rotated * sin[..., None, :]                         # over heads
+    return out.astype(x.dtype)
+
+
+def rope_tables(inv_freq, scale, positions, seq: int, d: int):
+    """``(C, S)`` float32 ``(seq, d)``: cos of a position's angles times
+    ``scale`` on the rotated lanes (lane ``i`` and ``i + r/2`` share pair
+    ``i``'s angle) and 1 past them, sin and 0. ``positions`` ``(seq,)``
+    integers, or None for ``arange(seq)``."""
+    half = len(inv_freq)
+    if positions is None:
+        positions = jnp.arange(seq)
+    angles = jnp.asarray(positions).astype(jnp.float32)[:, None] \
+        * jnp.asarray(inv_freq, jnp.float32)              # (seq, r/2)
+    rest = angles.shape[:-1] + (d - 2 * half,)
+    cos = jnp.concatenate(
+        [jnp.cos(angles) * scale] * 2 + [jnp.ones(rest)], axis=-1)
+    sin = jnp.concatenate(
+        [jnp.sin(angles) * scale] * 2 + [jnp.zeros(rest)], axis=-1)
+    return cos, sin
+
+
+def _count_staged(path: str, norm: bool):
+    """``rope_calls_staged_total{path, norm}``: one call of
+    ``rotary_embedding`` being staged, by the path its input took."""
+    from ... import telemetry
+    if telemetry.enabled():
+        telemetry.counter(
+            "rope_calls_staged_total",
+            "Staged calls of rotary_embedding, by the path taken and "
+            "whether the QK norm is folded in").inc(
+                1, path=path, norm=int(norm))
+
+
+def rotary_embedding(x, inv_freq, scale: float = 1.0, positions=None,
+                     norm_weight=None, epsilon: float = 1e-6):
     """Rotate the first ``2 * len(inv_freq)`` lanes of ``x`` (batch, seq,
     heads, head_dim) by its positions, ``arange(seq)`` or the ``(seq,)``
     integers ``positions`` (a row that holds a sequence twice gives both
     copies of token ``i`` position ``i``); the other lanes pass through.
-    Angles, cos and sin are float32; the result has ``x``'s dtype.
+    Angles, cos and sin are float32; the result has ``x``'s dtype. With
+    ``norm_weight`` ``(head_dim,)``, ``x`` is first RMS-normalised over the
+    head width with ``epsilon`` and scaled by it (``F.rms_norm``): the
+    per-head prologue of q and k in one call.
 
-    ``x * C + (x @ P) * S`` over whole head vectors: ``C`` holds cos on the
-    rotated lanes and 1 on the others, ``S`` sin and 0, and ``P`` is the
-    rotate-half as a signed permutation matrix. The product is exact (one
-    +-1 a column) and costs the MXU next to nothing, where slicing a
-    128-lane vector into halves and joining them again took 89 ms of a 641
-    ms step of ``laguna-xs2.seq4096`` (PERF.md section 6, PR 27).
+    ``y * C + rotate_half(y) * S`` over whole head vectors: ``C`` holds cos
+    on the rotated lanes and 1 on the others, ``S`` sin and 0. Which path
+    computes it is read from the input. On a TPU, with a head of whole
+    128-lane blocks and a sequence a row tile divides, norm and rotation
+    are one Pallas pass forward and one backward
+    (``ops/pallas/rotary.py``): the rotate-half is a lane roll in
+    registers, the tensor is read once and written once. Everywhere else
+    (the CPU, narrow heads, a decode step) XLA computes ``F.rms_norm`` and
+    then ``y * C + (y @ P) * S`` with ``P`` the rotate-half as a signed
+    permutation matrix, exact at ``Precision.HIGHEST``. That product goes
+    through HBM in float32: 35.81 ms of a 541.88 ms step of
+    ``laguna-xs2.seq4096`` at 2.7x the tensor's bytes (ledger, PR 31),
+    which is why the TPU no longer takes it; slicing a 128-lane vector
+    into halves and joining them again took 89 ms there (PERF.md section
+    6, PR 27).
 
     Staged under the scope ``rope``, which the benchmark's
-    ``rope_ms_per_step`` reads."""
+    ``rope_ms_per_step`` reads; ``rope_calls_staged_total{path, norm}``
+    counts the staged calls by path."""
+    from ...ops.pallas import rotary as kernel
+    from .norm import _unwrap, rms_norm
+    norm_weight = _unwrap(norm_weight)
     with jax.named_scope("rope"):
         half, d = len(inv_freq), x.shape[-1]
-        if positions is None:
-            positions = jnp.arange(x.shape[1])
-        angles = jnp.asarray(positions).astype(jnp.float32)[:, None] \
-            * jnp.asarray(inv_freq, jnp.float32)              # (seq, r/2)
-        rest = angles.shape[:-1] + (d - 2 * half,)
-        cos = jnp.concatenate(
-            [jnp.cos(angles) * scale] * 2 + [jnp.ones(rest)], axis=-1)
-        sin = jnp.concatenate(
-            [jnp.sin(angles) * scale] * 2 + [jnp.zeros(rest)], axis=-1)
-        rotated = jnp.matmul(
-            x, jnp.asarray(_rotate_half_matrix(half, d), x.dtype),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)
-        out = x.astype(jnp.float32) * cos[..., None, :] \
-            + rotated * sin[..., None, :]                     # over heads
-        return out.astype(x.dtype)
+        cos, sin = rope_tables(inv_freq, scale, positions, x.shape[1], d)
+        pallas = jax.default_backend() == "tpu" and x.ndim == 4 \
+            and kernel.supported(x.shape, x.dtype, half)
+        _count_staged("pallas" if pallas else "xla", norm_weight is not None)
+        if pallas:
+            return kernel.rotary(x, cos, sin, half, norm_weight, epsilon)
+        if norm_weight is not None:
+            x = rms_norm(x, norm_weight, epsilon)
+        return _rotate_xla(x, cos, sin, half)

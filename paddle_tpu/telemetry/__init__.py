@@ -61,6 +61,10 @@ flash_tiles_staged_total       counter    ops.pallas.flash_attention, where
                                           {kernel=flash_fwd|flash_bwd_dq|
                                           flash_bwd_dkv, kind=dense|
                                           triangular|masked}
+rope_calls_staged_total        counter    nn.functional.rotary_embedding,
+                                          where a call is staged: the path
+                                          its input took {path=pallas|xla,
+                                          norm=0|1 (the QK norm folded in)}
 moe_tokens_routed_total        counter    incubate.moe DroplessMoELayer.
                                           publish_routing: tokens routed
 moe_held_assignments_total     counter    (token, expert) assignments on

@@ -43,8 +43,12 @@ class GroupedQueryAttention(Layer):
     embeddings on q and k, causal with an optional window, and (``gated``)
     a per-head ``sigmoid(x W_g)`` on the heads' outputs before ``o_proj``.
     ``qk_norm_epsilon`` (off by default) puts an RMSNorm over the head
-    width, one learned vector for q and one for k, before the rotation:
-    the scope ``qk_norm``."""
+    width, one learned vector for q and one for k (``q_norm.weight``,
+    ``k_norm.weight``), before the rotation. ``F.rotary_embedding`` takes
+    the weight and computes both, on a TPU as one pass over the tensor, so
+    the scope ``qk_norm`` holds the whole per-head prologue of q and k,
+    norm and rotation (``.../qk_norm/rope/...``); without a norm the
+    rotation is under ``rope`` alone."""
 
     def __init__(self, hidden_size, num_heads, kv_heads, head_dim, rope,
                  window=None, gated=False, qk_norm_epsilon=None):
@@ -72,6 +76,13 @@ class GroupedQueryAttention(Layer):
             self.q_norm = nn.RMSNorm(head_dim, qk_norm_epsilon)
             self.k_norm = nn.RMSNorm(head_dim, qk_norm_epsilon)
 
+    def _rope(self, x, positions, norm=None):
+        if norm is None:
+            return F.rotary_embedding(x, self.inv_freq, self.rope_scale,
+                                      positions)
+        return F.rotary_embedding(x, self.inv_freq, self.rope_scale,
+                                  positions, norm.weight, norm.epsilon)
+
     def forward(self, x, positions=None, block_diffusion=None):
         """``positions`` ``(seq,)``: what the rotation turns by, ``arange``
         by default. ``block_diffusion``: the block length of the
@@ -82,11 +93,12 @@ class GroupedQueryAttention(Layer):
         q = jnp.reshape(self.q_proj(x), (b, s, self.num_heads, d))
         k = jnp.reshape(self.k_proj(x), (b, s, self.kv_heads, d))
         v = jnp.reshape(self.v_proj(x), (b, s, self.kv_heads, d))
-        if self.q_norm is not None:
+        if self.q_norm is None:
+            q, k = self._rope(q, positions), self._rope(k, positions)
+        else:
             with jax.named_scope("qk_norm"):
-                q, k = self.q_norm(q), self.k_norm(k)
-        q = F.rotary_embedding(q, self.inv_freq, self.rope_scale, positions)
-        k = F.rotary_embedding(k, self.inv_freq, self.rope_scale, positions)
+                q = self._rope(q, positions, self.q_norm)
+                k = self._rope(k, positions, self.k_norm)
         out = F.scaled_dot_product_attention(
             q, k, v, is_causal=block_diffusion is None, window=self.window,
             training=self.training, block_diffusion=block_diffusion)

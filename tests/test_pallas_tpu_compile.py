@@ -11,6 +11,7 @@ phase.
 """
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -235,6 +236,61 @@ def test_flash_grouped_heads_at_width_64(v5e, heads, kv_heads, kw):
 
     kv = ((2, 1024, kv_heads, 64), jnp.bfloat16)
     _compile(f, v5e, ((2, 1024, heads, 64), jnp.bfloat16), kv, kv)
+
+
+@pytest.mark.parametrize("shape, kv_heads, rotated, norm", [
+    ((4, 4096, 64, 128), 8, 128, False),   # laguna-xs2, a sliding layer
+    ((4, 4096, 48, 128), 8, 64, False),    # a full one: 64 lanes, two rolls
+    ((2, 8192, 32, 128), 4, 128, True),    # sdar-30b-a3b-chat: with QK norm
+], ids=["sliding_64_and_8", "full_48_and_8_half_rotated",
+        "sdar_32_and_4_norm"])
+def test_rope_kernels_at_the_mixed_decoder_shapes(v5e, shape, kv_heads,
+                                                  rotated, norm):
+    """QK norm and RoPE of q and k as the two mixed-decoder cells stage
+    them: ``rope_fwd`` and ``rope_bwd`` lower and fit on a v5e in the row
+    tiles the VMEM budget gives, under the scopes the benchmark's
+    ``rope_ms_per_step`` and ``qk_norm_ms_per_step`` read, q and k of two
+    layers through one staged forward and one staged backward, and the
+    result leaves head-major (no ``transpose`` follows the kernel)."""
+    import re
+
+    from paddle_tpu.nn.functional.rotary import rope_tables
+    from paddle_tpu.ops.pallas import rotary
+
+    b, seq, heads, d = shape
+    inv_freq = np.ones((rotated // 2,), np.float32)
+
+    def f(q, k, wq, wk, positions):
+        def loss(q, k, wq, wk):
+            cos, sin = rope_tables(inv_freq, 1.0, positions, seq, d)
+            total = 0.0
+            for _ in range(2):                     # two layers
+                with jax.named_scope("rope"):
+                    q, k = (rotary.rotary(x, cos, sin, rotated // 2,
+                                          w if norm else None)
+                            for x, w in ((q, wq), (k, wk)))
+                total += sum(jnp.sum(x.astype(jnp.float32) ** 2)
+                             for x in (q, k))
+            return total
+        return jax.grad(loss, argnums=(0, 1, 2, 3))(q, k, wq, wk)
+
+    assert rotary.supported(shape, jnp.bfloat16, rotated // 2)
+    weight = ((d,), jnp.bfloat16)
+    text = _compile(f, v5e, (shape, jnp.bfloat16),
+                    ((b, seq, kv_heads, d), jnp.bfloat16), weight, weight,
+                    ((seq,), jnp.int32))
+    for kernel, shared, transform in (
+            ("rope_fwd", "jit(_fwd)", "jvp("),
+            ("rope_bwd", "jit(_bwd_call)", "transpose(jvp(")):
+        calls = [line for line in text.splitlines()
+                 if "tpu_custom_call" in line and f"%{kernel}" in line]
+        assert len(calls) == 4, (kernel, len(calls))
+        op_name = re.search(r'op_name="([^"]+)"', calls[0]).group(1)
+        assert op_name == f"jit(f)/{transform}rope{')' * transform.count('(')}" \
+                          f"/{shared}/{kernel}/pallas_call"
+    forward = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "%rope_fwd" in line]
+    assert any(f"bf16[{b},{heads},{seq},{d}]" in line for line in forward)
 
 
 @pytest.mark.parametrize("h,dtype", [(768, jnp.bfloat16),

@@ -5,7 +5,8 @@ Every Pallas kernel is trajectory-tested on the CPU interpreter, but TPU
 hardware rounds differently (bf16 MXU accumulation, revectorized
 reductions) and the interpreter never meets the TPU lowering. This
 script runs the hot kernels — flash attention fwd/bwd (causal,
-kv-masked, and the geometries whose tiles are computed by sub-blocks), the fused LM-head CE fwd/bwd, paged decode/verify attention,
+kv-masked, and the geometries whose tiles are computed by sub-blocks), QK
+norm with RoPE fwd/bwd, the fused LM-head CE fwd/bwd, paged decode/verify attention,
 chunked LM cross-entropy fwd/bwd, bf16 matmul — on whatever backend is
 live and checks errors against references with bf16-appropriate
 tolerances.
@@ -139,6 +140,72 @@ def check_flash_tile_kinds(interpret):
         rel = max(_max_err(a, w)[1]
                   for a, w in zip((out,) + got, (out_ref,) + want))
         results.append({"check": f"flash_{name}", "max_rel_err": rel,
+                        "tol": 1e-2, "ok": rel < 1e-2})
+    return results
+
+
+def check_rope(interpret):
+    """QK norm and RoPE as the two mixed-decoder cells stage them, q's
+    shape a layer: all 128 lanes rotated, 64 of 128 with a YaRN factor,
+    and with a norm weight at explicit positions that hold a row twice.
+    Values, ``dx`` and the weight's gradient of the Pallas pass against
+    ``F.rms_norm`` and the XLA formula over the same inputs in float32,
+    each within 1% of the reference's largest magnitude (one rounding to
+    bf16 is 0.4% of a value). On the chip through ``F.rotary_embedding``,
+    which takes the kernels there; rehearsed on the CPU, whose entry point
+    takes the formula, straight through the interpreted kernels at a
+    sixteenth of the positions."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.nn import functional as F
+    from paddle_tpu.nn.functional.rotary import _rotate_xla, rope_tables
+    from paddle_tpu.ops.pallas import rotary as kernel
+
+    yarn = {"factor": 64.0, "original_max_position_embeddings": 4096,
+            "beta_fast": 32, "beta_slow": 1}
+    results = []
+    for name, (shape, theta, rotated, scaling, norm) in {
+            "laguna_sliding": ((4, 4096, 64, 128), 1e4, 128, None, False),
+            "laguna_full_yarn": ((4, 4096, 48, 128), 5e5, 64, yarn, False),
+            "sdar_norm_positions": ((2, 8192, 32, 128), 1e6, 128, None, True),
+    }.items():
+        b, seq, heads, d = shape
+        if interpret:
+            b, seq, heads = 1, seq // 16, 2
+        inv_freq, scale = F.rope_frequencies(theta, rotated, scaling)
+        positions = jnp.concatenate([jnp.arange(seq // 2)] * 2) \
+            if norm else None
+        ks = jax.random.split(jax.random.key(len(name)), 3)
+        x = jax.random.normal(ks[0], (b, seq, heads, d), jnp.bfloat16)
+        ct = jax.random.normal(ks[1], x.shape, jnp.float32)
+        w = (1.0 + 0.1 * jax.random.normal(ks[2], (d,))).astype(
+            jnp.bfloat16) if norm else None
+        cos, sin = rope_tables(inv_freq, scale, positions, seq, d)
+
+        def mine(x, w):
+            if interpret:
+                out = kernel.rotary(x, cos, sin, len(inv_freq), w, 1e-6,
+                                    interpret=True)
+            else:
+                out = F.rotary_embedding(x, inv_freq, scale, positions, w,
+                                         1e-6)
+            return jnp.sum(out.astype(jnp.float32) * ct), out
+
+        def ref(x, w):
+            if w is not None:
+                x = F.rms_norm(x, w, 1e-6)
+            out = _rotate_xla(x, cos, sin, len(inv_freq))
+            return jnp.sum(out * ct), out
+
+        args = (0, 1) if norm else (0,)
+        got, out = jax.grad(mine, argnums=args, has_aux=True)(x, w)
+        want, out_ref = jax.grad(ref, argnums=args, has_aux=True)(
+            x.astype(jnp.float32), None if w is None
+            else w.astype(jnp.float32))
+        rel = max(_max_err(a, r)[1]
+                  for a, r in zip((out,) + got, (out_ref,) + want))
+        results.append({"check": f"rope_{name}", "max_rel_err": rel,
                         "tol": 1e-2, "ok": rel < 1e-2})
     return results
 
@@ -277,8 +344,8 @@ def main():
     backend = jax.default_backend()
     interpret = backend != "tpu"
     checks = []
-    for fn in (check_flash_attention, check_flash_tile_kinds, check_fused_ce,
-               check_paged_attention):
+    for fn in (check_flash_attention, check_flash_tile_kinds, check_rope,
+               check_fused_ce, check_paged_attention):
         checks.extend(fn(interpret))
     for fn in (check_chunked_ce, check_bf16_matmul):
         checks.extend(fn())
