@@ -26,7 +26,10 @@ Phases (each through the entry points a user calls, weights from a seed):
   sub-blocks among them, with the counter's kinds), QK norm with RoPE
   fwd/bwd at the two mixed-decoder cells' shapes against ``F.rms_norm``
   and the XLA formula (``rope_calls_staged_total`` says the entry point
-  took the kernels), fused LM-head CE fwd/bwd at the bench
+  took the kernels), the chunked gated delta rule against the recurrence
+  and a Gated DeltaNet block beside a gated full-attention block at head
+  width 256 (``linear_attn_calls_staged_total``, ``gated_delta_chunks_total``,
+  the expert layers' counters), fused LM-head CE fwd/bwd at the bench
   shape against the chunked scan, paged decode and Tq=5 verify at
   h12/d64/page 16 bf16 against the XLA gather.
 - ``serve``  — ``DecodeServer`` over ``PagedKVCache`` with
@@ -214,6 +217,26 @@ def _rope_calls(registry):
             for path in ("pallas", "xla")} if staged else {}
 
 
+def _linear_attn_calls(registry):
+    """``linear_attn_calls_staged_total`` by path, the chunk states those
+    calls walk (``gated_delta_chunks_total``) and the expert layers' three
+    (``publish_routing``'s), as the registry holds them."""
+    out = {}
+    staged = registry.get("linear_attn_calls_staged_total")
+    if staged:
+        out["calls"] = {path: int(staged.value(path=path))
+                        for path in ("chunked", "recurrent")}
+    chunks = registry.get("gated_delta_chunks_total")
+    if chunks:
+        out["chunks"] = int(chunks.value())
+    flat = registry.to_dict()
+    for name in ("moe_tokens_routed_total", "moe_held_assignments_total",
+                 "moe_max_load_over_mean"):
+        if name in flat:
+            out[name] = flat[name]["series"]
+    return out
+
+
 def _config_origins(run, entries):
     """Where each kernel config used here resolves from. A tuning-DB
     file outside the checkout would make the run depend on what an
@@ -335,6 +358,10 @@ def phase_kernels(run: Run):
         flash_tiles = _flash_tiles(tel.registry)
         checks += ns.check_rope(run.interpret)
         rope_calls = _rope_calls(tel.registry)
+    with telemetry.scope(profile=False) as tel:
+        checks += ns.check_linear_attention(run.interpret)
+        linear_attn = _linear_attn_calls(tel.registry)
+        linear_rope_calls = _rope_calls(tel.registry)
     checks += (ns.check_flash_attention(run.interpret)
                + ns.check_fused_ce(run.interpret, **ce)
                + ns.check_paged_attention(run.interpret, **paged))
@@ -350,7 +377,9 @@ def phase_kernels(run: Run):
     origins = _config_origins(run, entries)
     run.say("kernels", event="result", n_checks=len(checks),
             interpret=run.interpret, config_origins=origins,
-            flash_tiles_staged=flash_tiles, rope_calls_staged=rope_calls)
+            flash_tiles_staged=flash_tiles, rope_calls_staged=rope_calls,
+            linear_attn_staged=linear_attn,
+            linear_model_rope_calls_staged=linear_rope_calls)
     bad = [c["check"] for c in checks if not c["ok"]]
     check(not bad, f"kernel checks out of tolerance: {bad}")
     # on the chip check_rope goes through F.rotary_embedding: two cases
@@ -360,6 +389,19 @@ def phase_kernels(run: Run):
                                      "xla": {"norm=0": 0, "norm=1": 0}}
     check(rope_calls == want, f"rotary_embedding staged as {rope_calls}, "
                               f"expected {want}")
+    # check_linear_attention: the rule staged twice chunked (alone, and in
+    # the decoder's one linear block) and once as the recurrence; the
+    # decoder's full layer normalises and rotates q and k in one call each,
+    # on the chip by the kernels (head width 256, 64 lanes rotated)
+    path = "xla" if run.rehearsal else "pallas"
+    want = {"chunked": 2, "recurrent": 1}
+    check(linear_attn.get("calls") == want,
+          f"gated_delta_rule staged as {linear_attn}, expected {want}")
+    check(linear_rope_calls.get(path, {}).get("norm=1") == 2
+          and sum(n for calls in linear_rope_calls.values()
+                  for n in calls.values()) == 2,
+          f"the gated full layer's rotary_embedding staged as "
+          f"{linear_rope_calls}, expected 2 calls with norm=1 on {path}")
     # the three geometries of check_flash_tile_kinds, (dense, triangular,
     # masked): one 1024-row tile; two of them on the diagonal and one dense;
     # 4 tiles of 512 rows on the diagonal and 3 band edges. A triangle is

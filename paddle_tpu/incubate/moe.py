@@ -252,8 +252,8 @@ CHUNK_HEADROOM = 2.0
 
 
 class DroplessMoELayer(Layer):
-    """``shared_expert(x) + sum over the held e in top_k(router(x)) of
-    w_e * expert_e(x)``.
+    """``[gate(x)] shared_expert(x) + sum over the held e in
+    top_k(router(x)) of w_e * expert_e(x)``.
 
     The router scores every token over all ``num_experts`` in float32. The
     layer holds the experts ``held = (first, count)`` (all of them by
@@ -284,7 +284,10 @@ class DroplessMoELayer(Layer):
     mean per held expert); ``publish_routing`` writes them to the telemetry
     registry. ``scoring`` is the router's rule, ``"sigmoid"`` or
     ``"softmax"``; either way the chosen scores are divided by their sum and
-    multiplied by ``routed_scaling_factor``. ``router_attr`` is the router
+    multiplied by ``routed_scaling_factor``. ``shared_expert_gate`` puts a
+    learned ``sigmoid(x w)`` (``w`` ``[d_model, 1]``, the sublayer
+    ``shared_expert_gate``) on the shared expert's output, a weight a
+    token. ``router_attr`` is the router
     weight's ``ParamAttr`` (initialiser, learning-rate multiplier): AdamW
     moves a fresh router a full step whatever its gradient, and at a rate
     the rest of a model trains at, the held experts' load runs to nothing or
@@ -294,7 +297,7 @@ class DroplessMoELayer(Layer):
 
     def __init__(self, d_model, d_expert, num_experts, top_k, held=None,
                  routed_scaling_factor=1.0, scoring="sigmoid", d_shared=None,
-                 router_attr=None):
+                 router_attr=None, shared_expert_gate=False):
         super().__init__()
         first, count = held if held is not None else (0, num_experts)
         if not (0 <= first and count >= 1 and first + count <= num_experts):
@@ -310,6 +313,9 @@ class DroplessMoELayer(Layer):
                              weight_attr=router_attr)
         self.shared_expert = (GatedSiluFFN(d_model, d_shared)
                               if d_shared else None)
+        self.shared_expert_gate = (
+            Linear(d_model, 1, bias_attr=False)
+            if shared_expert_gate and d_shared else None)
         self.experts = GroupedExperts(count, d_model, d_expert)
         for name, dtype in (("tokens_routed", jnp.int32),
                             ("held_assignments", jnp.int32),
@@ -395,7 +401,12 @@ class DroplessMoELayer(Layer):
 
         out = routed
         if self.shared_expert is not None:
-            out = out + self.shared_expert(tokens)
+            shared = self.shared_expert(tokens)
+            if self.shared_expert_gate is not None:
+                gate = jax.nn.sigmoid(
+                    self.shared_expert_gate(tokens).astype(jnp.float32))
+                shared = shared * gate.astype(shared.dtype)
+            out = out + shared
         return jnp.reshape(out, shape)
 
     def publish_routing(self, buffers=None, prefix="", **labels):

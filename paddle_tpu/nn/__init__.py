@@ -21,10 +21,11 @@ from .layers.loss import (  # noqa: F401
     HingeEmbeddingLoss, HSigmoidLoss, KLDivLoss, L1Loss, MarginRankingLoss,
     MSELoss, NLLLoss, SmoothL1Loss, TripletMarginLoss)
 from .decode import BeamSearchDecoder, Decoder, dynamic_decode  # noqa: F401
+from .layers.linear_attention import GatedDeltaNet  # noqa: F401
 from .layers.norm import (  # noqa: F401
-    BatchNorm, BatchNorm1D, BatchNorm2D, BatchNorm3D, GroupNorm, InstanceNorm1D,
-    InstanceNorm2D, InstanceNorm3D, LayerNorm, LocalResponseNorm, RMSNorm,
-    SpectralNorm, SyncBatchNorm)
+    BatchNorm, BatchNorm1D, BatchNorm2D, BatchNorm3D, GatedRMSNorm, GroupNorm,
+    InstanceNorm1D, InstanceNorm2D, InstanceNorm3D, LayerNorm,
+    LocalResponseNorm, RMSNorm, SpectralNorm, SyncBatchNorm)
 from .layers.pooling import (  # noqa: F401
     AdaptiveAvgPool1D, AdaptiveAvgPool2D, AdaptiveAvgPool3D, AdaptiveMaxPool1D,
     AdaptiveMaxPool2D, AdaptiveMaxPool3D, AvgPool1D, AvgPool2D, AvgPool3D,

@@ -21,7 +21,7 @@ from .loss import (  # noqa: F401
     square_error_cost, triplet_margin_loss)
 from .norm import (  # noqa: F401
     batch_norm, group_norm, instance_norm, layer_norm, local_response_norm,
-    normalize, rms_norm)
+    gated_rms_norm, normalize, rms_norm)
 from .pooling import (  # noqa: F401
     adaptive_avg_pool1d, adaptive_avg_pool2d, adaptive_avg_pool3d,
     adaptive_max_pool1d, adaptive_max_pool2d, adaptive_max_pool3d, avg_pool1d,
@@ -31,3 +31,4 @@ from .vision import (  # noqa: F401
 from .attention import (  # noqa: F401
     block_diffusion_mask, scaled_dot_product_attention)
 from .rotary import rope_frequencies, rotary_embedding  # noqa: F401
+from .linear_attention import causal_conv1d, gated_delta_rule  # noqa: F401

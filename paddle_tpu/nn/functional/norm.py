@@ -66,18 +66,37 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5, name=N
     return out
 
 
-def rms_norm(x, weight=None, epsilon=1e-6, name=None):
+def rms_norm(x, weight=None, epsilon=1e-6, name=None, offset=0.0):
     """``x / sqrt(mean(x**2) + epsilon) * weight`` over the last axis: no
     mean is subtracted and there is no bias. The statistics are float32
-    whatever ``x`` is."""
+    whatever ``x`` is. With ``offset`` the scale is ``offset + weight`` (a
+    zero-centred weight under ``offset=1``: it starts at 0 and weight decay
+    pulls the scale to 1), formed and applied in float32 before the result
+    takes ``x``'s dtype."""
     weight = _unwrap(weight)
     x32 = x.astype(jnp.float32)
-    out = (x32 * jax.lax.rsqrt(
+    out = x32 * jax.lax.rsqrt(
         jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + epsilon)
-    ).astype(x.dtype)
+    if offset:
+        return (out * (offset + weight.astype(jnp.float32))).astype(x.dtype)
+    out = out.astype(x.dtype)
     if weight is not None:
         out = out * weight
     return out
+
+
+def gated_rms_norm(x, z, weight, epsilon=1e-6):
+    """``rms_norm(x) * weight * silu(z)`` over the last axis, ``z`` of
+    ``x``'s shape: the norm on a linear-attention head's output with the
+    gate the input projection made beside it. All in float32; the result
+    has ``x``'s dtype."""
+    weight = _unwrap(weight)
+    x32 = x.astype(jnp.float32)
+    out = x32 * jax.lax.rsqrt(
+        jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + epsilon)
+    out = out * weight.astype(jnp.float32) \
+        * jax.nn.silu(z.astype(jnp.float32))
+    return out.astype(x.dtype)
 
 
 def instance_norm(x, running_mean=None, running_var=None, weight=None, bias=None,

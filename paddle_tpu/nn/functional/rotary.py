@@ -154,5 +154,7 @@ def rotary_embedding(x, inv_freq, scale: float = 1.0, positions=None,
         if pallas:
             return kernel.rotary(x, cos, sin, half, norm_weight, epsilon)
         if norm_weight is not None:
-            x = rms_norm(x, norm_weight, epsilon)
+            # a float32 scale (a zero-centred norm's 1 + w) on bf16 heads:
+            # the result keeps the heads' dtype, as the kernel's does
+            x = rms_norm(x, norm_weight, epsilon).astype(x.dtype)
         return _rotate_xla(x, cos, sin, half)

@@ -184,19 +184,47 @@ class LayerNorm(Layer):
 
 class RMSNorm(Layer):
     """Root-mean-square norm over the last axis with a learned scale
-    (``F.rms_norm``)."""
+    (``F.rms_norm``). ``offset=1.0`` makes the weight zero-centred: the
+    scale is ``1 + weight`` and the weight starts at 0."""
 
     def __init__(self, hidden_size, epsilon=1e-6, weight_attr=None,
-                 name=None):
+                 name=None, offset=0.0):
         super().__init__()
         self.hidden_size = hidden_size
         self.epsilon = epsilon
+        self.offset = offset
         self.weight = self.create_parameter(
             (hidden_size,), attr=weight_attr,
-            initializer=_to_initializer(weight_attr, None) or Constant(1.0))
+            initializer=_to_initializer(weight_attr, None)
+            or Constant(1.0 - offset))
+
+    def scale(self):
+        """What the normalised value is multiplied by: the weight, or with
+        an offset ``offset + weight`` in float32."""
+        if not self.offset:
+            return self.weight.value
+        return self.offset + self.weight.value.astype(jnp.float32)
 
     def forward(self, x):
-        return F.rms_norm(x, self.weight, self.epsilon)
+        return F.rms_norm(x, self.weight, self.epsilon, offset=self.offset)
+
+    def extra_repr(self):
+        return f"hidden_size={self.hidden_size}"
+
+
+class GatedRMSNorm(Layer):
+    """``rms_norm(x) * weight * silu(z)`` over the last axis
+    (``F.gated_rms_norm``): ``forward(x, z)``."""
+
+    def __init__(self, hidden_size, epsilon=1e-6, name=None):
+        super().__init__()
+        self.hidden_size = hidden_size
+        self.epsilon = epsilon
+        self.weight = self.create_parameter((hidden_size,),
+                                            initializer=Constant(1.0))
+
+    def forward(self, x, z):
+        return F.gated_rms_norm(x, z, self.weight, self.epsilon)
 
     def extra_repr(self):
         return f"hidden_size={self.hidden_size}"

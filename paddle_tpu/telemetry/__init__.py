@@ -65,6 +65,12 @@ rope_calls_staged_total        counter    nn.functional.rotary_embedding,
                                           where a call is staged: the path
                                           its input took {path=pallas|xla,
                                           norm=0|1 (the QK norm folded in)}
+linear_attn_calls_staged_total counter    nn.functional.gated_delta_rule,
+                                          where a call is staged: the path
+                                          it took {path=chunked|recurrent}
+gated_delta_chunks_total       counter    chunk states a row of those calls
+                                          walks one after another (seq /
+                                          chunk; seq on the recurrent path)
 moe_tokens_routed_total        counter    incubate.moe DroplessMoELayer.
                                           publish_routing: tokens routed
 moe_held_assignments_total     counter    (token, expert) assignments on

@@ -480,7 +480,7 @@ def _laguna_block():
                                                       MixedDecoderBlock)
     attn = GroupedQueryAttention(
         256, 4, 2, 128, {"theta": 10000.0, "rotary_dim": 128}, window=256,
-        gated=True)
+        gate="head")
     block = MixedDecoderBlock(attn, nn.GatedSiluFFN(256, 512), False, 256,
                               1e-6)
     return block, (2, 512, 256), 4, 128

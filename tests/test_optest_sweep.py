@@ -367,6 +367,13 @@ F_CONFIGS = {
     "mse_loss": lambda: (lambda x: F.mse_loss(x, jnp.zeros_like(x)), _x()),
     "nll_loss": lambda: (lambda x: F.nll_loss(
         jax.nn.log_softmax(x, -1), jnp.asarray([1, 2])), _x((2, 4))),
+    "causal_conv1d": lambda: (lambda x: F.causal_conv1d(
+        x, jnp.asarray(_x((3, 4)))), _x((1, 6, 3))),
+    "gated_delta_rule": lambda: (lambda x: F.gated_delta_rule(
+        x, x, x, -jnp.abs(x[..., 0]), F.sigmoid(x[..., 1]), chunk=4),
+        _x((1, 8, 2, 4))),
+    "gated_rms_norm": lambda: (lambda x: F.gated_rms_norm(
+        x, x + 0.5, jnp.ones(x.shape[-1])), _x()),
     "normalize": lambda: (lambda x: F.normalize(x), _x()),
     "npair_loss": lambda: (lambda x: F.npair_loss(
         x, jnp.asarray(_x((2, 3))), jnp.asarray([0, 1])), _x((2, 3))),

@@ -167,6 +167,37 @@ def test_flash_grouped_heads_and_window_at_the_mixed_decoder_shapes(
         "flash_bwd_dkv": whole if window else cut}
 
 
+def test_flash_at_head_width_256_in_the_tuning_dbs_blocks(v5e):
+    """qwen3-next-80b-a3b-instruct.seq8192's one full layer: rows of 8,192
+    positions, 16 query heads over 2 KV heads at head width 256 (one head a
+    256-lane block). The tuning DB's row says (512, 1024): 5.09 ms a call
+    forward and backward against 4.66 in (1024, 1024), 5.32 at 512 and 8.49
+    at 256 (my chip run, PR 33); (1024, 1024) is not taken because the
+    dk/dv kernel then leaves the compiler too little VMEM beside it in a
+    whole training step of one row (refused here: "ran out of memory in
+    memory space vmem"), alone it fits. The three kernels lower and fit."""
+    from paddle_tpu.ops.pallas import tuner
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+
+    cfg, source = tuner.resolve(
+        "flash_attention", jnp.bfloat16, tuner.flash_dims(256, 8192, 8192),
+        {})
+    assert source == "db" and (cfg["block_q"], cfg["block_k"]) == (512, 1024)
+
+    def f(q, k, v):
+        return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True).astype(jnp.float32) ** 2),
+            argnums=(0, 1, 2))(q, k, v)
+
+    kv = ((2, 8192, 2, 256), jnp.bfloat16)
+    text = _compile(f, v5e, ((2, 8192, 16, 256), jnp.bfloat16), kv, kv)
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert any("tpu_custom_call" in line and f"%{kernel}" in line
+                   for line in text.splitlines()), kernel
+    # the operands the benchmark's flash_attn_ms_per_step finds them by
+    assert "s32[32]" in text and "bf16[32,8192,256]" in text
+
+
 @pytest.mark.parametrize("block, non_power", [(512, False), (1024, False),
                                               (384, True)],
                          ids=["512", "1024", "384_block_length_12"])
@@ -242,8 +273,10 @@ def test_flash_grouped_heads_at_width_64(v5e, heads, kv_heads, kw):
     ((4, 4096, 64, 128), 8, 128, False),   # laguna-xs2, a sliding layer
     ((4, 4096, 48, 128), 8, 64, False),    # a full one: 64 lanes, two rolls
     ((2, 8192, 32, 128), 4, 128, True),    # sdar-30b-a3b-chat: with QK norm
+    ((2, 8192, 16, 256), 2, 64, True),     # qwen3-next: two lane blocks a
+                                           # head, a quarter of them rotated
 ], ids=["sliding_64_and_8", "full_48_and_8_half_rotated",
-        "sdar_32_and_4_norm"])
+        "sdar_32_and_4_norm", "qwen3next_16_and_2_norm_d256"])
 def test_rope_kernels_at_the_mixed_decoder_shapes(v5e, shape, kv_heads,
                                                   rotated, norm):
     """QK norm and RoPE of q and k as the two mixed-decoder cells stage

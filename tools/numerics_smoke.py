@@ -210,6 +210,95 @@ def check_rope(interpret):
     return results
 
 
+def check_linear_attention(interpret):
+    """The gated delta rule at the Qwen3-Next cell's head widths (4 value
+    heads of d_k = d_v = 128, bf16, 1,024 positions; rehearsed on the CPU
+    at 2 heads of 16 and 128 positions): the chunked path a layer takes
+    against the recurrence over positions, given the same bf16 inputs in
+    float32; output and the five gradients within 2% of the reference's
+    largest magnitude (the result's and the cotangents' rounding to bf16).
+    Then a two-layer decoder of that configuration's kinds (a Gated
+    DeltaNet block; gated full attention at head width 256 with the
+    zero-centred QK norm and 64 rotated lanes; a dropless expert layer with
+    the gated shared expert in both) forward and backward through
+    ``MixedDecoderModel``: every gradient finite and none all zero, the
+    expert layers' counts published. What path the rotation and the rule
+    took is for the caller to read from the counters."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.incubate.moe import DroplessMoELayer
+    from paddle_tpu.jit.functionalization import functional_call, state_of
+    from paddle_tpu.nn import functional as F
+    from paddle_tpu.text.models.mixed_decoder import MixedDecoderModel
+
+    seq, h, d = (128, 2, 16) if interpret else (1024, 4, 128)
+    ks = jax.random.split(jax.random.key(33), 6)
+    q, k = (jax.random.normal(key, (2, seq, h, d)) for key in ks[:2])
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) / d ** 0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    q, k = q.astype(jnp.bfloat16), k.astype(jnp.bfloat16)
+    v = jax.random.normal(ks[2], (2, seq, h, d), jnp.bfloat16)
+    g = -jax.random.uniform(ks[3], (2, seq, h)) * jnp.arange(1, h + 1)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (2, seq, h)))
+    ct = jax.random.normal(ks[5], v.shape, jnp.float32)
+
+    def rule(path):
+        def f(*args):
+            out = F.gated_delta_rule(*args, path=path)
+            return jnp.sum(out.astype(jnp.float32) * ct), out
+        return jax.jit(jax.grad(f, argnums=(0, 1, 2, 3, 4), has_aux=True))
+
+    got, out = rule("chunked")(q, k, v, g, beta)
+    want, out_ref = rule("recurrent")(*(
+        x.astype(jnp.float32) for x in (q, k, v, g, beta)))
+    rel = max(_max_err(a, w)[1]
+              for a, w in zip((out,) + got, (out_ref,) + want))
+    results = [{"check": "gated_delta_rule_chunked_vs_recurrent",
+                "max_rel_err": rel, "tol": 2e-2, "ok": rel < 2e-2}]
+
+    hidden, heads, kv, head_dim, experts = (64, 4, 2, 32, 8) if interpret \
+        else (1024, 4, 2, 256, 16)
+    model = MixedDecoderModel(
+        vocab_size=512, hidden_size=hidden,
+        layer_types=["linear_attention", "full_attention"],
+        heads_per_layer=[heads] * 2, mlp_layer_types=["sparse"] * 2,
+        kv_heads=kv, head_dim=head_dim,
+        rope={"full_attention": {"theta": 1e7, "rotary_dim": head_dim // 4}},
+        sliding_window=None, intermediate_size=2 * hidden,
+        num_experts=experts, experts_per_token=2, expert_size=hidden // 2,
+        shared_expert_size=hidden // 2, shared_expert_gate=True,
+        held_experts=(0, experts // 2), router_scoring="softmax",
+        qk_norm=True, attention_gate="elementwise", norm_offset=1.0,
+        checkpoint_blocks=True,
+        linear_attention=dict(key_heads=h // 2, value_heads=h, d_k=d, d_v=d,
+                              conv_kernel=4))
+    model.astype("bfloat16")
+    params = dict(state_of(model)[0])
+    ids = jax.random.randint(ks[0], (2, seq), 0, 512)
+
+    def loss(p):
+        # buffers None: the layers' own go in, all of them come out
+        out, buffers = functional_call(model, p, None, ids, rng=ks[1])
+        return jnp.mean(jnp.square(out.astype(jnp.float32))), buffers
+
+    (value, buffers), grads = jax.jit(
+        jax.value_and_grad(loss, has_aux=True))(params)
+    for name, layer in model.named_sublayers():
+        if isinstance(layer, DroplessMoELayer):
+            layer.publish_routing(buffers, name + ".", layer=name)
+    finite = bool(jnp.isfinite(value)) and all(
+        bool(jnp.all(jnp.isfinite(x.astype(jnp.float32))))
+        for x in grads.values())
+    zero = sorted(n for n, x in grads.items()
+                  if not float(jnp.max(jnp.abs(x.astype(jnp.float32)))))
+    results.append({"check": "mixed_decoder_linear_and_gated_full_block",
+                    "loss": float(value), "leaves": len(grads),
+                    "zero_gradient_leaves": zero,
+                    "ok": finite and not zero})
+    return results
+
+
 def _max_err(got, ref):
     """(max abs error, the same over max |ref|) in fp32."""
     import jax.numpy as jnp
@@ -345,7 +434,7 @@ def main():
     interpret = backend != "tpu"
     checks = []
     for fn in (check_flash_attention, check_flash_tile_kinds, check_rope,
-               check_fused_ce, check_paged_attention):
+               check_linear_attention, check_fused_ce, check_paged_attention):
         checks.extend(fn(interpret))
     for fn in (check_chunked_ce, check_bf16_matmul):
         checks.extend(fn())
