@@ -1461,8 +1461,9 @@ class ParallelTrainer:
                 # predicted-vs-measured step time (telemetry.calibration):
                 # the overlap model's makespan of the staged step vs this
                 # step's wall clock
+                # (``step`` here is the staged program, not a count)
                 _telemetry.calibration.record(
-                    "step_time", makespan, dt, step=step)
+                    "step_time", makespan, dt, step=self._steps_run)
         res = None
         if self.state["comm_err"]:
             from .compressed import residual_norm

@@ -18,17 +18,30 @@ one unit lower-triangular system
 
     (I + tril(diag(beta) (K K^T * decay), -1)) [W | U] = diag(beta) [K e^g | V]
 
-solved for all chunks of all heads at once as batched matrix products
-(``inverse_unit_lower``), and only the state is carried from chunk to
-chunk: ``seq / chunk`` dependent steps of two products each
-(``_chunk_states``) where the recurrence has ``seq``. What reads the
-states (the outputs) and what their cotangents feed are again products
-over all chunks at once.
+and only the state is carried from chunk to chunk: ``seq / chunk``
+dependent steps where the recurrence has ``seq``. Two implementations of
+that one algorithm, chosen from what the call can observe (backend, head
+widths, chunk, dtype, sequence), never by the caller:
+
+- on a TPU, at head widths of one 128-lane block and the layer's chunk of
+  64, two Pallas kernels a (row, head) (``ops/pallas/gated_delta.py``:
+  ``gated_delta_fwd`` / ``gated_delta_bwd``): a chunk's system, its
+  inverse, ``W``, ``U`` and the outputs stay in VMEM and the state is
+  carried in a scratch; HBM sees the operands, ``o`` and one state a chunk.
+  No head groups and no checkpoint: the grid is a (row, head) at a time;
+- everywhere else (the CPU, narrow toy heads, other chunk sizes) XLA's
+  ``_chunked`` below: the systems of all chunks of ``GROUP_HEADS`` heads
+  solved at once as batched matrix products (``inverse_unit_lower``), the
+  state carried by a ``lax.scan`` of two products a step
+  (``_chunk_states``), and what reads the states (the outputs) and what
+  their cotangents feed again products over all chunks at once. It is the
+  yardstick the kernels are tested against, beside the recurrence.
 
 Decays, the triangular system and the state are float32 whatever the
-operands are; a product with a float32 operand runs at ``PRECISION``.
-The backward pass keeps one state a chunk (``d_k x d_v`` float32 a head),
-never one a position.
+operands are; a product with a float32 operand runs at ``PRECISION`` (the
+kernels split such an operand into two bf16 terms and keep the cross
+terms: the same three passes). The backward pass keeps one state a chunk
+(``d_k x d_v`` float32 a head), never one a position.
 """
 from __future__ import annotations
 
@@ -36,23 +49,27 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-# float32 products (the system's inverse, everything the state touches):
-# three bf16 passes on a TPU's MXU, an error of 2**-16 of a term or less; the
-# CPU computes float32 as it is. On one layer's rule at 8,192 positions one
+# float32 products (the system's inverse, everything the state touches) on
+# XLA's paths, chunked and recurrent: three bf16 passes on a TPU's MXU, an
+# error of 2**-16 of a term or less; the CPU computes float32 as it is. The
+# kernels ask for the same three passes themselves (``gated_delta._dot``)
+# and do not read this value. On one layer's rule at 8,192 positions one
 # pass (the TPU's default, which rounds the state to bf16 in every product)
 # lay 3.7e-3 of the six-pass output's norm away, three passes 2.3e-4; over
 # the whole training step six passes (HIGHEST) cost 0.8% of the tokens a
-# second (PERF.md section 6, PR 33). Nothing on the chip guards this value:
-# the benchmark's comparison does not tell a bf16-rounded state from a sound
-# one under bf16 activations (PERF.md section 7); the CPU tests hold the
-# chunked path to the recurrence in float32.
+# second (PERF.md section 6, PR 33). The benchmark's comparison does not
+# tell a bf16-rounded state from a sound one under bf16 activations
+# (PERF.md section 7); ``chip_smoke.py``'s ``kernels`` phase does, against
+# this path at HIGHEST (``check_gated_delta_precision``), and the CPU tests
+# hold both implementations to the recurrence in float32.
 PRECISION = lax.Precision.HIGH
-# heads the chunked path works on at once. A (row, head) pair's chunks hold
-# about 70 MB of float32 at 8,192 positions (the systems, W, U, the states)
-# and three times that in the backward pass; all 32 heads of two rows at
-# once took a training step past a 16 GB chip (16.08 GB compiled, 13.35 GB
-# in two groups of 16 heads; PERF.md section 6, PR 33). The rule reads the
-# heads and not the batch, so that one row takes the path two rows take.
+# heads XLA's chunked path works on at once (the kernels have no groups). A
+# (row, head) pair's chunks hold about 70 MB of float32 at 8,192 positions
+# (the systems, W, U, the states) and three times that in the backward
+# pass; all 32 heads of two rows at once took a training step past a 16 GB
+# chip (16.08 GB compiled, 13.35 GB in two groups of 16 heads; PERF.md
+# section 6, PR 33). The rule reads the heads and not the batch, so that
+# one row takes the path two rows take.
 GROUP_HEADS = 16
 
 
@@ -339,20 +356,30 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 64,
 
     ``path="chunked"`` (what a layer calls) works in chunks of ``chunk``
     positions, a power of two; a sequence that is no multiple of it is
-    padded with ``beta = 0, g = 0`` and cut again; ``GROUP_HEADS`` heads
-    are worked on at once, the groups one after another and each recomputed
-    in the backward pass, which bounds what a call holds. ``path=
-    "recurrent"`` is
-    the definition, a step a position: the yardstick of the tests, too slow
-    and too large in the backward pass for a real row. Staged under the
-    scope ``gated_delta_rule``; ``linear_attn_calls_staged_total{path}``
-    counts the staged calls and ``gated_delta_chunks_total`` their chunk
+    padded with ``beta = 0, g = 0`` and cut again. Which implementation
+    runs is read from the input (the module's opening lines): the Pallas
+    kernels on a TPU where ``gated_delta.supported`` takes the shapes, else
+    XLA's batched products, ``GROUP_HEADS`` heads at once, the groups one
+    after another and each recomputed in the backward pass, which bounds
+    what a call holds. ``path="recurrent"`` is the definition, a step a
+    position: the yardstick of the tests, too slow and too large in the
+    backward pass for a real row. Staged under the scope
+    ``gated_delta_rule``; ``linear_attn_calls_staged_total{path=pallas|
+    chunked|recurrent}`` counts the staged calls by what was decided
+    (``chunked``: XLA's) and ``gated_delta_chunks_total`` their chunk
     steps."""
     if path not in ("chunked", "recurrent"):
         raise ValueError(f"unknown path {path!r}")
+    from ...ops.pallas import gated_delta as kernel
     seq = q.shape[1]
+    if path == "chunked" and jax.default_backend() == "tpu" \
+            and q.dtype == k.dtype == v.dtype \
+            and kernel.supported(q.shape, v.shape, v.dtype, chunk):
+        path = "pallas"
     _count_staged(path, seq if path == "recurrent" else -(-seq // chunk))
     with jax.named_scope("gated_delta_rule"):
         if path == "recurrent":
             return _recurrent(q, k, v, g, beta)
+        if path == "pallas":
+            return kernel.gated_delta(q, k, v, g, beta)
         return _chunked(q, k, v, g, beta, chunk, GROUP_HEADS)

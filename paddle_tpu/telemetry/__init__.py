@@ -67,7 +67,9 @@ rope_calls_staged_total        counter    nn.functional.rotary_embedding,
                                           norm=0|1 (the QK norm folded in)}
 linear_attn_calls_staged_total counter    nn.functional.gated_delta_rule,
                                           where a call is staged: the path
-                                          it took {path=chunked|recurrent}
+                                          it took {path=pallas (the
+                                          kernels)|chunked (XLA's batched
+                                          products)|recurrent}
 gated_delta_chunks_total       counter    chunk states a row of those calls
                                           walks one after another (seq /
                                           chunk; seq on the recurrent path)

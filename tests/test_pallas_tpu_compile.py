@@ -326,6 +326,59 @@ def test_rope_kernels_at_the_mixed_decoder_shapes(v5e, shape, kv_heads,
     assert any(f"bf16[{b},{heads},{seq},{d}]" in line for line in forward)
 
 
+@pytest.mark.parametrize("rows", [2, 1])
+def test_gated_delta_kernels_at_the_qwen3_next_cells_shape(v5e, rows):
+    """The gated delta rule of ``qwen3-next-80b-a3b-instruct.seq8192``'s
+    three linear layers: 32 value heads of ``d_k = d_v = 128`` over 8,192
+    positions in bf16, two timed rows and the comparison's one.
+    ``gated_delta_fwd`` and ``gated_delta_bwd`` lower and fit Mosaic's
+    scoped VMEM in the tile the module gives, in one program with the flash
+    kernels of the cell's full layer ((512, 1024) blocks at head width
+    256); two layers share one staged forward and one staged backward, and
+    the compiled program calls each kernel by its name with the scope
+    ``gated_delta_rule`` (which ``F.gated_delta_rule`` opens: its gate asks
+    the running backend, the CPU here) in front, forward and transposed:
+    what ``gated_delta_rule_ms_per_step`` finds them by."""
+    import re
+
+    from paddle_tpu.ops.pallas import gated_delta
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+
+    shape = (rows, 8192, 32, 128)
+    assert gated_delta.supported(shape, shape, jnp.bfloat16, 64)
+
+    def f(q, k, v, g, beta, fq, fk, fv):
+        def loss(q, k, v, g, beta, fq, fk, fv):
+            for _ in range(2):                     # two layers
+                with jax.named_scope("gated_delta_rule"):
+                    v = gated_delta.gated_delta(q, k, v, g, beta)
+            with jax.named_scope("attn"):
+                o = flash_attention(fq, fk, fv, causal=True)
+            return jnp.sum(v.astype(jnp.float32) ** 2) \
+                + jnp.sum(o.astype(jnp.float32) ** 2)
+        return jax.grad(loss, argnums=tuple(range(8)))(
+            q, k, v, g, beta, fq, fk, fv)
+
+    x, vec = (shape, jnp.bfloat16), (shape[:3], jnp.float32)
+    kv = ((rows, 8192, 2, 256), jnp.bfloat16)
+    text = _compile(f, v5e, x, x, x, vec, vec,
+                    ((rows, 8192, 16, 256), jnp.bfloat16), kv, kv)
+    for kernel, shared, transform, calls in (
+            ("gated_delta_fwd", "jit(_fwd)", "jvp(", 2),
+            ("gated_delta_bwd", "jit(_bwd_call)", "transpose(jvp(", 2)):
+        lines = [line for line in text.splitlines()
+                 if "tpu_custom_call" in line and f"%{kernel}" in line]
+        assert len(lines) == calls, (kernel, len(lines))
+        op_name = re.search(r'op_name="([^"]+)"', lines[0]).group(1)
+        assert op_name == f"jit(f)/{transform}gated_delta_rule" \
+                          f"{')' * transform.count('(')}/{shared}/{kernel}" \
+                          f"/pallas_call"
+    assert any("tpu_custom_call" in line and "%flash_bwd_dkv" in line
+               for line in text.splitlines())
+    # the residual: the state entering each chunk, float32
+    assert f"f32[{rows},32,128,128,128]" in text
+
+
 @pytest.mark.parametrize("h,dtype", [(768, jnp.bfloat16),
                                      (768, jnp.float32),
                                      (2048, jnp.bfloat16)])

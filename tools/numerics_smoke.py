@@ -214,6 +214,7 @@ def check_linear_attention(interpret):
     """The gated delta rule at the Qwen3-Next cell's head widths (4 value
     heads of d_k = d_v = 128, bf16, 1,024 positions; rehearsed on the CPU
     at 2 heads of 16 and 128 positions): the chunked path a layer takes
+    (on the chip the Pallas kernels, rehearsed XLA's batched products)
     against the recurrence over positions, given the same bf16 inputs in
     float32; output and the five gradients within 2% of the reference's
     largest magnitude (the result's and the cotangents' rounding to bf16).
@@ -297,6 +298,75 @@ def check_linear_attention(interpret):
                     "zero_gradient_leaves": zero,
                     "ok": finite and not zero})
     return results
+
+
+# ``check_gated_delta_precision``'s limits, as shares of each reference
+# tensor's norm: between what the kernels read at three bf16 passes a float32
+# product and what one pass reads (PERF.md section 6, PR 34, my chip runs:
+# 1.3e-4 to 1.4e-4 against 3.7e-3 for o, dq, dk and dv, 3.7e-6 against 2.4e-3
+# for dg and dbeta, which no bf16 rounds)
+GATED_DELTA_PRECISION_TOL = {"o": 7e-4, "dq": 7e-4, "dk": 7e-4, "dv": 7e-4,
+                             "dg": 1e-4, "dbeta": 1e-4}
+
+
+def check_gated_delta_precision(interpret):
+    """The guard the benchmark's comparison lacks (it does not tell a
+    bf16-rounded state from a sound one): the kernels' ``o`` and five
+    gradients on one layer-shaped input of the Qwen3-Next cell (a row of
+    8,192 positions, 32 value heads of ``d_k = d_v = 128``, bf16; rehearsed
+    on the CPU at 256 positions and 2 heads) against XLA's chunked path at
+    ``Precision.HIGHEST`` over the same numbers in float32, each as the
+    norm of the difference over the reference's norm. A product that fell
+    to one bf16 pass reads an order of magnitude over these limits."""
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.nn.functional import linear_attention as la
+    from paddle_tpu.ops.pallas import gated_delta
+
+    seq, h, d = (256, 2, 128) if interpret else (8192, 32, 128)
+    ks = jax.random.split(jax.random.key(34), 7)
+    q, k = (jax.random.normal(key, (1, seq, h, d)) for key in ks[:2])
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) / d ** 0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    q, k = q.astype(jnp.bfloat16), k.astype(jnp.bfloat16)
+    v = jax.random.normal(ks[2], (1, seq, h, d), jnp.bfloat16)
+    # the layer's decay, -A softplus(a + dt_bias): rates from e^-6 to e
+    g = -jnp.exp(jax.random.uniform(ks[3], (1, seq, h), minval=-6.0,
+                                    maxval=1.0)) \
+        * jax.random.uniform(ks[6], (1, seq, h))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (1, seq, h)))
+    # a cotangent that bf16 holds: the kernels' o is bf16, so is its do
+    ct = jax.random.normal(ks[5], v.shape, jnp.bfloat16).astype(jnp.float32)
+
+    def rule(fn):
+        def f(*args):
+            out = fn(*args)
+            return jnp.sum(out.astype(jnp.float32) * ct), out
+        return jax.jit(jax.grad(f, argnums=(0, 1, 2, 3, 4), has_aux=True))
+
+    got, out = rule(lambda *a: gated_delta.gated_delta(
+        *a, interpret=interpret))(q, k, v, g, beta)
+    # ``kk`` and ``qk`` carry no precision of their own (their operands are
+    # bf16 numbers), so their transposes, which multiply a float32 cotangent,
+    # take the default: one pass on the chip unless it is raised here
+    with mock.patch.object(la, "PRECISION", jax.lax.Precision.HIGHEST), \
+            jax.default_matmul_precision("highest"):
+        want, out_ref = rule(lambda *a: la._chunked(
+            *a, gated_delta.CHUNK, la.GROUP_HEADS))(*(
+                x.astype(jnp.float32) for x in (q, k, v, g, beta)))
+    rel = {}
+    for name, a, w in zip(GATED_DELTA_PRECISION_TOL, (out,) + got,
+                          (out_ref,) + want):
+        # the reference rounded as the kernels round what they write
+        a, w = a.astype(jnp.float32), w.astype(a.dtype).astype(jnp.float32)
+        rel[name] = float(jnp.linalg.norm(a - w) / jnp.linalg.norm(w))
+    return [{"check": "gated_delta_kernels_vs_xla_highest", "rel_l2": rel,
+             "tol": GATED_DELTA_PRECISION_TOL,
+             "ok": all(rel[n] < t
+                       for n, t in GATED_DELTA_PRECISION_TOL.items())}]
 
 
 def _max_err(got, ref):
@@ -434,7 +504,8 @@ def main():
     interpret = backend != "tpu"
     checks = []
     for fn in (check_flash_attention, check_flash_tile_kinds, check_rope,
-               check_linear_attention, check_fused_ce, check_paged_attention):
+               check_linear_attention, check_gated_delta_precision,
+               check_fused_ce, check_paged_attention):
         checks.extend(fn(interpret))
     for fn in (check_chunked_ce, check_bf16_matmul):
         checks.extend(fn())
