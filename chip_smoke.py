@@ -162,20 +162,16 @@ def _timed_steps(jax, trainer, ids, labels, steps):
     return out
 
 
-class CompileCounter:
-    """Counts XLA backend compilations through jax's own monitoring
-    events — a compile anywhere in the process, not only a re-staged
-    train step."""
+def _compiled_since(moment):
+    """{function: programs} the backend compiled (or loaded) since
+    ``moment`` (``time.time()``), from the program's own staging record:
+    a compile anywhere in the process, not only a re-staged train step."""
+    from collections import Counter
 
-    EVENT = "/jax/core/compile/backend_compile_duration"
+    from paddle_tpu.telemetry import staging
 
-    def __init__(self, jax):
-        self.n = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event, duration, **kw):
-        if event == self.EVENT:
-            self.n += 1
+    return dict(Counter(e["fun"] for e in staging.entries()
+                        if e["phase"] == "compile" and e["start"] >= moment))
 
 
 def _pallas_kernels(closed_jaxpr):
@@ -274,17 +270,24 @@ def phase_train(run: Run):
     from tools._mesh_setup import data_mesh
 
     jax, cfg = run.jax, run.cfg
-    compiles = CompileCounter(jax)
     ids, labels = _batch(cfg, cfg["batch"])
     with telemetry.scope(profile=False) as tel:
         trainer = _gpt_trainer(cfg, data_mesh(1))
         steps = _timed_steps(jax, trainer, ids, labels, 2)
+        warm = time.time()
+        # where the seconds to here went: the step's programs and the
+        # call each was staged in, their trace, lower and compile, the
+        # trainer's construction
+        staged = trainer.staging_summary()
         run.say("train", event="first_step", times={
             "first_step_s": round(steps[0][1], 2),
-            "since_start_s": round(time.perf_counter() - T0, 2)})
-        compiles_warm = compiles.n
+            "since_start_s": round(time.perf_counter() - T0, 2),
+            "staging": staged},
+            step_programs=staged["train_step"]["programs"],
+            step_programs_staged_in_steps=staged["train_step"][
+                "staged_in_steps"])
         steps += _timed_steps(jax, trainer, ids, labels, TRAIN_STEPS - 2)
-        compiled_late = compiles.n - compiles_warm
+        compiled_late = _compiled_since(warm)
         kernels = _pallas_kernels(trainer.staged_jaxpr(ids, labels))
         resolved = tel.registry.get("pallas_config_resolved_total")
         flash_fallbacks = resolved.value(
@@ -323,8 +326,8 @@ def phase_train(run: Run):
     check(abs(losses[0] - math.log(cfg["vocab"])) < 0.5,
           f"first loss {losses[0]} is not near ln(vocab)")
     check(losses[-1] < losses[0], f"loss did not fall: {losses}")
-    check(compiled_late == 0,
-          f"{compiled_late} compilations after step 2")
+    check(not compiled_late,
+          f"compilations after step 2, by function: {compiled_late}")
     if not run.rehearsal:
         # off the TPU the gate routes attention to XLA by design
         _check_flash_calls(kernels, cfg["layers"])

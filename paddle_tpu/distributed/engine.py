@@ -36,6 +36,7 @@ shard_map = jax.shard_map
 
 from .. import profiler as _profiler
 from .. import telemetry as _telemetry
+from ..telemetry import staging as _staging
 from ..telemetry import tracing as _tracing
 from ..framework.random import get_rng_key
 from ..jit.functionalization import functional_call, state_of
@@ -47,6 +48,9 @@ from .meta_parallel.pipeline_parallel import PipelineParallel
 from .meta_parallel.sharding_parallel import shard_spec_for
 
 DATA_AXES = ("data", "sharding")  # batch is split over both (ZeRO ⊂ DP)
+
+# what telemetry.staging calls the staged step (make_step's inner function)
+_STEP_FUN = "train_step"
 
 # XLA flags that make the TPU compiler schedule collectives asynchronously
 # and hide them under compute — the hardware half of the bucketed
@@ -214,16 +218,7 @@ class ParallelTrainer:
         self._steps_run = 0
         self.last_divergence: list = []
         self.state = None
-        self._init_state()
-        self._build()
-        # layers that manage live training state between steps (the
-        # HeterPS hot tier) bind themselves here — forgetting a manual
-        # attach() would silently train on the stale eager parameters
-        if hasattr(model, "named_sublayers"):
-            for _, sub in model.named_sublayers(include_self=True):
-                hook = getattr(sub, "_on_trainer_built", None)
-                if hook is not None:
-                    hook(self)
+        self._construct()
 
     @classmethod
     def from_plan(cls, plan, model, optimizer, loss_fn: Callable,
@@ -240,6 +235,23 @@ class ParallelTrainer:
         return cls(model, optimizer, loss_fn, **kw)
 
     # -- state -------------------------------------------------------------
+    def _construct(self):
+        """State and step construction for ``self.mesh``, each under its
+        span: on the profiler's clock, and in the staging record
+        (``telemetry.staging``) beside what jax staged inside it."""
+        with _staging.span("paddle_tpu.trainer.init_state"):
+            self._init_state()
+        with _staging.span("paddle_tpu.trainer.build"):
+            self._build()
+        # layers that manage live training state between steps (the
+        # HeterPS hot tier) bind themselves here — forgetting a manual
+        # attach() would silently train on the stale eager parameters
+        if hasattr(self.model, "named_sublayers"):
+            for _, sub in self.model.named_sublayers(include_self=True):
+                hook = getattr(sub, "_on_trainer_built", None)
+                if hook is not None:
+                    hook(self)
+
     def _param_spec(self, name, p):
         return p.pspec if p.pspec is not None else P()
 
@@ -1065,7 +1077,8 @@ class ParallelTrainer:
         step = self._step_cache.get(cache_key)
         self._last_stage_miss = step is None
         if step is None:
-            t0 = time.perf_counter()
+            # One span times the build, for the profiler, the staging
+            # record, the "stage" child span and stage_time_seconds alike.
             # "stage" rides as a child of the runner's ambient step span
             # (if one is open): staged-program builds show up inside the
             # step that paid for them. The per-bucket exchange plan is
@@ -1073,7 +1086,8 @@ class ParallelTrainer:
             # inside the jitted program, invisible to host-side spans.
             sp = _tracing.child_span("stage", check=bool(do_check))
             try:
-                step = self._make_step(in_specs, lb_specs, do_check)
+                with _staging.span("paddle_tpu.trainer.make_step") as made:
+                    step = self._make_step(in_specs, lb_specs, do_check)
             finally:
                 if sp is not None:
                     for i, bk in enumerate(
@@ -1083,7 +1097,7 @@ class ParallelTrainer:
                             bytes=int(sum(
                                 self.state["params"][k].nbytes
                                 for k in bk)))
-                    sp.end("ok", cache_miss=True)
+                    sp.end("ok", cache_miss=True, seconds=made.seconds)
             self._step_cache[cache_key] = step
             if _telemetry.enabled():
                 _telemetry.counter(
@@ -1093,7 +1107,7 @@ class ParallelTrainer:
                 _telemetry.histogram(
                     "stage_time_seconds",
                     "wall time building a step for a new batch "
-                    "structure").observe(time.perf_counter() - t0)
+                    "structure").observe(made.seconds)
                 self._step_costs[cache_key] = self._trace_step_cost(
                     step, inputs, labels)
         self._last_cache_key = cache_key
@@ -1288,7 +1302,10 @@ class ParallelTrainer:
         # "stage" is the rng key, the lr, the batch going to the device and
         # the program lookup; "launch" the call of the staged step until it
         # returns. Neither blocks. Read by the benchmark's trainer_stage_ms,
-        # trainer_launch_ms and dispatch_exposed_ms_per_step.
+        # trainer_launch_ms and dispatch_exposed_ms_per_step. A call that
+        # stages a program of the step (the first ones, a new batch shape)
+        # does so under "launch"; telemetry.staging keeps jax's trace,
+        # lower and compile of it and the step it happened in.
         with jax.profiler.TraceAnnotation("paddle_tpu.trainer.stage"):
             key = get_rng_key()
             lr = self.optimizer.get_lr() if lr is None else lr
@@ -1317,11 +1334,15 @@ class ParallelTrainer:
               if _profiler.is_profiler_enabled() else None)
         n_compiled0 = self._jit_cache_size(step) if tel else None
         taint = 1.0 if grad_taint is None else float(grad_taint)
+        staged0 = _staging.programs(_STEP_FUN)
         with jax.profiler.TraceAnnotation("paddle_tpu.trainer.launch"):
             loss, new_params, new_opt, new_comm_err, new_guard, integ = step(
                 self.state["params"], self.state["buffers"],
                 self.state["opt"], self.state["comm_err"],
                 self.state["guard"], key, lr, taint, inputs, labels)
+        if _staging.programs(_STEP_FUN) != staged0:
+            # this call staged a program of the step: the record says which
+            _staging.tag_step(_STEP_FUN, staged0, self._steps_run)
         if tel or ev is not None:
             # the documented telemetry sync point: step wall time includes
             # device execution (loss is the last value the step produces)
@@ -1346,6 +1367,23 @@ class ParallelTrainer:
         if _flags.flag("benchmark"):
             jax.block_until_ready(loss)
         return loss
+
+    def staging_summary(self) -> dict:
+        """Where set-up went, for a job's log at its first steady step:
+        the staging record's totals (``telemetry.staging.summary``) of
+        the step (programs, seconds traced, lowered and compiled, the
+        persistent cache's hits and misses), the ``train_step`` call each
+        of its programs was staged in, and the seconds of the trainer's
+        phases (the ``paddle_tpu.trainer.`` spans the record holds). The
+        record is the process's: two trainers in one process add up."""
+        totals = _staging.summary()
+        steps = sorted({(e["program"], e["step"])
+                        for e in _staging.entries(_STEP_FUN) if "step" in e})
+        out = {_STEP_FUN: dict(totals.get(_STEP_FUN, {"programs": 0}),
+                               staged_in_steps=[s for _, s in steps])}
+        out.update((name, row["span_s"]) for name, row in totals.items()
+                   if name.startswith("paddle_tpu.trainer."))
+        return out
 
     def _record_integrity(self, integ):
         """Host side of the check step: pull the tiny divergence mask
@@ -1573,11 +1611,5 @@ class ParallelTrainer:
         from .mesh import set_mesh
         self.mesh = mesh
         set_mesh(mesh)
-        self._init_state()
-        self._build()
-        if hasattr(self.model, "named_sublayers"):
-            for _, sub in self.model.named_sublayers(include_self=True):
-                hook = getattr(sub, "_on_trainer_built", None)
-                if hook is not None:
-                    hook(self)
+        self._construct()
         return self

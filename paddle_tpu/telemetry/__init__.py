@@ -27,10 +27,26 @@ Metric catalogue (recorded by the built-in instrumentation; see README
 name                           kind       source
 =============================  =========  =================================
 step_time_seconds              histogram  engine.train_step / hapi callback
-stage_time_seconds             histogram  engine._stage cache miss
+stage_time_seconds             histogram  engine._stage cache miss: the
+                                          span paddle_tpu.trainer.make_step
 compile_time_seconds           histogram  engine.compile
 recompiles_total               counter    engine._stage misses + jit shape
                                           misses
+staging_seconds_total          counter    telemetry.staging: seconds jax
+                                          spent staging a function, from
+                                          jax.monitoring's events {phase=
+                                          trace|lower|compile, fun=...};
+                                          a staging inside another counts
+                                          in the outer one only
+staging_programs_total         counter    programs of a function the
+                                          backend compiled or loaded from
+                                          the persistent cache {fun=...};
+                                          fun=train_step above 1 is a
+                                          step staged again
+compile_cache_hits_total       counter    programs loaded from jax's
+                                          persistent compilation cache
+compile_cache_misses_total     counter    programs compiled and written
+                                          to it
 tokens_per_sec                 gauge      engine.train_step
 mfu                            gauge      analysis.cost FLOPs / step time /
                                           peak_flops_per_sec()
@@ -240,6 +256,19 @@ planner_search_ms              histogram  plan_search wall time
                                           analytic/staged scoring)
 =============================  =========  =================================
 
+The staging record (``telemetry.staging``) behind the four ``staging_*``
+/ ``compile_cache_*`` series is on without ``telemetry.scope`` or
+``enable()``: a listener on jax's own events, installed when this
+package is imported, that costs nothing where nothing is staged.
+``staging.summary()`` / ``staging.entries()`` and
+``ParallelTrainer.staging_summary()`` read it in any run; the registry
+gets the series only while telemetry is enabled. The trainer's phases
+are ``jax.profiler.TraceAnnotation`` spans, always on, which the record
+keeps too: ``paddle_tpu.trainer.init_state``, ``paddle_tpu.trainer.build``
+and ``paddle_tpu.trainer.make_step`` around its construction, beside
+``paddle_tpu.trainer.stage`` and ``paddle_tpu.trainer.launch`` around
+each step.
+
 Multi-host merge: ``telemetry.aggregate.gather_registries()`` allgathers
 every process's ``Registry.to_dict()`` and merges on rank 0 with
 ``process_index`` labels (per-host series stay distinct, so straggler
@@ -258,7 +287,7 @@ from .scope import TelemetryScope, scope  # noqa: F401
 __all__ = [
     "Counter", "Gauge", "Histogram", "Registry", "DEFAULT_BUCKETS",
     "scope", "TelemetryScope", "aggregate", "tracing", "flight", "slo",
-    "calibration",
+    "calibration", "staging",
     "enable", "disable", "enabled", "is_enabled",
     "get_registry", "counter", "gauge", "histogram",
     "prometheus_text", "emit", "peak_flops_per_sec", "published_peak",
@@ -331,6 +360,8 @@ from . import calibration  # noqa: E402,F401
 from . import flight  # noqa: E402,F401
 from . import slo  # noqa: E402,F401
 from . import tracing  # noqa: E402,F401
+# the one listener on jax's staging events, installed here and always on
+from . import staging  # noqa: E402,F401
 
 
 # Published per-chip peaks keyed by ``jax.devices()[0].device_kind`` — the
