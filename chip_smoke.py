@@ -267,11 +267,13 @@ def phase_train(run: Run):
 
     from paddle_tpu import telemetry
     from paddle_tpu.ops.pallas import tuner
+    from paddle_tpu.telemetry import staging
     from tools._mesh_setup import data_mesh
 
     jax, cfg = run.jax, run.cfg
     ids, labels = _batch(cfg, cfg["batch"])
     with telemetry.scope(profile=False) as tel:
+        step_programs_before = staging.programs("train_step")
         trainer = _gpt_trainer(cfg, data_mesh(1))
         steps = _timed_steps(jax, trainer, ids, labels, 2)
         warm = time.time()
@@ -328,6 +330,13 @@ def phase_train(run: Run):
     check(losses[-1] < losses[0], f"loss did not fall: {losses}")
     check(not compiled_late,
           f"compilations after step 2, by function: {compiled_late}")
+    # one batch shape, one program: the state goes into the first call as
+    # the pytree the step hands back (ParallelTrainer._init_state)
+    mine = staged["train_step"]["programs"] - step_programs_before
+    calls = staged["train_step"]["staged_in_steps"][-mine:] if mine else []
+    check(calls == [1],
+          f"the step was staged in train_step calls {calls}, not in the "
+          f"first alone")
     if not run.rehearsal:
         # off the TPU the gate routes attention to XLA by design
         _check_flash_calls(kernels, cfg["layers"])

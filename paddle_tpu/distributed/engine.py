@@ -22,7 +22,6 @@ XLA's latency-hiding scheduler (replacing reducer.cc:798's manual overlap).
 from __future__ import annotations
 
 import time
-from collections import OrderedDict
 from functools import partial
 from typing import Callable, Optional
 
@@ -256,24 +255,27 @@ class ParallelTrainer:
         return p.pspec if p.pspec is not None else P()
 
     def _init_state(self):
+        # Every container of the state and of the specs that travel with
+        # it is a plain dict, the node type the staged step returns: the
+        # first train_step call then passes the pytree every later call
+        # passes, and jit stages one program of the step, not two. The
+        # dicts keep named_parameters' order for whoever iterates them
+        # (the exchange's buckets); jit flattens a dict by sorted key.
         params, buffers = state_of(self.model)
-        boxes = OrderedDict(self.model.named_parameters())
-        self.param_specs = OrderedDict(
-            (n, self._param_spec(n, boxes[n])) for n in params)
+        boxes = dict(self.model.named_parameters())
+        self.param_specs = {n: self._param_spec(n, boxes[n]) for n in params}
         # buffers default replicated; models may pin specific buffers to a
         # mesh axis (pipe-stacked stage buffers, pp_layers.buffer_pspecs)
         bspecs = (self.model.named_buffer_pspecs()
                   if hasattr(self.model, "named_buffer_pspecs") else {})
-        self.buffer_specs = OrderedDict(
-            (n, bspecs.get(n, P())) for n in buffers)
-        self.trainable = OrderedDict((n, boxes[n].trainable) for n in params)
+        self.buffer_specs = {n: bspecs.get(n, P()) for n in buffers}
+        self.trainable = {n: boxes[n].trainable for n in params}
         # ParamAttr(learning_rate=) multipliers, as Optimizer.step honours
         # them; where every one is 1 the staged update is what it was
         self.lr_scales = {n: boxes[n].optimize_attr["learning_rate"]
                           for n in params if boxes[n].optimize_attr.get(
                               "learning_rate", 1.0) != 1.0} or None
-        tparams = OrderedDict((k, v) for k, v in params.items()
-                              if self.trainable[k])
+        tparams = {k: v for k, v in params.items() if self.trainable[k]}
         opt_state = self.optimizer.init_state(tparams)
         # place params/opt on the mesh. Copy: the step donates these buffers,
         # and the Layer's Parameters (or another trainer) may alias them.
@@ -320,10 +322,9 @@ class ParallelTrainer:
                 for d, ax in enumerate(spec):
                     if ax == "sharding":
                         self.zero2_dims[k] = d
-        params = OrderedDict((k, put(v, self.param_specs[k]))
-                             for k, v in params.items())
-        buffers = OrderedDict((k, put(v, self.buffer_specs[k]))
-                              for k, v in buffers.items())
+        params = {k: put(v, self.param_specs[k]) for k, v in params.items()}
+        buffers = {k: put(v, self.buffer_specs[k])
+                   for k, v in buffers.items()}
         self.opt_specs = self._slot_specs(opt_state, params, n_shard)
         opt_state = jax.tree_util.tree_map(
             lambda v, s: put(v, s), opt_state, self.opt_specs)
@@ -550,13 +551,9 @@ class ParallelTrainer:
         self.integrity_axes = live_axes
         entries = []
         for part, vals, specs in (
-                # plain-dict forms, matching exactly what the check
-                # shard_map is called with (flatten order must agree)
-                ("params", dict(self.state["params"]),
-                 dict(self.param_specs)),
+                ("params", self.state["params"], self.param_specs),
                 ("opt", self.state["opt"], self.opt_specs),
-                ("comm_err", dict(self.state["comm_err"]),
-                 dict(self.comm_err_specs))):
+                ("comm_err", self.state["comm_err"], self.comm_err_specs)):
             spec_list = []
             jax.tree_util.tree_map(
                 lambda v, s: spec_list.append(s), vals, specs)
@@ -844,9 +841,8 @@ class ParallelTrainer:
                 return P(*spec)
             return self.param_specs[k]
 
-        tspecs = OrderedDict((k, _grad_spec(k))
-                             for k in self.param_specs
-                             if self.trainable[k])
+        tspecs = {k: _grad_spec(k) for k in self.param_specs
+                  if self.trainable[k]}
 
         opt = self.optimizer
 
@@ -884,10 +880,10 @@ class ParallelTrainer:
             # every step).
             sharded_grads = shard_map(
                 grads_fn, mesh=mesh,
-                in_specs=(dict(self.param_specs), dict(self.buffer_specs),
-                          dict(self.comm_err_specs), P(), P(), input_specs,
+                in_specs=(self.param_specs, self.buffer_specs,
+                          self.comm_err_specs, P(), P(), input_specs,
                           label_specs),
-                out_specs=(P(), dict(tspecs), dict(self.comm_err_specs)),
+                out_specs=(P(), tspecs, self.comm_err_specs),
                 check_vma=False)
 
             nan_guard = self.nan_guard
@@ -897,8 +893,8 @@ class ParallelTrainer:
             if do_check and self._integrity_entries:
                 check_map = shard_map(
                     self._integrity_check_fn, mesh=mesh,
-                    in_specs=(dict(self.param_specs), self.opt_specs,
-                              dict(self.comm_err_specs)),
+                    in_specs=(self.param_specs, self.opt_specs,
+                              self.comm_err_specs),
                     out_specs=(P(), P()), check_vma=False)
 
             def train_step(params, buffers, opt_state, comm_err, guard,
@@ -926,8 +922,8 @@ class ParallelTrainer:
                         ins_i, lbs_i = jax.tree_util.tree_map(
                             lambda x: x[i], chunk)
                         l_i, g_i, comm_err = sharded_grads(
-                            dict(params), dict(buffers), dict(comm_err),
-                            scale, keys[i], ins_i, lbs_i)
+                            params, buffers, comm_err, scale, keys[i],
+                            ins_i, lbs_i)
                         loss = loss + l_i / K
                         grads = g_i if grads is None else \
                             jax.tree_util.tree_map(
@@ -935,8 +931,8 @@ class ParallelTrainer:
                     grads = jax.tree_util.tree_map(lambda g: g / K, grads)
                 else:
                     loss, grads, comm_err = sharded_grads(
-                        dict(params), dict(buffers), dict(comm_err),
-                        scale, key, inputs, labels)
+                        params, buffers, comm_err, scale, key, inputs,
+                        labels)
                 if grads:
                     # fault-injection surface: poison ONE grad leaf
                     k0 = next(iter(grads))
@@ -969,7 +965,7 @@ class ParallelTrainer:
                                 return jax.tree_util.tree_map(
                                     lambda n, o: jnp.where(finite, n, o),
                                     new, old)
-                            new_params = keep(new_params, dict(params))
+                            new_params = keep(new_params, params)
                             new_opt = keep(new_opt, opt_state)
                             comm_err = keep(comm_err, comm_err0)
                             new_guard["skipped"] = guard["skipped"] + \
@@ -983,8 +979,7 @@ class ParallelTrainer:
                 # an empty pytree output, so both programs unpack alike.
                 integ = None
                 if check_map is not None:
-                    integ = check_map(dict(new_params), new_opt,
-                                      dict(comm_err))
+                    integ = check_map(new_params, new_opt, comm_err)
                 return loss, new_params, new_opt, comm_err, new_guard, \
                     integ
 
@@ -1214,19 +1209,7 @@ class ParallelTrainer:
             leaves, treedef = jax.tree_util.tree_flatten(part)
             if spec_tree is None:
                 return [P()] * len(leaves)
-            try:
-                return list(treedef.flatten_up_to(spec_tree))
-            except ValueError:
-                # node-type mismatch (dict vs OrderedDict spec trees):
-                # align per leaf by key path, in part's own flatten order
-                paths = jax.tree_util.tree_flatten_with_path(part)[0]
-                out = []
-                for path, _ in paths:
-                    node = spec_tree
-                    for e in path:
-                        node = node[e.key if hasattr(e, "key") else e.idx]
-                    out.append(node)
-                return out
+            return list(treedef.flatten_up_to(spec_tree))
         specs = []
         specs += flat(self.state["params"], self.param_specs)
         specs += flat(self.state["buffers"], self.buffer_specs)
@@ -1579,7 +1562,7 @@ class ParallelTrainer:
             value, NamedSharding(self.mesh, spec))
 
     def sync_to_model(self):
-        boxes = OrderedDict(self.model.named_parameters())
+        boxes = dict(self.model.named_parameters())
         for n, v in self.state["params"].items():
             if n in boxes:
                 boxes[n].value = v

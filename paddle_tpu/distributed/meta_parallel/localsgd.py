@@ -18,13 +18,13 @@ localsgd_optimizer.py:425) never recompiles.
 """
 from __future__ import annotations
 
-from collections import OrderedDict
 from typing import Callable
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ...framework.random import get_rng_key
@@ -34,6 +34,11 @@ from ..compressed import (DEFAULT_BUCKET_BYTES, GRAD_SYNC_POLICIES,
 from ..mesh import require_mesh
 
 shard_map = jax.shard_map
+
+
+def _replica_major(ndim: int) -> P:
+    """Spec of a replica-major ``(D, *shape)`` array of rank ``ndim``."""
+    return P("data", *([None] * (ndim - 1)))
 
 
 class LocalSGDTrainer:
@@ -86,46 +91,51 @@ class LocalSGDTrainer:
         self._build()
 
     def _init_state(self):
+        # plain dicts throughout, the containers the staged programs
+        # return: the first call of each passes the pytree every later
+        # call passes, and jit stages each program once
+        # (ParallelTrainer._init_state)
         params, buffers = state_of(self.model)
-        boxes = OrderedDict(self.model.named_parameters())
-        self.trainable = OrderedDict((n, boxes[n].trainable)
-                                     for n in params)
-        tparams = OrderedDict((k, v) for k, v in params.items()
-                              if self.trainable[k])
+        boxes = dict(self.model.named_parameters())
+        self.trainable = {n: boxes[n].trainable for n in params}
+        tparams = {k: v for k, v in params.items() if self.trainable[k]}
         opt_state = self.optimizer.init_state(tparams)
 
         def rep(v):  # replica-major: (D, *shape) sharded over "data"
             tiled = jnp.broadcast_to(v[None], (self.ndata,) + v.shape)
             return jax.device_put(
-                tiled, NamedSharding(self.mesh,
-                                     P("data", *([None] * v.ndim))))
+                tiled, NamedSharding(self.mesh, _replica_major(tiled.ndim)))
 
         # replicate the SLOTS per replica (they diverge between syncs);
         # the step counter stays a shared scalar — replicating it breaks
         # Adam-family bias correction broadcasting ((D,) vs (D, *shape))
-        rep_opt = dict(opt_state)
+        # (on the mesh like every leaf the programs hand back: a scalar
+        # left uncommitted would be another input type in the second call)
+        rep_sh = NamedSharding(self.mesh, P())
+        rep_opt = jax.tree_util.tree_map(
+            lambda v: jax.device_put(v, rep_sh),
+            {k: v for k, v in opt_state.items() if k != "slots"})
         rep_opt["slots"] = jax.tree_util.tree_map(
             rep, opt_state.get("slots", {}))
         self.state = {
-            "params": OrderedDict((k, rep(v)) for k, v in tparams.items()),
-            "frozen": OrderedDict((k, v) for k, v in params.items()
-                                  if not self.trainable[k]),
-            "buffers": buffers,
+            "params": {k: rep(v) for k, v in tparams.items()},
+            "frozen": {k: v for k, v in params.items()
+                       if not self.trainable[k]},
+            "buffers": dict(buffers),
             "opt": rep_opt,
         }
         # anchor = the last-synced params, identical on every replica (each
         # sync ends with all replicas on the same point); replicated
         # storage. The int8 residual is per-replica. Both empty for the
         # exact fp32 path.
-        rep_sh = NamedSharding(self.mesh, P())
-        self.state["anchor"] = (OrderedDict(
-            (k, jax.device_put(jnp.asarray(v), rep_sh))
-            for k, v in tparams.items())
-            if self.param_sync != "fp32" else OrderedDict())
+        self.state["anchor"] = (
+            {k: jax.device_put(jnp.asarray(v), rep_sh)
+             for k, v in tparams.items()}
+            if self.param_sync != "fp32" else {})
         self.state["sync_err"] = (
-            OrderedDict((k, rep(jnp.zeros(jnp.shape(v), jnp.float32)))
-                        for k, v in tparams.items())
-            if self.param_sync in QUANTIZED_POLICIES else OrderedDict())
+            {k: rep(jnp.zeros(jnp.shape(v), jnp.float32))
+             for k, v in tparams.items()}
+            if self.param_sync in QUANTIZED_POLICIES else {})
 
     def _build(self):
         mesh = self.mesh
@@ -153,7 +163,7 @@ class LocalSGDTrainer:
             # the no-sync program, which must contain none.
             return loss[None], {k: g[None] for k, g in grads.items()}
 
-        pspec = {k: P("data", *([None] * (v.ndim - 1)))
+        pspec = {k: _replica_major(v.ndim)
                  for k, v in self.state["params"].items()}
         sharded_grads = shard_map(
             grads_fn, mesh=mesh,
@@ -196,21 +206,25 @@ class LocalSGDTrainer:
 
             def train_step(params, frozen, buffers, opt_state, anchor,
                            sync_err, key, lr, inputs, labels):
-                loss, grads = sharded_grads(dict(params), dict(frozen),
-                                            dict(buffers), key, inputs,
-                                            labels)
-                new_p, new_opt = opt.apply_gradients(dict(params), grads,
+                loss, grads = sharded_grads(params, frozen, buffers, key,
+                                            inputs, labels)
+                new_p, new_opt = opt.apply_gradients(params, grads,
                                                      opt_state, lr=lr)
                 if do_sync:
                     # average params (and moments) over replicas — XLA
-                    # inserts the cross-replica all-reduce here
+                    # inserts the cross-replica all-reduce here. The mean
+                    # leaves replica-major as it came: left to XLA it
+                    # leaves replicated, D copies a device, and the local
+                    # program is staged again for that input
                     def avg(v):
-                        return jnp.broadcast_to(
-                            jnp.mean(v, axis=0, keepdims=True), v.shape)
+                        return lax.with_sharding_constraint(
+                            jnp.broadcast_to(
+                                jnp.mean(v, axis=0, keepdims=True), v.shape),
+                            NamedSharding(mesh, _replica_major(v.ndim)))
 
                     if sharded_sync is not None:
                         new_p, anchor, sync_err = sharded_sync(
-                            dict(new_p), dict(anchor), dict(sync_err))
+                            new_p, anchor, sync_err)
                     else:
                         new_p = {k: avg(v) for k, v in new_p.items()}
                     new_opt = dict(new_opt)

@@ -358,18 +358,17 @@ class TestEngine:
     def test_model_walks_through_trainer(self, build):
         """The model families no benchmark cell trains still meet
         ParallelTrainer: five steps on one fixed batch, loss finite and
-        falling, nothing staged or compiled after the second step."""
+        falling, one program staged in the first step and none after."""
         from paddle_tpu import telemetry
         make_mesh(data=1)
         paddle.seed(0)
         with telemetry.scope(profile=False) as tel:
             tr, inputs, labels = getattr(self, build)()
-            losses = [float(tr.train_step(inputs, labels))
-                      for _ in range(2)]
-            compiled = tel.registry.get("recompiles_total").value()
+            losses = [float(tr.train_step(inputs, labels))]
+            assert tel.registry.get("recompiles_total").value() == 1
             losses += [float(tr.train_step(inputs, labels))
-                       for _ in range(3)]
-            assert tel.registry.get("recompiles_total").value() == compiled
+                       for _ in range(4)]
+            assert tel.registry.get("recompiles_total").value() == 1
         assert np.all(np.isfinite(losses)), losses
         assert losses[-1] < losses[0], losses
         if tr.scaler is not None:

@@ -442,12 +442,10 @@ class TestNanGuard:
     def test_taint_flip_does_not_recompile(self):
         tr = _mlp_trainer()
         x, y = _xy()
-        # two warmup steps: the 1st→2nd call transition recompiles once
-        # (donated-output layout), independent of the guard
-        tr.train_step(x, y)
         tr.train_step(x, y)
         step = tr._step_cache[tr._last_cache_key]
         n0 = step._cache_size()
+        assert n0 == 1
         tr.train_step(x, y, grad_taint=float("nan"))
         tr.train_step(x, y, grad_taint=1.0)
         tr.train_step(x, y)
