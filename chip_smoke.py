@@ -237,6 +237,23 @@ def _linear_attn_calls(registry):
     return out
 
 
+def _latent_attn_calls(registry):
+    """``latent_attn_calls_staged_total`` by the queries' rotation, the
+    rotations and flash tiles those calls staged and the two loss gauges
+    (``publish_losses``'s), as the registry holds them."""
+    out = {"rope_calls_staged": _rope_calls(registry),
+           "flash_tiles_staged": _flash_tiles(registry)}
+    staged = registry.get("latent_attn_calls_staged_total")
+    if staged:
+        out["calls"] = {path: int(staged.value(rope=path))
+                        for path in ("pallas", "xla")}
+    flat = registry.to_dict()
+    for name in ("mtp_main_loss", "mtp_next_loss"):
+        if name in flat:
+            out[name] = flat[name]["series"]
+    return out
+
+
 def _config_origins(run, entries):
     """Where each kernel config used here resolves from. A tuning-DB
     file outside the checkout would make the run depend on what an
@@ -379,6 +396,9 @@ def phase_kernels(run: Run):
         linear_attn = _linear_attn_calls(tel.registry)
         linear_rope_calls = _rope_calls(tel.registry)
     checks += ns.check_gated_delta_precision(run.interpret)
+    with telemetry.scope(profile=False) as tel:
+        checks += ns.check_latent_attention(run.interpret)
+        latent = _latent_attn_calls(tel.registry)
     checks += (ns.check_flash_attention(run.interpret)
                + ns.check_fused_ce(run.interpret, **ce)
                + ns.check_paged_attention(run.interpret, **paged))
@@ -396,7 +416,8 @@ def phase_kernels(run: Run):
             interpret=run.interpret, config_origins=origins,
             flash_tiles_staged=flash_tiles, rope_calls_staged=rope_calls,
             linear_attn_staged=linear_attn,
-            linear_model_rope_calls_staged=linear_rope_calls)
+            linear_model_rope_calls_staged=linear_rope_calls,
+            latent_attn_staged=latent)
     bad = [c["check"] for c in checks if not c["ok"]]
     check(not bad, f"kernel checks out of tolerance: {bad}")
     # on the chip check_rope goes through F.rotary_embedding: two cases
@@ -422,6 +443,28 @@ def phase_kernels(run: Run):
                   for n in calls.values()) == 2,
           f"the gated full layer's rotary_embedding staged as "
           f"{linear_rope_calls}, expected 2 calls with norm=1 on {path}")
+    # check_latent_attention: the trunk's block and the module's, each
+    # staged once on the kernels' path (q through the rotary kernel, the one
+    # rotary key head through XLA) and once more for the float32 reference,
+    # whose rotations are all XLA's
+    path = "xla" if run.rehearsal else "pallas"
+    want = {"pallas": 0, "xla": 2}
+    want[path] += 2
+    check(latent.get("calls") == want,
+          f"latent attention staged as {latent.get('calls')}, expected "
+          f"{want}")
+    rope = latent["rope_calls_staged"]
+    check(rope.get("pallas", {}).get("norm=0") == (0 if run.rehearsal else 2)
+          and sum(n for calls in rope.values() for n in calls.values()) == 8,
+          f"the latent layers' rotary_embedding staged as {rope}: expected "
+          f"8 calls, 2 of them (q on the chip's path) by the kernels")
+    check(run.rehearsal or all(
+        sum(kinds.values()) > 0
+        for kinds in latent["flash_tiles_staged"].values()),
+        f"the latent layers staged no flash tiles: "
+        f"{latent['flash_tiles_staged']}")
+    check(set(latent) >= {"mtp_main_loss", "mtp_next_loss"},
+          f"the loss gauges were not published: {sorted(latent)}")
     # the three geometries of check_flash_tile_kinds, (dense, triangular,
     # masked): one 1024-row tile; two of them on the diagonal and one dense;
     # 4 tiles of 512 rows on the diagonal and 3 band edges. A triangle is

@@ -192,11 +192,15 @@ class MoELayer(Layer):
 # ---------------------------------------------------------------------------
 # dropless top-k layer over held experts
 # ---------------------------------------------------------------------------
-def route_top_k(logits, top_k, scoring="sigmoid", scaling_factor=1.0):
+def route_top_k(logits, top_k, scoring="sigmoid", scaling_factor=1.0,
+                selection_bias=None):
     """``(expert ids int32 (T, k), weights float32 (T, k))`` from float32
     router logits ``(T, E)``: scores are ``sigmoid`` or ``softmax`` of the
     logits, the ``top_k`` largest are chosen, and their weights are the
-    scores over their sum, times ``scaling_factor``."""
+    scores over their sum, times ``scaling_factor``. With
+    ``selection_bias`` ``(E,)`` (auxiliary-loss-free balancing,
+    ``topk_method: noaux_tc``) the experts are chosen by ``scores + bias``
+    and weighted by their ``scores`` alone; no gradient reaches the bias."""
     logits = logits.astype(jnp.float32)
     if scoring == "sigmoid":
         scores = jax.nn.sigmoid(logits)
@@ -204,7 +208,13 @@ def route_top_k(logits, top_k, scoring="sigmoid", scaling_factor=1.0):
         scores = jax.nn.softmax(logits, axis=-1)
     else:
         raise ValueError(f"unknown router scoring {scoring!r}")
-    top, ids = lax.top_k(scores, top_k)
+    if selection_bias is None:
+        top, ids = lax.top_k(scores, top_k)
+    else:
+        # only the ids read the bias, so no gradient reaches it
+        _, ids = lax.top_k(scores + selection_bias.astype(jnp.float32),
+                           top_k)
+        top = jnp.take_along_axis(scores, ids, axis=-1)
     top = top / jnp.sum(top, axis=-1, keepdims=True)
     return ids.astype(jnp.int32), top * scaling_factor
 
@@ -292,12 +302,18 @@ class DroplessMoELayer(Layer):
     moves a fresh router a full step whatever its gradient, and at a rate
     the rest of a model trains at, the held experts' load runs to nothing or
     to twice its expectation within twenty steps (PERF.md section 6, PRs 27
-    and 31).
+    and 31). ``selection_bias=True`` registers ``e_score_correction_bias``
+    ``(num_experts,)`` float32, zeros: a persistable buffer and not a
+    parameter (it is in ``state_dict`` and in a trainer's state, and has no
+    gradient and no optimizer slot), which ``route_top_k`` adds to the
+    scores to choose the experts and leaves out of their weights. Nothing
+    here changes it: the balancing step that would is a training recipe.
     """
 
     def __init__(self, d_model, d_expert, num_experts, top_k, held=None,
                  routed_scaling_factor=1.0, scoring="sigmoid", d_shared=None,
-                 router_attr=None, shared_expert_gate=False):
+                 router_attr=None, shared_expert_gate=False,
+                 selection_bias=False):
         super().__init__()
         first, count = held if held is not None else (0, num_experts)
         if not (0 <= first and count >= 1 and first + count <= num_experts):
@@ -317,6 +333,9 @@ class DroplessMoELayer(Layer):
             Linear(d_model, 1, bias_attr=False)
             if shared_expert_gate and d_shared else None)
         self.experts = GroupedExperts(count, d_model, d_expert)
+        if selection_bias:
+            self.register_buffer("e_score_correction_bias",
+                                 jnp.zeros((num_experts,), jnp.float32))
         for name, dtype in (("tokens_routed", jnp.int32),
                             ("held_assignments", jnp.int32),
                             ("max_load_over_mean", jnp.float32)):
@@ -343,8 +362,9 @@ class DroplessMoELayer(Layer):
                 tokens, self.router.weight.value.astype(tokens.dtype),
                 (((1,), (0,)), ((), ())), precision=lax.Precision.HIGHEST,
                 preferred_element_type=jnp.float32)
-            return route_top_k(logits, self.top_k, self.scoring,
-                               self.routed_scaling_factor)
+            return route_top_k(
+                logits, self.top_k, self.scoring, self.routed_scaling_factor,
+                self._buffers.get("e_score_correction_bias"))
 
     def forward(self, x):
         shape = x.shape

@@ -112,6 +112,16 @@ def _count_staged(path: str, norm: bool):
                 1, path=path, norm=int(norm))
 
 
+def rotary_path(x, inv_freq) -> str:
+    """``"pallas"`` or ``"xla"``: the path ``rotary_embedding`` takes for
+    ``x`` (batch, seq, heads, head_dim), read from the input and the
+    backend as the docstring there says."""
+    from ...ops.pallas import rotary as kernel
+    pallas = jax.default_backend() == "tpu" and x.ndim == 4 \
+        and kernel.supported(x.shape, x.dtype, len(inv_freq))
+    return "pallas" if pallas else "xla"
+
+
 def rotary_embedding(x, inv_freq, scale: float = 1.0, positions=None,
                      norm_weight=None, epsilon: float = 1e-6):
     """Rotate the first ``2 * len(inv_freq)`` lanes of ``x`` (batch, seq,
@@ -148,10 +158,9 @@ def rotary_embedding(x, inv_freq, scale: float = 1.0, positions=None,
     with jax.named_scope("rope"):
         half, d = len(inv_freq), x.shape[-1]
         cos, sin = rope_tables(inv_freq, scale, positions, x.shape[1], d)
-        pallas = jax.default_backend() == "tpu" and x.ndim == 4 \
-            and kernel.supported(x.shape, x.dtype, half)
-        _count_staged("pallas" if pallas else "xla", norm_weight is not None)
-        if pallas:
+        path = rotary_path(x, inv_freq)
+        _count_staged(path, norm_weight is not None)
+        if path == "pallas":
             return kernel.rotary(x, cos, sin, half, norm_weight, epsilon)
         if norm_weight is not None:
             # a float32 scale (a zero-centred norm's 1 + w) on bf16 heads:

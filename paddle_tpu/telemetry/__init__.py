@@ -86,6 +86,10 @@ linear_attn_calls_staged_total counter    nn.functional.gated_delta_rule,
                                           it took {path=pallas (the
                                           kernels)|chunked (XLA's batched
                                           products)|recurrent}
+latent_attn_calls_staged_total counter    text.models MultiHeadLatent
+                                          Attention, where a call is staged:
+                                          the path the rotation of its
+                                          queries took {rope=pallas|xla}
 gated_delta_chunks_total       counter    chunk states a row of those calls
                                           walks one after another (seq /
                                           chunk; seq on the recurrent path)
@@ -101,6 +105,13 @@ block_diffusion_masked_share   gauge      text.models MixedDecoderFor
                                           BlockDiffusion.publish_noise:
                                           share of a step's clean tokens
                                           the noise masked, last call
+mtp_main_loss                  gauge      text.models MixedDecoderFor
+                                          Pretraining.publish_losses: the
+                                          next-token cross entropy of the
+                                          last call, unweighted
+mtp_next_loss                  gauge      same: the token-after-next term
+                                          (the multi-token-prediction
+                                          module's), unweighted
 retries_total                  counter    resilience.retry {site=...}
 retry_exhausted_total          counter    resilience.retry {site=...}
 retry_bytes_abandoned_total    counter    resilience.retry byte budget

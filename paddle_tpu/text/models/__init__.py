@@ -10,4 +10,5 @@ from .transformer import (  # noqa: F401
     InferTransformerModel, TransformerModel, position_encoding_init)
 from .mixed_decoder import (  # noqa: F401
     GroupedQueryAttention, MixedDecoderBlock, MixedDecoderForBlockDiffusion,
-    MixedDecoderForPretraining, MixedDecoderModel)
+    MixedDecoderForPretraining, MixedDecoderModel, MultiHeadLatentAttention,
+    MultiTokenPrediction)

@@ -198,6 +198,27 @@ def test_flash_at_head_width_256_in_the_tuning_dbs_blocks(v5e):
     assert "s32[32]" in text and "bf16[32,8192,256]" in text
 
 
+def test_flash_at_20_ungrouped_heads_of_width_256(v5e):
+    """glm-4.7-flash.seq4096's six latent-attention layers: rows of 4,096
+    positions, 20 query heads over 20 key/value heads (no grouping: each is
+    the up-projection's own) at head width 256, in the blocks the lookup
+    resolves for that shape. The three kernels lower and fit."""
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+
+    def f(q, k, v):
+        return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True).astype(jnp.float32) ** 2),
+            argnums=(0, 1, 2))(q, k, v)
+
+    qkv = ((4, 4096, 20, 256), jnp.bfloat16)
+    text = _compile(f, v5e, qkv, qkv, qkv)
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert any("tpu_custom_call" in line and f"%{kernel}" in line
+                   for line in text.splitlines()), kernel
+    # the operands the benchmark's flash_attn_ms_per_step finds them by
+    assert "s32[80]" in text and "bf16[80,4096,256]" in text
+
+
 @pytest.mark.parametrize("block, non_power", [(512, False), (1024, False),
                                               (384, True)],
                          ids=["512", "1024", "384_block_length_12"])
@@ -275,8 +296,11 @@ def test_flash_grouped_heads_at_width_64(v5e, heads, kv_heads, kw):
     ((2, 8192, 32, 128), 4, 128, True),    # sdar-30b-a3b-chat: with QK norm
     ((2, 8192, 16, 256), 2, 64, True),     # qwen3-next: two lane blocks a
                                            # head, a quarter of them rotated
+    ((4, 4096, 20, 256), 20, 64, False),   # glm-4.7-flash: the queries of a
+                                           # latent layer, [rope | nope]
 ], ids=["sliding_64_and_8", "full_48_and_8_half_rotated",
-        "sdar_32_and_4_norm", "qwen3next_16_and_2_norm_d256"])
+        "sdar_32_and_4_norm", "qwen3next_16_and_2_norm_d256",
+        "glm47flash_20_latent_d256"])
 def test_rope_kernels_at_the_mixed_decoder_shapes(v5e, shape, kv_heads,
                                                   rotated, norm):
     """QK norm and RoPE of q and k as the two mixed-decoder cells stage

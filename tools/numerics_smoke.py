@@ -369,6 +369,99 @@ def check_gated_delta_precision(interpret):
                        for n, t in GATED_DELTA_PRECISION_TOL.items())}]
 
 
+# ``check_latent_attention``'s limits: the loss as a relative difference,
+# the gradients as |g - g_ref| / |g_ref| in the 2-norm, worst leaf and
+# median leaf, the program in bf16 on the chip's kernels against the same
+# model in float32 on XLA's paths at ``Precision.HIGHEST``. The chip read
+# 2.0e-5, 0.184 (the module's router) and 0.0215 (my chip run, PR 37); the
+# cell's comparison, which has a float8 control, keeps 1.2e-4, 0.5, 0.04.
+LATENT_TOL = {"loss": 2e-4, "worst_leaf": 0.5, "median_leaf": 0.04}
+
+
+def check_latent_attention(interpret):
+    """One latent-attention block with its multi-token-prediction module at
+    the GLM-4.7-Flash cell's widths (hidden 2,048, 20 heads of 192 + 64
+    lanes from latents of 768 and 512, a sigmoid router of 64 choosing 4 by
+    its bias with 8 held and a shared expert, experts 1,536 wide; two rows
+    of 1,024 positions, bf16, a vocabulary of 4,096; rehearsed on the CPU
+    at toy widths in float32): the model's two-term loss and every gradient
+    leaf through the path a step takes (on the chip the flash kernels and
+    the rotary kernel for q) against the same weights in float32 on XLA's
+    attention and rotation at ``Precision.HIGHEST``. The two loss terms are
+    published as ``publish_losses`` does; what path the rotation and the
+    attention took is for the caller to read from the counters."""
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu.jit.functionalization import functional_call, state_of
+    from paddle_tpu.text.models import MixedDecoderForPretraining
+
+    if interpret:
+        sizes = dict(hidden=64, heads=4, inter=128, expert=32, seq=128,
+                     vocab=512, experts=(16, 4), dtype="float32",
+                     latent=dict(q_lora_rank=24, kv_lora_rank=16,
+                                 qk_nope_head_dim=12, qk_rope_head_dim=4,
+                                 v_head_dim=16))
+    else:
+        sizes = dict(hidden=2048, heads=20, inter=10240, expert=1536,
+                     seq=1024, vocab=4096, experts=(64, 8), dtype="bfloat16",
+                     latent=dict(q_lora_rank=768, kv_lora_rank=512,
+                                 qk_nope_head_dim=192, qk_rope_head_dim=64,
+                                 v_head_dim=256))
+    paddle.seed(37)
+    model = MixedDecoderForPretraining(
+        mtp_layers=1, mtp_loss_weight=0.3, vocab_size=sizes["vocab"],
+        hidden_size=sizes["hidden"], layer_types=["latent_attention"],
+        heads_per_layer=[sizes["heads"]], mlp_layer_types=["sparse"],
+        kv_heads=None, head_dim=None,
+        rope={"latent_attention": {"theta": 1e6}}, sliding_window=None,
+        intermediate_size=sizes["inter"], num_experts=sizes["experts"][0],
+        experts_per_token=4, expert_size=sizes["expert"],
+        shared_expert_size=sizes["expert"],
+        held_experts=(0, sizes["experts"][1]), routed_scaling_factor=1.8,
+        router_selection_bias=True, epsilon=1e-5, checkpoint_blocks=True,
+        latent_attention=sizes["latent"],
+        embedding_attr=paddle.nn.initializer.Normal(0.0, 1.0))
+    model.astype(sizes["dtype"])
+    params = dict(state_of(model)[0])
+    tokens = jax.random.randint(jax.random.key(38), (2, sizes["seq"] + 1), 0,
+                                sizes["vocab"])
+    batch = (tokens[:, :-1], tokens[:, 1:])
+
+    def loss(p):
+        # buffers None: the layers' own go in, all of them come out
+        return functional_call(model, p, None, batch, rng=jax.random.key(0))
+
+    step = jax.value_and_grad(loss, has_aux=True)
+    (value, buffers), grads = jax.jit(step)(params)
+    model.publish_losses(buffers)
+    # the same weights in float32 where the CPU's gates leave attention and
+    # the rotation: XLA's, every product at the highest precision
+    with mock.patch.object(jax, "default_backend", lambda: "cpu"), \
+            jax.default_matmul_precision("highest"):
+        (want, _), ref = jax.jit(step)(jax.tree_util.tree_map(
+            lambda x: x.astype(jnp.float32), params))
+    rel = {k: float(jnp.linalg.norm((grads[k].astype(jnp.float32) - ref[k])
+                                    .ravel())
+                    / jnp.linalg.norm(ref[k].ravel())) for k in ref}
+    worst = max(rel, key=rel.get)
+    got = {"loss": abs(float(value) - float(want)) / abs(float(want)),
+           "worst_leaf": rel[worst],
+           "median_leaf": float(np.median(list(rel.values())))}
+    return [{"check": "latent_attention_block_and_mtp_vs_xla_highest",
+             "loss": float(value), "loss_reference": float(want),
+             "mtp_main_loss": float(buffers["mtp_main_loss"]),
+             "mtp_next_loss": float(buffers["mtp_next_loss"]),
+             "leaves": len(rel), "worst_leaf_name": worst, "rel": got,
+             "tol": LATENT_TOL,
+             "ok": all(np.isfinite(v) and v < LATENT_TOL[k]
+                       for k, v in got.items())}]
+
+
 def _max_err(got, ref):
     """(max abs error, the same over max |ref|) in fp32."""
     import jax.numpy as jnp
