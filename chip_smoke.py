@@ -23,7 +23,8 @@ Phases (each through the entry points a user calls, weights from a seed):
   (``flash_tiles_staged_total``).
 - ``kernels`` — each Pallas kernel compiled (``interpret=False``) against
   its reference: flash fwd/bwd (the geometries whose tiles are computed by
-  sub-blocks among them, with the counter's kinds), QK norm with RoPE
+  sub-blocks among them, with the counter's kinds and the steps the grids
+  skip, ``flash_steps_held_total``), QK norm with RoPE
   fwd/bwd at the two mixed-decoder cells' shapes against ``F.rms_norm``
   and the XLA formula (``rope_calls_staged_total`` says the entry point
   took the kernels), the chunked gated delta rule against the recurrence
@@ -208,6 +209,17 @@ def _flash_tiles(registry):
             for kernel in KERNEL_NAMES} if staged else {}
 
 
+def _flash_held(registry):
+    """``flash_steps_held_total`` as ``{kernel: steps}``: the grid steps of
+    a lane block that the staged flash kernels skip, their block index
+    held."""
+    from paddle_tpu.ops.pallas.flash_attention import KERNEL_NAMES
+
+    held = registry.get("flash_steps_held_total")
+    return {kernel: int(held.value(kernel=kernel))
+            for kernel in KERNEL_NAMES} if held else {}
+
+
 def _rope_calls(registry):
     """``rope_calls_staged_total`` as ``{path: {norm: calls}}``: which path
     the staged calls of ``F.rotary_embedding`` took."""
@@ -389,6 +401,7 @@ def phase_kernels(run: Run):
     with telemetry.scope(profile=False) as tel:
         checks = ns.check_flash_tile_kinds(run.interpret)
         flash_tiles = _flash_tiles(tel.registry)
+        flash_held = _flash_held(tel.registry)
         checks += ns.check_rope(run.interpret)
         rope_calls = _rope_calls(tel.registry)
     with telemetry.scope(profile=False) as tel:
@@ -414,8 +427,8 @@ def phase_kernels(run: Run):
     origins = _config_origins(run, entries)
     run.say("kernels", event="result", n_checks=len(checks),
             interpret=run.interpret, config_origins=origins,
-            flash_tiles_staged=flash_tiles, rope_calls_staged=rope_calls,
-            linear_attn_staged=linear_attn,
+            flash_tiles_staged=flash_tiles, flash_steps_held=flash_held,
+            rope_calls_staged=rope_calls, linear_attn_staged=linear_attn,
             linear_model_rope_calls_staged=linear_rope_calls,
             latent_attn_staged=latent)
     bad = [c["check"] for c in checks if not c["ok"]]
@@ -476,6 +489,13 @@ def phase_kernels(run: Run):
            for kernel, kinds in flash_tiles.items()}
     check(got == want, f"flash tiles staged as {flash_tiles}, expected "
                        f"(dense, triangular, masked) {want}")
+    # the other steps of those grids, skipped with the block index held: none
+    # of one tile; the tile over the diagonal of the 2 x 2 (for each of the 6
+    # query heads of the dk/dv kernel's group); the second step of the
+    # window's first q block and of its last key block (8 heads)
+    want = {"flash_fwd": 2, "flash_bwd_dq": 2, "flash_bwd_dkv": 6 + 8}
+    check(flash_held == want,
+          f"flash steps held as {flash_held}, expected {want}")
 
 
 def phase_serve(run: Run):

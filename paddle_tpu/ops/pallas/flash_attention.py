@@ -40,6 +40,22 @@ runs over the band of blocks a window leaves (first block from the index
 map) instead of over all of them, so blocks wholly outside the window cost
 neither a grid step nor a DMA.
 
+Hidden steps (ISSUE 38): without a window the grid of a ``causal`` call
+still walks every (q block, key block) pair, and a pair over the diagonal
+computes nothing (``run`` in the kernels). The pipeline copies an operand's
+next block whenever its block index changes, computed or not, and a step
+without arithmetic has nothing to hide a copy behind, so it used to cost
+its DMA (1.0 to 1.5 MB, 1.3 to 1.9 us on a v5e). The index maps therefore
+hold the index over such steps: the forward and dq kernels name the last
+key block their q block sees for the steps past it, the dk/dv kernel the
+first q block that sees its keys for the steps before it (q, do, lse and
+delta alike), and a window's band its last block over a short row's tail.
+One function says both whether a step is skipped and what its maps name
+(``_Band.key_step``, ``_Band.query_step``), so a step is skipped exactly
+where its fetch is held; no tile's arithmetic or order changes. What a
+hidden step still costs is the grid's own overhead (about 0.35 us); its
+number a lane block is ``flash_steps_held_total{kernel}``.
+
 Tile kinds (ISSUE 30): ``_visible`` masks scores that the MXU has already
 formed, and the two backward kernels are bound by the MXU's passes, so a
 tile half of which the causal mask throws away pays for twice what it
@@ -163,36 +179,82 @@ def _cap(x, hi):
     return min(x, hi) if isinstance(x, int) else jnp.minimum(x, hi)
 
 
+def _div(x, n):
+    """``x // n`` for ``x >= 0``, where truncation is the floor: an integer
+    ``//`` of a traced scalar stages two ``sign``s and a select (see
+    ``_Pack._slot`` for what they cost the TPU lowering)."""
+    return x // n if isinstance(x, int) else jax.lax.div(x, jnp.int32(n))
+
+
 class _Band:
-    """Which blocks of the other axis a block needs under a causal window.
+    """Which blocks of the other axis a block needs under the causal mask,
+    with a window or (``window`` None) without one.
 
     ``k_first(iq)``..``k_last(iq)`` are the key blocks q block ``iq`` sees
-    (columns ``iq*bq - window + 1 .. (iq+1)*bq - 1``), ``q_first(ik)``..
-    ``q_last(ik)`` the q blocks that see key block ``ik`` (rows ``ik*bk ..
-    (ik+1)*bk + window - 2``). They take a Python int (for the static step
-    counts ``k_steps`` and ``q_steps``, the widest band of any block) or a
-    traced scalar (index maps, kernels) alike."""
+    (columns ``iq*bq - window + 1 .. (iq+1)*bq - 1``; from column 0 without
+    a window), ``q_first(ik)``..``q_last(ik)`` the q blocks that see key
+    block ``ik`` (rows ``ik*bk .. (ik+1)*bk + window - 2``; to the last row
+    without a window; ``q_first`` lies past the last q block where ``sq <
+    sk`` leaves a key block unseen). They take a Python int or a traced
+    scalar (index maps, kernels) alike.
+
+    The grid's inner dimension walks ``k_steps`` key blocks from
+    ``k_first`` on and ``q_steps`` q blocks from ``q_first`` on under a
+    window (the widest band of any block), all of them from block 0
+    without one. ``key_step`` and ``query_step`` say where a step of
+    those walks is, whether its tile is computed, and which block its
+    index map names: the step's own where it is computed, else the nearest
+    computed one's, which is in VMEM already, so that a skipped step
+    fetches nothing. The kernels' ``run`` and the index maps read the same
+    two functions: a step is skipped exactly where its fetch is held."""
 
     def __init__(self, window, block_q, block_k, nq, nk):
         self.window, self.bq, self.bk = window, block_q, block_k
         self.nq, self.nk = nq, nk
-        self.k_steps = max(self.k_last(i) - self.k_first(i) + 1
-                           for i in range(nq))
-        self.q_steps = max(self.q_last(j) - self.q_first(j) + 1
-                           for j in range(nk))
+        self.k_steps, self.q_steps = nk, nq
+        if window is not None:
+            self.k_steps = max(self.k_last(i) - self.k_first(i) + 1
+                               for i in range(nq))
+            self.q_steps = max(self.q_last(j) - self.q_first(j) + 1
+                               for j in range(nk))
 
     def k_first(self, iq):
-        return _floor(iq * self.bq - (self.window - 1), 0) // self.bk
+        if self.window is None:
+            return 0
+        return _div(_floor(iq * self.bq - (self.window - 1), 0), self.bk)
 
     def k_last(self, iq):
-        return _cap(((iq + 1) * self.bq - 1) // self.bk, self.nk - 1)
+        return _cap(_div((iq + 1) * self.bq - 1, self.bk), self.nk - 1)
 
     def q_first(self, ik):
-        return (ik * self.bk) // self.bq
+        return _div(ik * self.bk, self.bq)
 
     def q_last(self, ik):
-        return _cap(((ik + 1) * self.bk + self.window - 2) // self.bq,
+        if self.window is None:
+            return self.nq - 1
+        return _cap(_div((ik + 1) * self.bk + self.window - 2, self.bq),
                     self.nq - 1)
+
+    def key_step(self, iq, step):
+        """Step ``step`` of q block ``iq``'s walk over the keys (forward
+        and dq): ``(ik, run, held)``. The steps past the last visible key
+        block, a row's blocks over the diagonal or a short band's tail,
+        hold its index."""
+        ik, last = self.k_first(iq) + step, self.k_last(iq)
+        return ik, ik <= last, _cap(ik, last)
+
+    def query_step(self, ik, step):
+        """Step ``step`` of key block ``ik``'s walk over the q blocks
+        (dk/dv, one query lane block of the group): ``(iq, run, held)``.
+        Without a window the walk starts at q block 0, and the steps before
+        the first q block that sees the keys hold that block's index (the
+        last block's where none does); under a window it starts there, and
+        a short band's tail holds the last."""
+        first, last = self.q_first(ik), self.q_last(ik)
+        if self.window is None:
+            return step, step >= first, _cap(_floor(step, first), last)
+        iq = first + step
+        return iq, iq <= last, _cap(iq, last)
 
 
 def _dropout_mask(shape, rate, seed, b, iq, ik, row0=0, col0=0):
@@ -401,19 +463,23 @@ class _Tiles:
       lane tiles or not of a size that pays in this kernel (``_SUB_ROWS``):
       one strip, the whole tile under the triangle's mask.
 
-    A tile no query of which sees any key is skipped as before (``run`` in
-    the kernels) and has no kind. ``counts`` is the number of tiles of each
-    kind one lane block's grid walks."""
+    A tile no query of which sees any key is skipped (``run`` in the
+    kernels, from ``band``) and has no kind. ``counts`` is the number of
+    tiles of each kind one lane block's grid walks, ``held`` the number of
+    its other steps: they compute nothing, and their index maps name the
+    block of the nearest computed tile (``_Band``)."""
 
     # scalar-prefetch tables the grid is walked by: none, the grid's
     # indices say where a step is (``walk_keys``, ``walk_queries``)
     tables = ()
 
-    def __init__(self, kernel, causal, masked_keys, block_q, block_k, nq, nk,
-                 band, square):
-        self.causal, self.masked_keys, self.band = causal, masked_keys, band
+    def __init__(self, kernel, masked_keys, block_q, block_k, nq, nk, band,
+                 square):
+        # ``band``: the causal mask's geometry, None without ``causal``
+        self.causal = causal = band is not None
+        self.masked_keys, self.band = masked_keys, band
         self.bq, self.bk = block_q, block_k
-        window = None if band is None else band.window
+        window = band.window if causal else None
         # the kinds are known from a tile's position
         self.exact = (causal and not masked_keys and square
                       and block_q == block_k
@@ -431,14 +497,15 @@ class _Tiles:
                 kind = self.kind(iq, ik)
                 if kind is not None:
                     self.counts[kind] += 1
+        k_steps, q_steps = (band.k_steps, band.q_steps) if causal else (nk, nq)
+        walked = nk * q_steps if kernel == BWD_DKV else nq * k_steps
+        self.held = walked - sum(self.counts.values())
 
     def kind(self, iq, ik):
         """The kind of tile ``(iq, ik)`` (Python ints), None if skipped."""
-        if self.band is not None:
-            run = self.band.k_first(iq) <= ik <= self.band.k_last(iq)
-        else:
-            run = ik * self.bk < (iq + 1) * self.bq or not self.causal
-        if not run:
+        band = self.band
+        if band is not None and not (band.k_first(iq) <= ik
+                                     <= band.k_last(iq)):
             return None
         if not self.exact:
             return MASKED if self.causal or self.masked_keys else DENSE
@@ -501,12 +568,9 @@ class _Tiles:
     def walk_keys(self, hb, i, step, steps, table):
         """The forward and dq kernels: grid (lane blocks, q blocks, key
         steps); under a window the steps walk the band's key blocks only."""
-        band = self.band
-        ik = step if band is None else band.k_first(i) + step
-        if band is not None:
-            run = ik <= band.k_last(i)
-        else:
-            run = (ik * self.bk < (i + 1) * self.bq) if self.causal else True
+        ik, run = step, True
+        if self.band is not None:
+            ik, run, _ = self.band.key_step(i, step)
         return (hb, i, ik, step == 0, step == steps - 1,
                 self.branches(i, ik, run))
 
@@ -514,15 +578,13 @@ class _Tiles:
         """The dk/dv kernel: grid (KV lane blocks, key blocks, the group's
         query lane blocks x the q blocks, all of them or a window's
         band)."""
-        band, jq = self.band, step
+        jq = step
         if group != 1:
             hb = hb * group + step // steps
             jq = step % steps
-        iq = jq if band is None else band.q_first(j) + jq
-        if band is not None:
-            run = iq <= band.q_last(j)
-        else:
-            run = ((iq + 1) * self.bq > j * self.bk) if self.causal else True
+        iq, run = jq, True
+        if self.band is not None:
+            iq, run, _ = self.band.query_step(j, jq)
         return (hb, iq, j, step == 0, step == group * steps - 1,
                 self.branches(iq, j, run))
 
@@ -615,7 +677,7 @@ class _DiffusionTiles:
             last = i == len(walk) - 1 or owner[i + 1] != owner[i]
             how.append(first | last << 1 | strict << 2
                        | self.shapes.index(shape) << 3)
-        self.steps = len(walk)
+        self.steps, self.held = len(walk), 0    # the walk is the tiles
         fields = [[t[0] for t in walk], [t[1] for t in walk], how]
         if kernel == BWD_DKV:
             fields.append([t[4] for t in walk])
@@ -630,7 +692,7 @@ class _DiffusionTiles:
             return p // n
         if n & (n - 1) == 0:
             return jax.lax.shift_right_logical(p, n.bit_length() - 1)
-        return jax.lax.div(p, jnp.int32(n))
+        return _div(p, n)
 
     def _tile(self, iq, ik):
         """``(shape, strict)`` of tile ``(iq, ik)``, None where the mask
@@ -799,10 +861,13 @@ def _fwd_kernel(lens_ref, seed_ref,       # (blocks,) i32, (1,) i32 in SMEM
 def _index_maps(group, band, tiles):
     """The q and the K/V block index maps of the two kernels whose grid is
     (q lane blocks, q blocks, key steps). Query lane block ``b`` reads KV
-    lane block ``b // group``; under a window step ``j`` is key block
-    ``k_first(i) + j``, held at the band's last block once past it so that
-    the skipped steps fetch nothing new. Ungrouped and unwindowed it is the
-    plain ``(b, j, 0)``. Where the grid walks a list of tiles (block
+    lane block ``b // group``. Under ``causal`` step ``j`` of q block ``i``
+    names the key block ``band.key_step`` holds for it: its own, ``k_first(i)
+    + j``, up to the last one the q block sees (the diagonal's), and that
+    one again for the steps past it, which the kernels skip: the pipeline
+    copies a block only when its index changes, so a skipped step fetches
+    nothing. Without ``causal`` every step runs and the map is the plain
+    ``(b // group, j, 0)``. Where the grid walks a list of tiles (block
     diffusion) step ``j``'s blocks are the tables' entries."""
     def head(b):
         return b if group == 1 else b // group
@@ -813,8 +878,32 @@ def _index_maps(group, band, tiles):
     q_map = lambda b, i, j: (b, i, 0)  # noqa: E731
     if band is None:
         return q_map, lambda b, i, j: (head(b), j, 0)
-    return q_map, lambda b, i, j: (
-        head(b), jnp.minimum(band.k_first(i) + j, band.k_last(i)), 0)
+    return q_map, lambda b, i, j: (head(b), band.key_step(i, j)[2], 0)
+
+
+def _dkv_index_maps(group, band, q_steps, tiles):
+    """The index maps of the dk/dv kernel, whose grid is (KV lane blocks,
+    key blocks, the group's query lane blocks x ``q_steps`` q steps): of
+    what lives per query lane block (q, do, lse, delta) and of the KV lane
+    block's own (k, v, dk, dv). Inner step ``t`` is q step ``t % q_steps``
+    of the group's query lane block ``t // q_steps``. Under ``causal`` a
+    step the kernel skips names the q block ``band.query_step`` holds for
+    it, the nearest one it computes, and so fetches nothing; at a change of
+    head within the group the lane block changes and the blocks are
+    fetched. Where the grid walks a list of tiles (block diffusion) step
+    ``t``'s q block, key block and head are the tables' entries."""
+    if tiles.tables:
+        return (lambda b, j, t, lens, seed, tq, tk, how, head: (
+                    b if group == 1 else b * group + head[t], tq[t], 0),
+                lambda b, j, t, lens, seed, tq, tk, how, head: (b, tk[t], 0))
+
+    def q_map(b, j, t):
+        jq = t if group == 1 else t % q_steps
+        block = b if group == 1 else b * group + t // q_steps
+        if band is not None:
+            jq = band.query_step(j, jq)[2]
+        return (block, jq, 0)
+    return q_map, lambda b, j, t: (b, j, 0)
 
 
 def _geometry(q, k, d, heads, block_q, block_k, window, causal,
@@ -826,10 +915,10 @@ def _geometry(q, k, d, heads, block_q, block_k, window, causal,
     group = blocks // k.shape[0]
     nq = pl.cdiv(sq, block_q)
     nk = pl.cdiv(sk, block_k)
-    band = None if window is None else _Band(window, block_q, block_k, nq, nk)
+    band = _Band(window, block_q, block_k, nq, nk) if causal else None
     if diffusion is None:
-        tiles = {kernel: _Tiles(kernel, causal, use_kv_mask, block_q,
-                                block_k, nq, nk, band, sq == sk)
+        tiles = {kernel: _Tiles(kernel, use_kv_mask, block_q, block_k, nq,
+                                nk, band, sq == sk)
                  for kernel in KERNEL_NAMES}
     else:
         tiles = {kernel: _DiffusionTiles(kernel, *diffusion, use_kv_mask,
@@ -859,9 +948,13 @@ def _call(kernel, name, tiles, grid, in_specs, out_specs, out_shape, scratch,
         lens, seed, *(jnp.asarray(t) for t in tiles.tables), *operands)
 
 
-def _count_tiles(kernel, tiles):
+def _count_tiles(kernel, tiles, heads=1):
     """``flash_tiles_staged_total{kernel, kind}``: the tiles of each kind
-    one lane block's grid walks in a kernel that is being staged."""
+    one lane block's grid walks in a kernel that is being staged, and
+    ``flash_steps_held_total{kernel}``: the other steps of that grid, which
+    compute nothing and fetch nothing (the dk/dv kernel's grid walks the
+    ``heads`` query lane blocks of a KV lane block's group, and skips the
+    same steps for each)."""
     from ... import telemetry
     if telemetry.enabled():
         counter = telemetry.counter(
@@ -870,6 +963,11 @@ def _count_tiles(kernel, tiles):
             "how they are computed")
         for kind, n in tiles.counts.items():
             counter.inc(n, kernel=kernel, kind=kind)
+        telemetry.counter(
+            "flash_steps_held_total",
+            "Grid steps of a lane block in a staged flash kernel whose tile "
+            "the mask hides: skipped, the block index held").inc(
+                tiles.held * heads, kernel=kernel)
 
 
 # ``_fwd`` and ``_bwd_calls`` are jitted so that the layers of a model share
@@ -1061,7 +1159,7 @@ def _bwd_calls(sm_scale, causal, block_q, block_k, use_kv_mask, dropout_rate,
         diffusion)
     lanes, n = pack.lanes, pack.n
     _count_tiles(BWD_DQ, tiles[BWD_DQ])
-    _count_tiles(BWD_DKV, tiles[BWD_DKV])
+    _count_tiles(BWD_DKV, tiles[BWD_DKV], group)
     k_steps = nk if band is None else band.k_steps
     q_steps = nq if band is None else band.q_steps
     q_map_dq, kv_map = _index_maps(group, band, tiles[BWD_DQ])
@@ -1096,22 +1194,7 @@ def _bwd_calls(sm_scale, causal, block_q, block_k, use_kv_mask, dropout_rate,
         [pltpu.VMEM((block_q, lanes), jnp.float32)],
         interpret, lens, seed, q, k, v, do, lse, delta)
 
-    # the dk/dv kernel's view of what lives per query lane block (q, do,
-    # lse, delta): KV lane block ``b``, key block ``j``, inner step ``t``
-    def q_map(b, j, t):
-        jq = t if group == 1 else t % q_steps
-        block = b if group == 1 else b * group + t // q_steps
-        if band is not None:
-            jq = jnp.minimum(band.q_first(j) + jq, band.q_last(j))
-        return (block, jq, 0)
-    kv_own = lambda b, j, i: (b, j, 0)  # noqa: E731
-    if diffusion is not None:
-        # step ``t`` of the walk: the tables' q block, key block and head
-        def q_map(b, j, t, lens, seed, tq, tk, how, head):
-            return (b if group == 1 else b * group + head[t], tq[t], 0)
-
-        def kv_own(b, j, t, lens, seed, tq, tk, how, head):
-            return (b, tk[t], 0)
+    q_map, kv_own = _dkv_index_maps(group, band, q_steps, tiles[BWD_DKV])
     q_spec_kv = pl.BlockSpec((1, block_q, lanes), q_map)
     stat_spec_kv = pl.BlockSpec((n, block_q, STAT_LANES), q_map)
     kv_spec = pl.BlockSpec((1, block_k, lanes), kv_own)

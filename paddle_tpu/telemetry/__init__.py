@@ -77,6 +77,11 @@ flash_tiles_staged_total       counter    ops.pallas.flash_attention, where
                                           {kernel=flash_fwd|flash_bwd_dq|
                                           flash_bwd_dkv, kind=dense|
                                           triangular|masked}
+flash_steps_held_total         counter    ops.pallas.flash_attention, beside
+                                          it: the other steps of that grid,
+                                          hidden by the causal mask, skipped
+                                          with their block index held so
+                                          that they fetch nothing {kernel}
 rope_calls_staged_total        counter    nn.functional.rotary_embedding,
                                           where a call is staged: the path
                                           its input took {path=pallas|xla,

@@ -48,9 +48,9 @@ def _tile_kinds(seq, block, window):
 
     fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
     n = seq // block
-    band = None if window is None else fa._Band(window, block, block, n, n)
-    return {kernel: tuple(fa._Tiles(kernel, True, False, block, block, n, n,
-                                    band, True).counts[kind]
+    band = fa._Band(window, block, block, n, n)
+    return {kernel: tuple(fa._Tiles(kernel, False, block, block, n, n, band,
+                                    True).counts[kind]
                           for kind in fa.TILE_KINDS)
             for kernel in fa.KERNEL_NAMES}
 
@@ -198,25 +198,38 @@ def test_flash_at_head_width_256_in_the_tuning_dbs_blocks(v5e):
     assert "s32[32]" in text and "bf16[32,8192,256]" in text
 
 
-def test_flash_at_20_ungrouped_heads_of_width_256(v5e):
+@pytest.mark.parametrize("rows", [4, 1], ids=["timed_4_rows",
+                                              "compared_1_row"])
+def test_flash_at_20_ungrouped_heads_of_width_256(v5e, rows):
     """glm-4.7-flash.seq4096's six latent-attention layers: rows of 4,096
     positions, 20 query heads over 20 key/value heads (no grouping: each is
-    the up-projection's own) at head width 256, in the blocks the lookup
-    resolves for that shape. The three kernels lower and fit."""
+    the up-projection's own) at head width 256, the step's four rows and
+    the comparison's one, in the blocks the lookup resolves for that shape,
+    (512, 1024): 8 x 4 steps a lane block, 12 of them over the diagonal,
+    where the index maps hold their block (a ``min``, a ``max`` and a
+    division of scalars in the maps and in the kernels' ``run``). The three
+    kernels lower and fit."""
+    from paddle_tpu.ops.pallas import tuner
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
+
+    cfg, source = tuner.resolve(
+        "flash_attention", jnp.bfloat16, tuner.flash_dims(256, 4096, 4096),
+        {})
+    assert source == "db" and (cfg["block_q"], cfg["block_k"]) == (512, 1024)
 
     def f(q, k, v):
         return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
             q, k, v, causal=True).astype(jnp.float32) ** 2),
             argnums=(0, 1, 2))(q, k, v)
 
-    qkv = ((4, 4096, 20, 256), jnp.bfloat16)
+    qkv = ((rows, 4096, 20, 256), jnp.bfloat16)
     text = _compile(f, v5e, qkv, qkv, qkv)
     for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
         assert any("tpu_custom_call" in line and f"%{kernel}" in line
                    for line in text.splitlines()), kernel
     # the operands the benchmark's flash_attn_ms_per_step finds them by
-    assert "s32[80]" in text and "bf16[80,4096,256]" in text
+    assert f"s32[{20 * rows}]" in text
+    assert f"bf16[{20 * rows},4096,256]" in text
 
 
 @pytest.mark.parametrize("block, non_power", [(512, False), (1024, False),
