@@ -28,7 +28,7 @@ from jax import lax
 
 from ..nn import functional as F
 from ..nn.layer import Layer
-from ..nn.layers.common import GatedSiluFFN, Linear
+from ..nn.layers.common import GatedSiluFFN, Linear, SquaredReluFFN
 
 EXPERT_AXIS = "model"
 
@@ -219,16 +219,27 @@ def route_top_k(logits, top_k, scoring="sigmoid", scaling_factor=1.0,
     return ids.astype(jnp.int32), top * scaling_factor
 
 
-class GroupedExperts(Layer):
-    """``count`` gated SiLU FFNs with stacked weights, applied to rows that
-    are sorted by expert: three grouped matrix products."""
+# the routed and the shared experts' form, by name: the shared expert's
+# class (GroupedExperts is either)
+ACTIVATIONS = {"silu": GatedSiluFFN, "relu2": SquaredReluFFN}
 
-    def __init__(self, count, d_model, d_expert):
+
+class GroupedExperts(Layer):
+    """``count`` FFNs with stacked weights, applied to rows that are sorted
+    by expert. ``activation="silu"``: gated SiLU, ``down(silu(gate(x)) *
+    up(x))``, three grouped matrix products; ``"relu2"``: ``down(relu(up(x))
+    ^ 2)``, two, and no ``gate_proj``."""
+
+    def __init__(self, count, d_model, d_expert, activation="silu"):
         super().__init__()
         from ..nn.initializer import XavierUniform
+        if activation not in ACTIVATIONS:
+            raise ValueError(f"unknown expert activation {activation!r}")
+        self.activation = activation
         wide = XavierUniform(fan_in=d_model, fan_out=d_expert)
-        self.gate_proj = self.create_parameter((count, d_model, d_expert),
-                                               initializer=wide)
+        if activation == "silu":
+            self.gate_proj = self.create_parameter(
+                (count, d_model, d_expert), initializer=wide)
         self.up_proj = self.create_parameter((count, d_model, d_expert),
                                              initializer=wide)
         self.down_proj = self.create_parameter(
@@ -241,11 +252,13 @@ class GroupedExperts(Layer):
         group: on the TPU the kernels do not write them, in the result or
         in its gradient, so the caller masks them."""
         dt = rows.dtype
-        gate = lax.ragged_dot(rows, self.gate_proj.value.astype(dt),
-                              group_sizes)
+        gate = None if self.activation == "relu2" else lax.ragged_dot(
+            rows, self.gate_proj.value.astype(dt), group_sizes)
         up = lax.ragged_dot(rows, self.up_proj.value.astype(dt), group_sizes)
-        return lax.ragged_dot(F.silu(gate) * up,
-                              self.down_proj.value.astype(dt), group_sizes)
+        hidden = jnp.square(F.relu(up)) if gate is None else F.silu(gate) * up
+        return lax.ragged_dot(hidden, self.down_proj.value.astype(dt),
+                              group_sizes)
+
 
 
 # the compiler's grouped-product kernels work in tiles of this many rows
@@ -308,12 +321,14 @@ class DroplessMoELayer(Layer):
     gradient and no optimizer slot), which ``route_top_k`` adds to the
     scores to choose the experts and leaves out of their weights. Nothing
     here changes it: the balancing step that would is a training recipe.
+    ``activation`` is the routed and the shared experts' form
+    (``GroupedExperts``): ``"silu"``, gated, or ``"relu2"``.
     """
 
     def __init__(self, d_model, d_expert, num_experts, top_k, held=None,
                  routed_scaling_factor=1.0, scoring="sigmoid", d_shared=None,
                  router_attr=None, shared_expert_gate=False,
-                 selection_bias=False):
+                 selection_bias=False, activation="silu"):
         super().__init__()
         first, count = held if held is not None else (0, num_experts)
         if not (0 <= first and count >= 1 and first + count <= num_experts):
@@ -327,12 +342,12 @@ class DroplessMoELayer(Layer):
         self.scoring = scoring
         self.router = Linear(d_model, num_experts, bias_attr=False,
                              weight_attr=router_attr)
-        self.shared_expert = (GatedSiluFFN(d_model, d_shared)
+        self.shared_expert = (ACTIVATIONS[activation](d_model, d_shared)
                               if d_shared else None)
         self.shared_expert_gate = (
             Linear(d_model, 1, bias_attr=False)
             if shared_expert_gate and d_shared else None)
-        self.experts = GroupedExperts(count, d_model, d_expert)
+        self.experts = GroupedExperts(count, d_model, d_expert, activation)
         if selection_bias:
             self.register_buffer("e_score_correction_bias",
                                  jnp.zeros((num_experts,), jnp.float32))

@@ -22,6 +22,7 @@ from .layers.loss import (  # noqa: F401
     MSELoss, NLLLoss, SmoothL1Loss, TripletMarginLoss)
 from .decode import BeamSearchDecoder, Decoder, dynamic_decode  # noqa: F401
 from .layers.linear_attention import GatedDeltaNet  # noqa: F401
+from .layers.state_space import Mamba2Mixer  # noqa: F401
 from .layers.norm import (  # noqa: F401
     BatchNorm, BatchNorm1D, BatchNorm2D, BatchNorm3D, GatedRMSNorm, GroupNorm,
     InstanceNorm1D, InstanceNorm2D, InstanceNorm3D, LayerNorm,

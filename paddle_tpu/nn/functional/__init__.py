@@ -32,3 +32,4 @@ from .attention import (  # noqa: F401
     block_diffusion_mask, scaled_dot_product_attention)
 from .rotary import rope_frequencies, rotary_embedding  # noqa: F401
 from .linear_attention import causal_conv1d, gated_delta_rule  # noqa: F401
+from .state_space import ssd_scan  # noqa: F401

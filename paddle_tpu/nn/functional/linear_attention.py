@@ -73,11 +73,12 @@ PRECISION = lax.Precision.HIGH
 GROUP_HEADS = 16
 
 
-def causal_conv1d(x, w):
+def causal_conv1d(x, w, bias=None):
     """Depthwise causal convolution over the sequence: ``x`` ``(batch,
-    seq, channels)``, ``w`` ``(channels, kernel)``, no bias::
+    seq, channels)``, ``w`` ``(channels, kernel)``, ``bias`` ``(channels,)``
+    or None::
 
-        out[t] = sum_j w[:, j] * x[t - (kernel - 1) + j]
+        out[t] = sum_j w[:, j] * x[t - (kernel - 1) + j]  [+ bias]
 
     with zeros to the left of position 0, so position ``t`` reads
     ``t - kernel + 1 .. t`` and never ``t + 1`` (``w[:, -1]`` weighs the
@@ -90,6 +91,8 @@ def causal_conv1d(x, w):
         w = w.astype(jnp.float32)
         out = sum(padded[:, j:j + seq].astype(jnp.float32) * w[:, j]
                   for j in range(kernel))
+        if bias is not None:
+            out = out + bias.astype(jnp.float32)
         return out.astype(x.dtype)
 
 

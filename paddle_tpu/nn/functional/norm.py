@@ -85,17 +85,28 @@ def rms_norm(x, weight=None, epsilon=1e-6, name=None, offset=0.0):
     return out
 
 
-def gated_rms_norm(x, z, weight, epsilon=1e-6):
+def gated_rms_norm(x, z, weight, epsilon=1e-6, group_size=None,
+                   norm_before_gate=True):
     """``rms_norm(x) * weight * silu(z)`` over the last axis, ``z`` of
     ``x``'s shape: the norm on a linear-attention head's output with the
-    gate the input projection made beside it. All in float32; the result
-    has ``x``'s dtype."""
+    gate the input projection made beside it. ``norm_before_gate=False``
+    gates first, ``rms_norm(x * silu(z)) * weight`` (Mamba-2's order), and
+    ``group_size`` takes the statistics over groups of that many lanes of
+    the last axis, not over all of it. All in float32; the result has
+    ``x``'s dtype."""
     weight = _unwrap(weight)
     x32 = x.astype(jnp.float32)
-    out = x32 * jax.lax.rsqrt(
-        jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + epsilon)
-    out = out * weight.astype(jnp.float32) \
-        * jax.nn.silu(z.astype(jnp.float32))
+    if not norm_before_gate:
+        x32 = x32 * jax.nn.silu(z.astype(jnp.float32))
+    out = x32 if group_size is None else jnp.reshape(
+        x32, x32.shape[:-1] + (-1, group_size))
+    out = out * jax.lax.rsqrt(
+        jnp.mean(jnp.square(out), axis=-1, keepdims=True) + epsilon)
+    if group_size is not None:
+        out = jnp.reshape(out, x32.shape)
+    out = out * weight.astype(jnp.float32)
+    if norm_before_gate:
+        out = out * jax.nn.silu(z.astype(jnp.float32))
     return out.astype(x.dtype)
 
 

@@ -56,6 +56,22 @@ class GatedSiluFFN(Layer):
         return self.down_proj(F.silu(self.gate_proj(x)) * self.up_proj(x))
 
 
+class SquaredReluFFN(Layer):
+    """``down(relu(up(x))^2)``: the feed-forward block with a squared ReLU,
+    two products, no gate and no bias."""
+
+    def __init__(self, hidden_size, intermediate_size, weight_attr=None,
+                 name=None):
+        super().__init__()
+        self.up_proj = Linear(hidden_size, intermediate_size,
+                              weight_attr=weight_attr, bias_attr=False)
+        self.down_proj = Linear(intermediate_size, hidden_size,
+                                weight_attr=weight_attr, bias_attr=False)
+
+    def forward(self, x):
+        return self.down_proj(jnp.square(F.relu(self.up_proj(x))))
+
+
 class Embedding(Layer):
     def __init__(self, num_embeddings, embedding_dim, padding_idx=None,
                  sparse=False, weight_attr=None, name=None):

@@ -214,17 +214,22 @@ class RMSNorm(Layer):
 
 class GatedRMSNorm(Layer):
     """``rms_norm(x) * weight * silu(z)`` over the last axis
-    (``F.gated_rms_norm``): ``forward(x, z)``."""
+    (``F.gated_rms_norm``): ``forward(x, z)``. ``norm_before_gate=False``
+    gates first; ``group_size`` takes the statistics over groups of that
+    many lanes."""
 
-    def __init__(self, hidden_size, epsilon=1e-6, name=None):
+    def __init__(self, hidden_size, epsilon=1e-6, name=None, group_size=None,
+                 norm_before_gate=True):
         super().__init__()
         self.hidden_size = hidden_size
         self.epsilon = epsilon
+        self.group_size, self.norm_before_gate = group_size, norm_before_gate
         self.weight = self.create_parameter((hidden_size,),
                                             initializer=Constant(1.0))
 
     def forward(self, x, z):
-        return F.gated_rms_norm(x, z, self.weight, self.epsilon)
+        return F.gated_rms_norm(x, z, self.weight, self.epsilon,
+                                self.group_size, self.norm_before_gate)
 
     def extra_repr(self):
         return f"hidden_size={self.hidden_size}"

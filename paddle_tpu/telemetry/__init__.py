@@ -98,6 +98,12 @@ latent_attn_calls_staged_total counter    text.models MultiHeadLatent
 gated_delta_chunks_total       counter    chunk states a row of those calls
                                           walks one after another (seq /
                                           chunk; seq on the recurrent path)
+ssd_scan_calls_staged_total    counter    nn.functional.ssd_scan, where a
+                                          call is staged: the path it took
+                                          {path=chunked|recurrent}
+ssd_chunks_total               counter    chunk states a row of those calls
+                                          walks one after another (seq /
+                                          chunk; seq on the recurrent path)
 moe_tokens_routed_total        counter    incubate.moe DroplessMoELayer.
                                           publish_routing: tokens routed
 moe_held_assignments_total     counter    (token, expert) assignments on

@@ -1,15 +1,22 @@
 """A decoder whose layers differ: per layer, the sequence mixer (full
-causal attention, a causal sliding window, multi-head latent attention or a
-Gated DeltaNet linear recurrence), the number of query heads, and a dense
-or a sparse (routed experts plus a shared expert) feed-forward block.
+causal attention, a causal sliding window, multi-head latent attention, a
+Gated DeltaNet linear recurrence, a Mamba-2 state-space scan or none), the
+number of query heads, and a dense, a sparse (routed experts plus a shared
+expert) or no feed-forward block. A layer with both is two residual
+sublayers, ``h = x + mixer(norm(x)); y = h + ffn(norm(h))``; a layer with
+one of them is one, ``y = x + sublayer(norm(x))`` (Nemotron-H's pattern of
+Mamba, expert and attention layers).
 
 What every layer shares: pre-norm residual blocks with RMSNorm
-(``norm_offset=1`` for zero-centred weights, scale ``1 + w``), gated SiLU
-FFNs, token embeddings only, a final RMSNorm and an untied head. Attention
+(``norm_offset=1`` for zero-centred weights, scale ``1 + w``), token
+embeddings only, a final RMSNorm and an untied head. Feed-forward blocks
+are gated SiLU FFNs, or squared-ReLU experts (``expert_activation=
+"relu2"``). Attention
 layers have grouped KV heads (``kv_heads`` of width ``head_dim`` serve
 every layer's query heads), rotary embeddings whose parameters go by the
 kind of attention (so a model can rotate all lanes at one theta in its
-window layers and part of them, YaRN-scaled, in its full layers), and an
+window layers and part of them, YaRN-scaled, in its full layers; or none
+at all, ``rope[kind]`` None), and an
 optional sigmoid gate on the attention output: one a head from a
 projection of its own (``"head"``) or one a lane from a doubled ``q_proj``
 (``"elementwise"``). ``qk_norm`` adds an RMSNorm over the head width on q
@@ -19,7 +26,9 @@ fixed size a head carried along the sequence, no positions and no mask.
 ``"latent_attention"`` layers are ``MultiHeadLatentAttention``
 (``latent_attention`` holds its ranks and widths): q and k, v come from
 low-rank latents with an RMSNorm on each, and the rotary part of the key is
-one head that all key heads share.
+one head that all key heads share. ``"mamba"`` layers are
+``nn.Mamba2Mixer`` (``mamba`` holds its sizes): a ``P x N`` state a head
+carried along the sequence by ``F.ssd_scan``, no positions and no mask.
 Sparse layers are ``incubate.moe.DroplessMoELayer``: the router's width and
 ``top_k`` are the model's, ``held_experts`` says which experts this copy
 holds (expert parallelism's share; the whole set by default),
@@ -28,7 +37,8 @@ holds (expert parallelism's share; the whole set by default),
 ``e_score_correction_bias`` that chooses and does not weigh. A call
 may give the positions explicitly and ask for the block-diffusion mask in
 place of the causal one (a model with linear layers refuses both: its
-recurrence defines neither); ``MixedDecoderForBlockDiffusion`` trains the
+recurrence defines neither; so does one with mamba layers);
+``MixedDecoderForBlockDiffusion`` trains the
 trunk that way (``text/block_diffusion.py``).
 ``MixedDecoderForPretraining(mtp_layers=1)`` adds a multi-token-prediction
 module (``MultiTokenPrediction``) that predicts the token after the next
@@ -36,8 +46,9 @@ through the trunk's own embedding and head.
 
 Names are what the benchmark's scope metrics read: root
 ``mixeddecoderforpretraining`` or ``mixeddecoderforblockdiffusion``, trunk
-``decoder``, blocks ``h.N``, in a block ``attn`` or ``linear_attn`` and
-``mlp`` or ``moe``, then ``lm_head``; the multi-token-prediction module is
+``decoder``, blocks ``h.N``, in a block ``attn``, ``linear_attn`` or
+``mamba`` and ``mlp`` or ``moe`` (one of the two in a one-sublayer block),
+then ``lm_head``; the multi-token-prediction module is
 ``mtp`` with its block ``mtp.block``; in a latent-attention layer the scopes
 ``latent_q`` and ``latent_kv`` hold what stands where grouped-query
 attention has its three projections.
@@ -56,7 +67,7 @@ from ...nn.layer import Layer
 from .. import block_diffusion as bd
 
 FULL, SLIDING = "full_attention", "sliding_attention"
-LINEAR, LATENT = "linear_attention", "latent_attention"
+LINEAR, LATENT, MAMBA = "linear_attention", "latent_attention", "mamba"
 DENSE, SPARSE = "dense", "sparse"
 
 
@@ -73,7 +84,9 @@ class GroupedQueryAttention(Layer):
     the scale and computes both, on a TPU as one pass over the tensor, so
     the scope ``qk_norm`` holds the whole per-head prologue of q and k,
     norm and rotation (``.../qk_norm/rope/...``); without a norm the
-    rotation is under ``rope`` alone."""
+    rotation is under ``rope`` alone. ``rope=None`` rotates nothing: the
+    layer has no positional encoding and sees the order through the causal
+    mask alone (a QK norm is then applied by itself)."""
 
     def __init__(self, hidden_size, num_heads, kv_heads, head_dim, rope,
                  window=None, gate=None, qk_norm_epsilon=None,
@@ -87,9 +100,12 @@ class GroupedQueryAttention(Layer):
         self.gate = gate
         self.num_heads, self.kv_heads = num_heads, kv_heads
         self.head_dim, self.window = head_dim, window
-        # rope: {"theta", "rotary_dim", "yarn" or None}
-        self.inv_freq, self.rope_scale = F.rope_frequencies(
-            rope["theta"], rope["rotary_dim"], rope.get("yarn"))
+        # rope: {"theta", "rotary_dim", "yarn" or None}, or None for no
+        # rotation
+        self.inv_freq = self.rope_scale = None
+        if rope is not None:
+            self.inv_freq, self.rope_scale = F.rope_frequencies(
+                rope["theta"], rope["rotary_dim"], rope.get("yarn"))
         self.q_proj = nn.Linear(
             hidden_size,
             num_heads * head_dim * (2 if gate == "elementwise" else 1),
@@ -110,6 +126,8 @@ class GroupedQueryAttention(Layer):
                                      offset=norm_offset)
 
     def _rope(self, x, positions, norm=None):
+        if self.inv_freq is None:
+            return x if norm is None else norm(x)
         if norm is None:
             return F.rotary_embedding(x, self.inv_freq, self.rope_scale,
                                       positions)
@@ -257,38 +275,44 @@ class MultiHeadLatentAttention(Layer):
 
 class MixedDecoderBlock(Layer):
     """``h = x + mixer(norm(x)); y = h + ffn(norm(h))`` with the sublayers
-    ``attn`` (softmax attention) or ``linear_attn`` (``linear``: a mixer
-    that takes the hidden states alone) and ``mlp`` (dense) or ``moe``
-    (sparse)."""
+    ``attn`` (softmax attention), ``linear_attn`` or ``mamba`` (the
+    ``mixer_name``; these two take the hidden states alone) and ``mlp``
+    (dense) or ``moe`` (sparse). A block of one sublayer (``mixer`` or
+    ``ffn`` None) is ``y = x + sublayer(norm(x))`` under ``input_norm`` and
+    has no ``post_attn_norm``."""
 
     def __init__(self, mixer: Layer, ffn: Layer, sparse: bool, hidden_size,
-                 epsilon, linear=False, norm_offset=0.0):
+                 epsilon, norm_offset=0.0, mixer_name="attn"):
         super().__init__()
+        if mixer is None and ffn is None:
+            raise ValueError("a block needs a mixer or a feed-forward block")
         self.input_norm = nn.RMSNorm(hidden_size, epsilon,
                                      offset=norm_offset)
-        if linear:
-            self.linear_attn = mixer
-        else:
-            self.attn = mixer
-        self.post_attn_norm = nn.RMSNorm(hidden_size, epsilon,
-                                         offset=norm_offset)
-        if sparse:
-            self.moe = ffn
-        else:
-            self.mlp = ffn
-        self._linear = linear
+        self._mixer_name = mixer_name
+        if mixer is not None:
+            setattr(self, self._mixer_name, mixer)
+        if mixer is not None and ffn is not None:
+            self.post_attn_norm = nn.RMSNorm(hidden_size, epsilon,
+                                             offset=norm_offset)
         self._ffn_name = "moe" if sparse else "mlp"
+        if ffn is not None:
+            setattr(self, self._ffn_name, ffn)
+        self.has_mixer, self.has_ffn = mixer is not None, ffn is not None
 
     def mixer_half(self, x, positions=None, block_diffusion=None):
-        if self._linear:
-            return x + self.linear_attn(self.input_norm(x))
-        return x + self.attn(self.input_norm(x), positions, block_diffusion)
+        mixer = getattr(self, self._mixer_name)
+        if self._mixer_name != "attn":
+            return x + mixer(self.input_norm(x))
+        return x + mixer(self.input_norm(x), positions, block_diffusion)
 
     def ffn_half(self, x):
-        return x + getattr(self, self._ffn_name)(self.post_attn_norm(x))
+        norm = self.post_attn_norm if self.has_mixer else self.input_norm
+        return x + getattr(self, self._ffn_name)(norm(x))
 
     def forward(self, x, positions=None, block_diffusion=None):
-        return self.ffn_half(self.mixer_half(x, positions, block_diffusion))
+        if self.has_mixer:
+            x = self.mixer_half(x, positions, block_diffusion)
+        return self.ffn_half(x) if self.has_ffn else x
 
 
 def _call_checkpointed(block: Layer, fn, x, *args):
@@ -318,14 +342,19 @@ class MixedDecoderModel(Layer):
     ``"linear_attention"`` (then ``linear_attention`` holds
     ``nn.GatedDeltaNet``'s ``key_heads``, ``value_heads``, ``d_k``,
     ``d_v`` and ``conv_kernel``, and the layer's
-    entry in ``heads_per_layer`` is not read) or ``"latent_attention"``
+    entry in ``heads_per_layer`` is not read), ``"latent_attention"``
     (then ``latent_attention`` holds ``MultiHeadLatentAttention``'s
     ``q_lora_rank``, ``kv_lora_rank``, ``qk_nope_head_dim``,
     ``qk_rope_head_dim`` and ``v_head_dim``, ``rope["latent_attention"]``
     its ``theta`` and optionally ``yarn``, and ``kv_heads`` and
-    ``head_dim`` are not read), ``heads_per_layer[i]`` the
-    layer's query heads, ``mlp_layer_types[i]`` ``"dense"`` or ``"sparse"``
-    (``router_scoring`` ``"sigmoid"`` or ``"softmax"``;
+    ``head_dim`` are not read), ``"mamba"`` (then ``mamba`` holds
+    ``nn.Mamba2Mixer``'s keyword arguments but ``hidden_size`` and
+    ``epsilon``, and ``heads_per_layer[i]`` is not read) or None (the layer
+    has no mixer), ``heads_per_layer[i]`` the
+    layer's query heads, ``mlp_layer_types[i]`` ``"dense"``, ``"sparse"``
+    or None (no feed-forward block; a layer has a mixer or a feed-forward
+    block or both) (``router_scoring`` ``"sigmoid"`` or ``"softmax"``;
+    ``expert_activation`` the experts' form, ``"silu"`` or ``"relu2"``;
     ``shared_expert_size`` 0 for no shared expert, ``shared_expert_gate``
     for a sigmoid weight a token on it; ``router_attr`` the routers'
     ``ParamAttr``; ``router_selection_bias`` for the buffer
@@ -337,7 +366,7 @@ class MixedDecoderModel(Layer):
     RMSNorm over the head width on q and k, the model's ``epsilon``.
     ``norm_offset=1`` makes every RMSNorm weight zero-centred (scale ``1 +
     w``). ``rope[kind]`` holds ``theta``, ``rotary_dim`` and optionally
-    ``yarn`` for each kind of attention.
+    ``yarn`` for each kind of attention, or None for no rotation.
     ``checkpoint_blocks`` recomputes each block in the backward pass (the
     trainer's ``remat`` checkpoints the whole model at once), its mixer
     half and its feed-forward half apart: one more hidden state is kept a
@@ -360,24 +389,29 @@ class MixedDecoderModel(Layer):
                  qk_norm=False, router_scoring="sigmoid", router_attr=None,
                  attention_gate=None, shared_expert_gate=False,
                  linear_attention=None, norm_offset=0.0,
-                 latent_attention=None, router_selection_bias=False):
+                 latent_attention=None, router_selection_bias=False,
+                 mamba=None, expert_activation="silu"):
         super().__init__()
         if not (len(layer_types) == len(heads_per_layer)
                 == len(mlp_layer_types)):
             raise ValueError("the three per-layer lists differ in length")
         if gated_attention:
             attention_gate = attention_gate or "head"
-        self.has_linear_layers = LINEAR in layer_types
+        self.has_recurrent_layers = bool({LINEAR, MAMBA} & set(layer_types))
         self.hidden_size, self.epsilon = hidden_size, epsilon
         self.checkpoint_blocks = checkpoint_blocks
         self.embed_tokens = nn.Embedding(vocab_size, hidden_size,
                                          weight_attr=embedding_attr)
 
         def block(kind, ffn_kind, heads):
-            if kind not in (FULL, SLIDING, LINEAR, LATENT) \
-                    or ffn_kind not in (DENSE, SPARSE):
+            if kind not in (FULL, SLIDING, LINEAR, LATENT, MAMBA, None) \
+                    or ffn_kind not in (DENSE, SPARSE, None):
                 raise ValueError(f"unknown layer kinds {kind!r}, {ffn_kind!r}")
-            if kind == LINEAR:
+            if kind is None:
+                mixer = None
+            elif kind == MAMBA:
+                mixer = nn.Mamba2Mixer(hidden_size, epsilon=epsilon, **mamba)
+            elif kind == LINEAR:
                 mixer = nn.GatedDeltaNet(hidden_size, epsilon=epsilon,
                                          **linear_attention)
             elif kind == LATENT:
@@ -400,12 +434,16 @@ class MixedDecoderModel(Layer):
                     d_shared=shared_expert_size or None,
                     router_attr=router_attr,
                     shared_expert_gate=shared_expert_gate,
-                    selection_bias=router_selection_bias)
-            else:
+                    selection_bias=router_selection_bias,
+                    activation=expert_activation)
+            elif ffn_kind == DENSE:
                 ffn = nn.GatedSiluFFN(hidden_size, intermediate_size)
+            else:
+                ffn = None
             return MixedDecoderBlock(
                 mixer, ffn, ffn_kind == SPARSE, hidden_size, epsilon,
-                linear=kind == LINEAR, norm_offset=norm_offset)
+                norm_offset=norm_offset, mixer_name={
+                    LINEAR: "linear_attn", MAMBA: "mamba"}.get(kind, "attn"))
 
         self.make_block = block
         self.h = nn.LayerList([
@@ -418,12 +456,12 @@ class MixedDecoderModel(Layer):
 
     def blocks(self, input_ids, positions=None, block_diffusion=None):
         """The last block's output, before the final norm."""
-        if self.has_linear_layers and not (positions is None
+        if self.has_recurrent_layers and not (positions is None
                                            and block_diffusion is None):
             raise ValueError(
-                "a model with linear_attention layers runs a recurrence "
-                "along the row: it takes no explicit positions and no "
-                "block-diffusion mask")
+                "a model with linear_attention or mamba layers runs a "
+                "recurrence along the row: it takes no explicit positions "
+                "and no block-diffusion mask")
         x = self.embed_tokens(input_ids)
         for block in self.h:
             x = self.run_block(block, x, positions, block_diffusion)
@@ -431,14 +469,18 @@ class MixedDecoderModel(Layer):
 
     def run_block(self, block, x, positions=None, block_diffusion=None):
         """``block(x, ...)``, its two halves recomputed apart in the
-        backward pass under ``checkpoint_blocks``."""
+        backward pass under ``checkpoint_blocks`` (a block of one sublayer
+        under one checkpoint)."""
         if not self.checkpoint_blocks:
             return block(x, positions, block_diffusion)
         # under the scope block(...) would open
         with jax.named_scope(block._scope_name):
-            x = _call_checkpointed(block, block.mixer_half, x, positions,
-                                   block_diffusion)
-            return _call_checkpointed(block, block.ffn_half, x)
+            if block.has_mixer:
+                x = _call_checkpointed(block, block.mixer_half, x, positions,
+                                       block_diffusion)
+            if block.has_ffn:
+                x = _call_checkpointed(block, block.ffn_half, x)
+            return x
 
     def forward(self, input_ids, positions=None, block_diffusion=None):
         return self.norm(self.blocks(input_ids, positions, block_diffusion))

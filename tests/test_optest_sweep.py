@@ -372,6 +372,9 @@ F_CONFIGS = {
     "gated_delta_rule": lambda: (lambda x: F.gated_delta_rule(
         x, x, x, -jnp.abs(x[..., 0]), F.sigmoid(x[..., 1]), chunk=4),
         _x((1, 8, 2, 4))),
+    "ssd_scan": lambda: (lambda x: F.ssd_scan(
+        x, jax.nn.softplus(x[..., 0]), -jnp.arange(1.0, 3.0), x[:, :, :1],
+        x[:, :, 1:], jnp.ones(2), chunk=4), _x((1, 8, 2, 4))),
     "gated_rms_norm": lambda: (lambda x: F.gated_rms_norm(
         x, x + 0.5, jnp.ones(x.shape[-1])), _x()),
     "normalize": lambda: (lambda x: F.normalize(x), _x()),

@@ -158,8 +158,11 @@ def _trainer():
     class MLP(nn.Layer):
         def __init__(self):
             super().__init__()
-            self.l1 = nn.Linear(16, 32)
-            self.l2 = nn.Linear(32, 4)
+            # a width no other test file's trainer has: jax stages the
+            # leaves' copies once a process, and a file that ran before
+            # this one in the same worker would have staged them already
+            self.l1 = nn.Linear(16, 27)
+            self.l2 = nn.Linear(27, 4)
 
         def forward(self, x):
             return self.l2(nn.functional.relu(self.l1(x)))
