@@ -249,6 +249,14 @@ def _linear_attn_calls(registry):
     return out
 
 
+def _ssd_scan_calls(registry):
+    """``ssd_scan_calls_staged_total`` by path, as the registry holds
+    it."""
+    staged = registry.get("ssd_scan_calls_staged_total")
+    return {path: int(staged.value(path=path))
+            for path in ("pallas", "chunked", "recurrent")} if staged else {}
+
+
 def _latent_attn_calls(registry):
     """``latent_attn_calls_staged_total`` by the queries' rotation, the
     rotations and flash tiles those calls staged and the two loss gauges
@@ -410,6 +418,9 @@ def phase_kernels(run: Run):
         linear_rope_calls = _rope_calls(tel.registry)
     checks += ns.check_gated_delta_precision(run.interpret)
     with telemetry.scope(profile=False) as tel:
+        checks += ns.check_ssd_scan(run.interpret)
+        ssd = _ssd_scan_calls(tel.registry)
+    with telemetry.scope(profile=False) as tel:
         checks += ns.check_latent_attention(run.interpret)
         latent = _latent_attn_calls(tel.registry)
     checks += (ns.check_flash_attention(run.interpret)
@@ -430,7 +441,7 @@ def phase_kernels(run: Run):
             flash_tiles_staged=flash_tiles, flash_steps_held=flash_held,
             rope_calls_staged=rope_calls, linear_attn_staged=linear_attn,
             linear_model_rope_calls_staged=linear_rope_calls,
-            latent_attn_staged=latent)
+            ssd_scan_staged=ssd, latent_attn_staged=latent)
     bad = [c["check"] for c in checks if not c["ok"]]
     check(not bad, f"kernel checks out of tolerance: {bad}")
     # on the chip check_rope goes through F.rotary_embedding: two cases
@@ -456,6 +467,12 @@ def phase_kernels(run: Run):
                   for n in calls.values()) == 2,
           f"the gated full layer's rotary_embedding staged as "
           f"{linear_rope_calls}, expected 2 calls with norm=1 on {path}")
+    # check_ssd_scan: on the chip the scan staged once through F.ssd_scan,
+    # by the kernels (heads of 64, a state of 128); rehearsed, it calls the
+    # interpreted kernels itself and stages nothing
+    want = {} if run.rehearsal else {"pallas": 1, "chunked": 0,
+                                     "recurrent": 0}
+    check(ssd == want, f"ssd_scan staged as {ssd}, expected {want}")
     # check_latent_attention: the trunk's block and the module's, each
     # staged once on the kernels' path (q through the rotary kernel, the one
     # rotary key head through XLA) and once more for the float32 reference,

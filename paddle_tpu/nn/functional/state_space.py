@@ -10,8 +10,16 @@ In the gated delta rule's terms (``linear_attention.py``) this is ``q =
 C``, ``k = B``, ``v = dt x`` and ``g = dt A`` with no delta correction, so
 its chunked form needs no triangular system. ``ssd_scan`` computes it. The
 recurrence over positions (``path="recurrent"``, one ``lax.scan`` step a
-position) is the definition and what the tests hold the other path to. The
-path a layer takes is the chunked one: the sequence is cut into chunks of
+position) is the definition and what the tests hold the other paths to.
+The path a layer takes is the chunked one, in two implementations of one
+algorithm chosen from what the call can observe (backend, widths, chunk,
+dtype, sequence), never by the caller: on a TPU at the kernels' widths
+(``P`` dividing a 128-lane block, ``N = 128``, chunks of 128) two Pallas
+kernels a (row, group of heads) (``ops/pallas/ssd_scan.py``: ``ssd_fwd`` /
+``ssd_bwd``), which keep a chunk's scores and the float32 state in VMEM;
+everywhere else (the CPU, toy widths, other chunks) XLA's ``_chunked``
+below, the yardstick the kernels are tested against beside the recurrence.
+``_chunked`` cuts the sequence into chunks of
 ``chunk`` positions; inside a chunk the outputs are one masked product a
 head, ``(C B^T * L) (dt x)`` with ``L_ij = exp(a_j+1 + .. + a_i)`` for ``i
 >= j``; what a chunk adds to the state is one product a head; and the state
@@ -113,7 +121,7 @@ def _chunked(x, dt, a, b, c, chunk):
 
 
 def _count_staged(path: str, chunks: int):
-    """``ssd_scan_calls_staged_total{path}`` and ``ssd_chunks_total``: one
+    """``ssd_scan_calls_staged_total{path=pallas|chunked|recurrent}`` and ``ssd_chunks_total``: one
     call of ``ssd_scan`` being staged, and the dependent steps a row of it
     has."""
     from ... import telemetry
@@ -139,20 +147,31 @@ def ssd_scan(x, dt, A, B, C, D=None, chunk: int = 128,
 
     ``path="chunked"`` (what a layer calls) works in chunks of ``chunk``
     positions; a sequence that is no multiple of it is padded with ``dt =
-    0`` and cut again. ``path="recurrent"`` is the definition, a step a
-    position: the yardstick of the tests, too slow and too large in the
-    backward pass for a real row. Staged under the scope ``ssd_scan``;
-    ``ssd_scan_calls_staged_total{path=chunked|recurrent}`` counts the
-    staged calls and ``ssd_chunks_total`` their dependent steps (``seq``
-    on the recurrent path)."""
+    0`` and cut again. Which implementation runs is read from the input
+    (the module's opening lines): the Pallas kernels on a TPU where
+    ``ssd_scan.supported`` takes the shapes, else XLA's batched products.
+    ``path="recurrent"`` is the definition, a step a position: the
+    yardstick of the tests, too slow and too large in the backward pass for
+    a real row. Staged under the scope ``ssd_scan``;
+    ``ssd_scan_calls_staged_total{path=pallas|chunked|recurrent}`` counts
+    the staged calls by what was decided (``chunked``: XLA's) and
+    ``ssd_chunks_total`` their dependent steps (``seq`` on the recurrent
+    path)."""
     if path not in ("chunked", "recurrent"):
         raise ValueError(f"unknown path {path!r}")
+    from ...ops.pallas import ssd_scan as kernel
     batch, seq, heads, p = x.shape
     groups = B.shape[2]
     if heads % groups or B.shape != C.shape:
         raise ValueError(f"{heads} heads over B {B.shape} and C {C.shape}")
+    if path == "chunked" and jax.default_backend() == "tpu" \
+            and x.dtype == B.dtype == C.dtype \
+            and kernel.supported(x.shape, B.shape, x.dtype, chunk):
+        path = "pallas"
     _count_staged(path, seq if path == "recurrent" else -(-seq // chunk))
     with jax.named_scope("ssd_scan"):
+        if path == "pallas":
+            return kernel.ssd_scan(x, dt, A, B, C, D)
         f32 = jnp.float32
         dt = dt.astype(f32)
         a = dt * A.astype(f32)

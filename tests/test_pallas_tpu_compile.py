@@ -416,6 +416,51 @@ def test_gated_delta_kernels_at_the_qwen3_next_cells_shape(v5e, rows):
     assert f"f32[{rows},32,128,128,128]" in text
 
 
+@pytest.mark.parametrize("rows", [2, 1])
+def test_ssd_scan_kernels_at_the_nemotron_cells_shape(v5e, rows):
+    """The state-space scan of ``nemotron-3-nano-30b-a3b.seq8192``'s Mamba
+    layers: 64 heads of 64 over 8 groups of a 128-wide state, 8,192
+    positions in bf16, two timed rows and the comparison's one. ``ssd_fwd``
+    and ``ssd_bwd`` lower and fit Mosaic's scoped VMEM in the tile the
+    module gives; two layers share one staged forward and one staged
+    backward, and the compiled program calls each kernel by its name with
+    the scope ``ssd_scan`` (which ``F.ssd_scan`` opens: its gate asks the
+    running backend, the CPU here) in front, forward and transposed: what
+    ``ssd_scan_ms_per_step`` and ``mamba_ms_per_step`` find them by."""
+    import re
+
+    from paddle_tpu.ops.pallas import ssd_scan
+
+    x_shape, bc_shape = (rows, 8192, 64, 64), (rows, 8192, 8, 128)
+    assert ssd_scan.supported(x_shape, bc_shape, jnp.bfloat16, 128)
+
+    def f(x, dt, A, B, C, D):
+        def loss(x, dt, A, B, C, D):
+            for _ in range(2):                     # two layers
+                with jax.named_scope("ssd_scan"):
+                    x = ssd_scan.ssd_scan(x, dt, A, B, C, D)
+            return jnp.sum(x.astype(jnp.float32) ** 2)
+        return jax.grad(loss, argnums=tuple(range(6)))(x, dt, A, B, C, D)
+
+    bc = (bc_shape, jnp.bfloat16)
+    head = ((64,), jnp.float32)
+    text = _compile(f, v5e, (x_shape, jnp.bfloat16),
+                    (x_shape[:3], jnp.float32), head, bc, bc, head)
+    for kernel, shared, transform in (
+            ("ssd_fwd", "jit(_fwd)", "jvp("),
+            ("ssd_bwd", "jit(_bwd_call)", "transpose(jvp(")):
+        lines = [line for line in text.splitlines()
+                 if "tpu_custom_call" in line and f"%{kernel}" in line]
+        assert len(lines) == 2, (kernel, len(lines))
+        op_name = re.search(r'op_name="([^"]+)"', lines[0]).group(1)
+        assert op_name == f"jit(f)/{transform}ssd_scan" \
+                          f"{')' * transform.count('(')}/{shared}/{kernel}" \
+                          f"/pallas_call"
+    # the residual: the state entering each chunk, float32, a group's heads
+    # stacked along the rows
+    assert f"f32[{rows},8,64,512,128]" in text
+
+
 @pytest.mark.parametrize("h,dtype", [(768, jnp.bfloat16),
                                      (768, jnp.float32),
                                      (2048, jnp.bfloat16)])

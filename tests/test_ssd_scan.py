@@ -142,7 +142,7 @@ def test_bf16_operands_stay_inside_their_rounding():
     assert err < 2e-2, err
 
 
-def test_staged_calls_are_counted_by_path():
+def test_staged_calls_are_counted_by_path(monkeypatch):
     args = inputs(6, seq=100)
     with telemetry.scope(profile=False) as tel:
         F.ssd_scan(*args, chunk=32)
@@ -150,10 +150,22 @@ def test_staged_calls_are_counted_by_path():
         calls = tel.registry.get("ssd_scan_calls_staged_total")
         assert calls.value(path="chunked") == 1
         assert calls.value(path="recurrent") == 1
+        assert calls.value(path="pallas") == 0
         # 4 chunks of 32 cover 100 positions; the recurrence walks all 100
         assert tel.registry.get("ssd_chunks_total").value() == 104
     with pytest.raises(ValueError, match="unknown path"):
         F.ssd_scan(*args, path="pallas")
+    # on a TPU the kernels' widths (heads of 64, a state of 128, chunks of
+    # 128) stage the Pallas kernels, and the toy widths still XLA's path
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    wide = inputs(6, b=1, seq=256, heads=4, groups=2, p=64, n=128)
+    with telemetry.scope(profile=False) as tel:
+        jax.make_jaxpr(lambda *a: F.ssd_scan(*a))(*wide)
+        jax.make_jaxpr(lambda *a: F.ssd_scan(*a, chunk=32))(*args)
+        calls = tel.registry.get("ssd_scan_calls_staged_total")
+        assert calls.value(path="pallas") == 1
+        assert calls.value(path="chunked") == 1
+        assert calls.value(path="recurrent") == 0
 
 
 # -- the convolution's bias and the gated norm --------------------------------

@@ -369,6 +369,80 @@ def check_gated_delta_precision(interpret):
                        for n, t in GATED_DELTA_PRECISION_TOL.items())}]
 
 
+# ``check_ssd_scan``'s limits, as shares of each reference tensor's norm:
+# between what the kernels read at three bf16 passes a float32 product and
+# what XLA's path reads at one pass, on a layer of the Nemotron cell (PERF.md
+# section 6; a TPU v5e: 6.9e-5 to 9.9e-5 against 2.0e-3 to 2.7e-3 for y, dx,
+# dB, dC; 8.3e-7 and 2.0e-6 against 5.2e-4 and 2.5e-4 for ddt and dA; dD is
+# XLA's sum in both, 3.3e-7)
+SSD_PRECISION_TOL = {"y": 7e-4, "dx": 7e-4, "ddt": 5e-5, "dA": 5e-5,
+                     "dB": 7e-4, "dC": 7e-4, "dD": 1e-5}
+
+
+def check_ssd_scan(interpret):
+    """The state-space scan's kernels on one layer-shaped input of the
+    Nemotron cell (a row of 8,192 positions, 64 heads of 64 over 8 groups
+    of a 128-wide state, bf16; rehearsed on the CPU at 512 positions), on
+    the chip through ``F.ssd_scan``, which takes them there: ``y`` and the
+    six gradients against XLA's chunked path at ``Precision.HIGHEST`` over
+    the same numbers in float32, each as the norm of the difference over
+    the reference's norm."""
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.nn import functional as F
+    from paddle_tpu.nn.functional import linear_attention as la
+    from paddle_tpu.nn.functional import state_space
+    from paddle_tpu.ops.pallas import ssd_scan
+
+    seq, h, g, p = (512, 8, 2, 64) if interpret else (8192, 64, 8, 64)
+    ks = jax.random.split(jax.random.key(40), 7)
+    x = jax.random.normal(ks[0], (1, seq, h, p), jnp.bfloat16)
+    # the layer's step, softplus(dt + dt_bias): 1e-3 to 0.1 at its start
+    dt = jnp.exp(jax.random.uniform(ks[1], (1, seq, h), minval=-6.9,
+                                    maxval=-2.3))
+    A = -jax.random.uniform(ks[2], (h,), minval=1.0, maxval=16.0)
+    B, C = (jax.random.normal(k, (1, seq, g, ssd_scan.LANES), jnp.bfloat16)
+            for k in ks[3:5])
+    D = jax.random.normal(ks[5], (h,))
+    ct = jax.random.normal(ks[6], x.shape, jnp.bfloat16).astype(jnp.float32)
+
+    def scan(fn):
+        def f(*args):
+            out = fn(*args)
+            return jnp.sum(out.astype(jnp.float32) * ct), out
+        return jax.jit(jax.grad(f, argnums=tuple(range(6)), has_aux=True))
+
+    if interpret:
+        got, out = scan(lambda *a: ssd_scan.ssd_scan(*a, interpret=True))(
+            x, dt, A, B, C, D)
+    else:
+        got, out = scan(F.ssd_scan)(x, dt, A, B, C, D)
+
+    def reference(x, dt, A, B, C, D):
+        shape = (1, seq, g, h // g)
+        y = state_space._chunked(
+            jnp.reshape(x, shape + (p,)), jnp.reshape(dt, shape),
+            jnp.reshape(dt * A, shape), B, C, ssd_scan.CHUNK)
+        return jnp.reshape(y, x.shape) + x * D[:, None]
+
+    with mock.patch.object(la, "PRECISION", jax.lax.Precision.HIGHEST), \
+            jax.default_matmul_precision("highest"):
+        want, out_ref = scan(reference)(*(
+            v.astype(jnp.float32) for v in (x, dt, A, B, C, D)))
+    rel = {}
+    for name, a, w in zip(SSD_PRECISION_TOL, (out,) + got,
+                          (out_ref,) + want):
+        # the reference rounded as the kernels round what they write
+        a, w = a.astype(jnp.float32), w.astype(a.dtype).astype(jnp.float32)
+        rel[name] = float(jnp.linalg.norm(a - w) / jnp.linalg.norm(w))
+    return [{"check": "ssd_scan_kernels_vs_xla_highest", "rel_l2": rel,
+             "tol": SSD_PRECISION_TOL,
+             "ok": all(rel[n] < t for n, t in SSD_PRECISION_TOL.items())}]
+
+
 # ``check_latent_attention``'s limits: the loss as a relative difference,
 # the gradients as |g - g_ref| / |g_ref| in the 2-norm, worst leaf and
 # median leaf, the program in bf16 on the chip's kernels against the same
@@ -598,7 +672,7 @@ def main():
     checks = []
     for fn in (check_flash_attention, check_flash_tile_kinds, check_rope,
                check_linear_attention, check_gated_delta_precision,
-               check_fused_ce, check_paged_attention):
+               check_ssd_scan, check_fused_ce, check_paged_attention):
         checks.extend(fn(interpret))
     for fn in (check_chunked_ce, check_bf16_matmul):
         checks.extend(fn())
