@@ -34,7 +34,10 @@ Phases (each through the entry points a user calls, weights from a seed):
   rule's kernels on a layer-shaped row of 8,192 positions against XLA's
   chunked path at ``Precision.HIGHEST`` (the precision guard: a float32
   product that fell to one bf16 pass fails here and nowhere in the
-  benchmark), fused LM-head CE fwd/bwd at the bench
+  benchmark), the expert layer's grouped products on the kernels at the
+  Nemotron cell's widths against ``lax.ragged_dot`` at ``HIGHEST``
+  (``moe_grouped_calls_staged_total`` says the layer took them), fused
+  LM-head CE fwd/bwd at the bench
   shape against the chunked scan, paged decode and Tq=5 verify at
   h12/d64/page 16 bf16 against the XLA gather.
 - ``serve``  — ``DecodeServer`` over ``PagedKVCache`` with
@@ -257,6 +260,14 @@ def _ssd_scan_calls(registry):
             for path in ("pallas", "chunked", "recurrent")} if staged else {}
 
 
+def _grouped_calls(registry):
+    """``moe_grouped_calls_staged_total`` by path, as the registry holds
+    it."""
+    staged = registry.get("moe_grouped_calls_staged_total")
+    return {path: int(staged.value(path=path))
+            for path in ("pallas", "xla")} if staged else {}
+
+
 def _latent_attn_calls(registry):
     """``latent_attn_calls_staged_total`` by the queries' rotation, the
     rotations and flash tiles those calls staged and the two loss gauges
@@ -421,6 +432,9 @@ def phase_kernels(run: Run):
         checks += ns.check_ssd_scan(run.interpret)
         ssd = _ssd_scan_calls(tel.registry)
     with telemetry.scope(profile=False) as tel:
+        checks += ns.check_grouped_matmul(run.interpret)
+        grouped = _grouped_calls(tel.registry)
+    with telemetry.scope(profile=False) as tel:
         checks += ns.check_latent_attention(run.interpret)
         latent = _latent_attn_calls(tel.registry)
     checks += (ns.check_flash_attention(run.interpret)
@@ -441,7 +455,8 @@ def phase_kernels(run: Run):
             flash_tiles_staged=flash_tiles, flash_steps_held=flash_held,
             rope_calls_staged=rope_calls, linear_attn_staged=linear_attn,
             linear_model_rope_calls_staged=linear_rope_calls,
-            ssd_scan_staged=ssd, latent_attn_staged=latent)
+            ssd_scan_staged=ssd, grouped_staged=grouped,
+            latent_attn_staged=latent)
     bad = [c["check"] for c in checks if not c["ok"]]
     check(not bad, f"kernel checks out of tolerance: {bad}")
     # on the chip check_rope goes through F.rotary_embedding: two cases
@@ -473,6 +488,12 @@ def phase_kernels(run: Run):
     want = {} if run.rehearsal else {"pallas": 1, "chunked": 0,
                                      "recurrent": 0}
     check(ssd == want, f"ssd_scan staged as {ssd}, expected {want}")
+    # check_grouped_matmul: the expert layer staged once by its gate (the
+    # kernels: on the chip at the Nemotron cell's widths, rehearsed
+    # interpreted) and once on ragged_dot beside it
+    want = {"pallas": 1, "xla": 1}
+    check(grouped == want,
+          f"the grouped experts staged as {grouped}, expected {want}")
     # check_latent_attention: the trunk's block and the module's, each
     # staged once on the kernels' path (q through the rotary kernel, the one
     # rotary key head through XLA) and once more for the float32 reference,

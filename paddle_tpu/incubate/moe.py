@@ -16,7 +16,9 @@ experts batched, and all_to_all's back. Its dispatch and combine tensors are
 ``DroplessMoELayer`` (ISSUE 27) is the layer for hundreds of experts: a
 top-k router over all of them, a layer that is told which experts it holds,
 sorted (token, expert) assignments and grouped matrix products
-(``jax.lax.ragged_dot``), a shared expert, and no dropped token. It computes
+(``jax.lax.ragged_dot``, or the kernels of ``ops/pallas/grouped_matmul.py``
+where the widths would give its kernels narrow tiles), a shared expert, and
+no dropped token. It computes
 its own experts' part of the result; the exchange that would bring other
 chips' tokens to them is not written yet.
 """
@@ -224,11 +226,35 @@ def route_top_k(logits, top_k, scoring="sigmoid", scaling_factor=1.0,
 ACTIVATIONS = {"silu": GatedSiluFFN, "relu2": SquaredReluFFN}
 
 
+def grouped_path(d_model, d_expert, dtype, interpret=False):
+    """The path of a layer's grouped products: ``"pallas"`` (the kernels of
+    ``ops/pallas/grouped_matmul.py``) on a TPU, or in interpret mode, where
+    ``grouped_matmul.supported`` takes the widths, which is where the
+    compiler's ``ragged_dot`` kernels would fall to 128-wide tiles;
+    ``"xla"`` (``lax.ragged_dot``) everywhere else."""
+    from ..ops.pallas import grouped_matmul
+    on = interpret or jax.default_backend() == "tpu"
+    return "pallas" if on and grouped_matmul.supported(
+        d_model, d_expert, dtype) else "xla"
+
+
+def _count_staged(path: str):
+    """``moe_grouped_calls_staged_total{path=pallas|xla}``: one call of the
+    grouped experts being staged."""
+    from .. import telemetry
+    if telemetry.enabled():
+        telemetry.counter(
+            "moe_grouped_calls_staged_total",
+            "Staged calls of the grouped experts, by the path their "
+            "products took").inc(1, path=path)
+
+
 class GroupedExperts(Layer):
     """``count`` FFNs with stacked weights, applied to rows that are sorted
     by expert. ``activation="silu"``: gated SiLU, ``down(silu(gate(x)) *
     up(x))``, three grouped matrix products; ``"relu2"``: ``down(relu(up(x))
-    ^ 2)``, two, and no ``gate_proj``."""
+    ^ 2)``, two, and no ``gate_proj``. The products take ``grouped_path``'s
+    path; ``interpret`` (tests) runs the kernels interpreted off the TPU."""
 
     def __init__(self, count, d_model, d_expert, activation="silu"):
         super().__init__()
@@ -236,6 +262,7 @@ class GroupedExperts(Layer):
         if activation not in ACTIVATIONS:
             raise ValueError(f"unknown expert activation {activation!r}")
         self.activation = activation
+        self.interpret = False
         wide = XavierUniform(fan_in=d_model, fan_out=d_expert)
         if activation == "silu":
             self.gate_proj = self.create_parameter(
@@ -251,13 +278,24 @@ class GroupedExperts(Layer):
         expert 0 and so on. Rows past ``sum(group_sizes)`` belong to no
         group: on the TPU the kernels do not write them, in the result or
         in its gradient, so the caller masks them."""
+        from ..ops.pallas.grouped_matmul import grouped_matmul
         dt = rows.dtype
-        gate = None if self.activation == "relu2" else lax.ragged_dot(
-            rows, self.gate_proj.value.astype(dt), group_sizes)
-        up = lax.ragged_dot(rows, self.up_proj.value.astype(dt), group_sizes)
+        d_expert, d_model = self.down_proj.value.shape[1:]
+        path = grouped_path(d_model, d_expert, dt, self.interpret)
+        _count_staged(path)
+
+        def product(x, w):
+            w = w.value.astype(dt)
+            if path == "pallas":
+                return grouped_matmul(x, w, group_sizes,
+                                      interpret=self.interpret)
+            return lax.ragged_dot(x, w, group_sizes)
+
+        gate = None if self.activation == "relu2" else product(
+            rows, self.gate_proj)
+        up = product(rows, self.up_proj)
         hidden = jnp.square(F.relu(up)) if gate is None else F.silu(gate) * up
-        return lax.ragged_dot(hidden, self.down_proj.value.astype(dt),
-                              group_sizes)
+        return product(hidden, self.down_proj)
 
 
 

@@ -104,6 +104,10 @@ ssd_scan_calls_staged_total    counter    nn.functional.ssd_scan, where a
 ssd_chunks_total               counter    chunk states a row of those calls
                                           walks one after another (seq /
                                           chunk; seq on the recurrent path)
+moe_grouped_calls_staged_total counter    incubate.moe GroupedExperts,
+                                          where a call is staged: the path
+                                          its grouped products took
+                                          {path=pallas|xla}
 moe_tokens_routed_total        counter    incubate.moe DroplessMoELayer.
                                           publish_routing: tokens routed
 moe_held_assignments_total     counter    (token, expert) assignments on

@@ -496,3 +496,43 @@ def test_paged_attention_decode_and_verify(v5e, tq):
     pool = ((pages, h, ps, d), jnp.bfloat16)
     _compile(f, v5e, tok, pool, pool, ((b, width), jnp.int32),
              ((b,), jnp.int32), tok, tok)
+
+
+def test_grouped_matmul_kernels_at_the_nemotron_cells_shape(v5e):
+    """The grouped products of ``nemotron-3-nano-30b-a3b.seq8192``'s expert
+    layers: 12,288 rows (the part of the buffer that always runs) over 8
+    held experts, ``up`` 2,688 -> 1,856 and ``down`` 1,856 -> 2,688 with
+    the squared ReLU between, bf16. ``gmm`` (both products and, transposed,
+    the rows' gradients) and ``tgmm`` (the weights') lower and fit Mosaic's
+    scoped VMEM in the tiles the module gives, and the compiled program
+    calls each by its name under the scope the layer stages it in, which
+    ``moe_ms_per_step`` reads: forward and transposed."""
+    import re
+
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+
+    rows, d, e, groups = 12288, 2688, 1856, 8
+
+    def f(x, up, down, sizes):
+        def loss(x, up, down):
+            with jax.named_scope("experts"):
+                h = gm.grouped_matmul(x, up, sizes)
+                y = gm.grouped_matmul(jnp.square(jnp.maximum(h, 0)), down,
+                                      sizes)
+            return jnp.sum(y.astype(jnp.float32) ** 2)
+        return jax.grad(loss, argnums=(0, 1, 2))(x, up, down)
+
+    text = _compile(f, v5e, ((rows, d), jnp.bfloat16),
+                    ((groups, d, e), jnp.bfloat16),
+                    ((groups, e, d), jnp.bfloat16), ((groups,), jnp.int32))
+    for kernel, calls in (("gmm", 4), ("tgmm", 2)):
+        lines = [line for line in text.splitlines()
+                 if "tpu_custom_call" in line and f"%{kernel}" in line]
+        assert len(lines) == calls, (kernel, len(lines))
+        for line in lines:
+            op_name = re.search(r'op_name="([^"]+)"', line).group(1)
+            assert re.search(r"(jvp|transpose)\(.*experts", op_name) \
+                and op_name.endswith(f"/{kernel}/pallas_call"), op_name
+    # the weights' gradients in their own dtype and shape
+    assert f"bf16[{groups},{d},{e}]" in text
+    assert f"bf16[{groups},{e},{d}]" in text

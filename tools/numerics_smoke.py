@@ -443,6 +443,93 @@ def check_ssd_scan(interpret):
              "ok": all(rel[n] < t for n, t in SSD_PRECISION_TOL.items())}]
 
 
+# ``check_grouped_matmul``'s limits, as shares of each reference tensor's
+# norm (the held rows'): the kernels and the compiler's ``ragged_dot`` both
+# take bf16 operands, accumulate in float32 and write bf16, so both read the
+# rounding of their bf16 results
+GROUPED_PRECISION_TOL = {"y": 1e-2, "drows": 1e-2, "dup": 1e-2,
+                         "ddown": 1e-2}
+
+
+def check_grouped_matmul(interpret):
+    """The expert layer's grouped products on one layer-shaped input of the
+    Nemotron cell (12,288 rows, 6,144 of them held over 8 experts, 2,688 ->
+    1,856 -> 2,688 with the squared ReLU between, bf16; rehearsed on the
+    CPU at 512 rows and widths 256 / 232), through ``GroupedExperts``,
+    which takes the kernels on the chip by its gate and rehearsed
+    interpreted: the output and the gradients of the rows and both weights
+    against ``lax.ragged_dot`` at ``Precision.HIGHEST`` over the same
+    numbers in float32, each as the norm of the difference over the
+    reference's norm; the compiler's ``ragged_dot`` in bf16 read beside
+    them (``ragged_dot_rel_l2``)."""
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+
+    from paddle_tpu.incubate import moe
+    from paddle_tpu.jit.functionalization import functional_call
+
+    rows, d, e, groups, held = (512, 256, 232, 8, 256) if interpret \
+        else (12288, 2688, 1856, 8, 6144)
+    rng = np.random.default_rng(41)
+    sizes = jnp.asarray(np.bincount(rng.integers(0, groups, held),
+                                    minlength=groups), jnp.int32)
+    ks = jax.random.split(jax.random.key(41), 4)
+    keep = (jnp.arange(rows) < held)[:, None]
+    x = jnp.where(keep, jax.random.normal(ks[0], (rows, d)), 0).astype(
+        jnp.bfloat16)
+    params = {"up_proj": jax.random.normal(ks[1], (groups, d, e)) / d ** 0.5,
+              "down_proj": jax.random.normal(ks[2], (groups, e, d)) / e ** 0.5}
+    params = {n: v.astype(jnp.bfloat16) for n, v in params.items()}
+    ct = jax.random.normal(ks[3], (rows, d), jnp.bfloat16).astype(jnp.float32)
+    experts = moe.GroupedExperts(groups, d, e, "relu2")
+    experts.interpret = interpret
+
+    def grads(fn):
+        def f(p, x):
+            out = jnp.where(keep, fn(p, x), 0)
+            return jnp.sum(out.astype(jnp.float32) * ct), out
+        return jax.jit(jax.grad(f, argnums=(0, 1), has_aux=True))
+
+    def layer(p, x):
+        return functional_call(experts, p, {}, x, sizes)[0]
+
+    def reference(p, x):
+        h = lax.ragged_dot(x, p["up_proj"], sizes,
+                           precision=lax.Precision.HIGHEST)
+        return lax.ragged_dot(jnp.square(jnp.maximum(h, 0)), p["down_proj"],
+                              sizes, precision=lax.Precision.HIGHEST)
+
+    def read(params, x, fn):
+        (dp, dx), out = grads(fn)(params, x)
+        return {"y": out, "drows": dx, "dup": dp["up_proj"],
+                "ddown": dp["down_proj"]}
+
+    got = read(params, x, layer)
+    with mock.patch.object(moe, "grouped_path", lambda *a: "xla"):
+        compiler = read(params, x, layer)
+    want = read({n: v.astype(jnp.float32) for n, v in params.items()},
+                x.astype(jnp.float32), reference)
+
+    def rel(a):
+        out = {}
+        for name, w in want.items():
+            v = a[name].astype(jnp.float32)
+            if name in ("y", "drows"):
+                v, w = jnp.where(keep, v, 0), jnp.where(keep, w, 0)
+            out[name] = float(jnp.linalg.norm(v - w) / jnp.linalg.norm(w))
+        return out
+
+    mine = rel(got)
+    return [{"check": "grouped_matmul_kernels_vs_ragged_dot_highest",
+             "rel_l2": mine, "ragged_dot_rel_l2": rel(compiler),
+             "tol": GROUPED_PRECISION_TOL,
+             "ok": all(mine[n] < t for n, t in GROUPED_PRECISION_TOL.items())}]
+
+
 # ``check_latent_attention``'s limits: the loss as a relative difference,
 # the gradients as |g - g_ref| / |g_ref| in the 2-norm, worst leaf and
 # median leaf, the program in bf16 on the chip's kernels against the same
@@ -672,7 +759,8 @@ def main():
     checks = []
     for fn in (check_flash_attention, check_flash_tile_kinds, check_rope,
                check_linear_attention, check_gated_delta_precision,
-               check_ssd_scan, check_fused_ce, check_paged_attention):
+               check_ssd_scan, check_grouped_matmul, check_fused_ce,
+               check_paged_attention):
         checks.extend(fn(interpret))
     for fn in (check_chunked_ce, check_bf16_matmul):
         checks.extend(fn())
