@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _staged
 from paddle_tpu import nn
 from paddle_tpu.nn import functional as F
 from paddle_tpu.nn.functional.attention import _xla_attention
@@ -138,23 +139,7 @@ def test_the_cells_geometry_runs_80_tiles_of_256():
     assert sum(want[512][fa.FWD]) == 80 and (8192 // 512) ** 2 == 256
 
 
-@pytest.fixture
-def tile_counter():
-    """A fresh telemetry registry and the staged kernels' caches dropped
-    (tests/test_flash_attention_extras.py has the same)."""
-    from paddle_tpu import telemetry
-    from paddle_tpu.telemetry.metrics import Registry
-    prev, reg = telemetry.get_registry(), Registry()
-    telemetry._set_registry(reg)
-    telemetry.enable()
-    fa._fwd.clear_cache()
-    fa._bwd_calls.clear_cache()
-    yield lambda: reg.get("flash_tiles_staged_total")
-    telemetry.disable()
-    telemetry._set_registry(prev)
-
-
-def test_staging_counts_the_tiles(tile_counter):
+def test_staging_counts_the_tiles(flash_staged):
     """``flash_tiles_staged_total{kernel, kind}`` of one staged call at the
     cell's geometry (traced, not run): 80 tiles a kernel."""
     q = jax.ShapeDtypeStruct((1, 8192, 2, 128), jnp.bfloat16)
@@ -166,7 +151,7 @@ def test_staging_counts_the_tiles(tile_counter):
             interpret=True).astype(jnp.float32))
 
     jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)), q, k, k)
-    counter = tile_counter()
+    counter = flash_staged("flash_tiles_staged_total")
     got = {kernel: tuple(int(counter.value(kernel=kernel, kind=kind))
                          for kind in fa.TILE_KINDS)
            for kernel in fa.KERNEL_NAMES}
@@ -200,8 +185,8 @@ def test_flash_kernels_under_the_mask(case):
                                   block_q=tile, block_k=tile, interpret=True)
 
     def both(fn):
-        out, vjp = jax.vjp(fn, q, k, v)
-        return (out,) + vjp(do)
+        out, grads = _staged.out_and_grads(fn, q, k, v, ct=do)
+        return (out,) + grads
 
     got = both(flash)
     want = both(lambda q, k, v: plain_attention(q, k, v, seen))
@@ -217,8 +202,8 @@ def test_xla_path_under_the_mask(half, block, heads, kv_heads):
     seen = four_rules(half, block)
 
     def both(fn):
-        out, vjp = jax.vjp(fn, q, k, v)
-        return (out,) + vjp(do)
+        out, grads = _staged.out_and_grads(fn, q, k, v, ct=do)
+        return (out,) + grads
 
     got = both(lambda q, k, v: _xla_attention(q, k, v, block_diffusion=block))
     sdpa = both(lambda q, k, v: F.scaled_dot_product_attention(
@@ -450,9 +435,10 @@ def test_positions_and_mask_reach_every_layer():
     decoder = model.decoder
     clean = jax.random.randint(jax.random.key(1), (1, 16), 0, 63)
     positions = jnp.tile(jnp.arange(16), 2)
+    decode = _staged.forward(decoder, block_diffusion=4)  # one program
 
     def run(noised, clean):
-        return decoder(jnp.concatenate([noised, clean], 1), positions, 4)
+        return decode(jnp.concatenate([noised, clean], 1), positions)
 
     a = run(jnp.full((1, 16), 63), clean)
     b = run(jax.random.randint(jax.random.key(2), (1, 16), 0, 63), clean)

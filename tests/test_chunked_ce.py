@@ -6,6 +6,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import _staged
 from paddle_tpu.ops.chunked_ce import chunked_lm_ce
 
 N, H, V = 24, 16, 1000   # V deliberately not a multiple of chunk
@@ -42,9 +43,8 @@ def test_forward_matches_dense(chunk):
 
 def test_grads_match_dense():
     hid, w, y = _data(1)
-    ga = jax.grad(lambda h, w: chunked_lm_ce(h, w, y, 256),
-                  argnums=(0, 1))(hid, w)
-    gb = jax.grad(lambda h, w: _dense_ce(h, w, y), argnums=(0, 1))(hid, w)
+    ga = _staged.grads(lambda h, w: chunked_lm_ce(h, w, y, 256), hid, w)
+    gb = _staged.grads(lambda h, w: _dense_ce(h, w, y), hid, w)
     np.testing.assert_allclose(np.asarray(ga[0]), np.asarray(gb[0]),
                                rtol=2e-5, atol=1e-7)
     np.testing.assert_allclose(np.asarray(ga[1]), np.asarray(gb[1]),
@@ -58,8 +58,7 @@ def test_ignore_index_and_bf16():
     assert a == pytest.approx(b, rel=1e-6)
     # bf16 inputs: fp32 accumulation inside, grads in bf16
     hb, wb = hid.astype(jnp.bfloat16), w.astype(jnp.bfloat16)
-    gh, gw = jax.grad(lambda h, w: chunked_lm_ce(h, w, y, 256),
-                      argnums=(0, 1))(hb, wb)
+    gh, gw = _staged.grads(lambda h, w: chunked_lm_ce(h, w, y, 256), hb, wb)
     assert gh.dtype == jnp.bfloat16 and gw.dtype == jnp.bfloat16
     ref = float(_dense_ce(hb, wb, y))
     assert float(chunked_lm_ce(hb, wb, y, 256)) == \

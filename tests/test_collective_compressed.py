@@ -51,8 +51,9 @@ def spmd(fn, *arrays, n=N, out_specs=None):
                      for a in arrays)
     if out_specs is None:
         out_specs = in_specs[0]
-    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)(*arrays)
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs,
+                                 check_vma=False))(*arrays)
 
 
 # ---------------------------------------------------------------------------
@@ -124,11 +125,7 @@ class TestCollectiveRoundTrips:
         # of the rank-sum -> global out (N, k)
         rng = np.random.RandomState(2)
         x = rng.randn(N * N, 3).astype(np.float32)
-        mesh = build_mesh({"data": N})
-        out = jax.shard_map(
-            lambda v: collective.reduce_scatter(v),
-            mesh=mesh, in_specs=P("data", None), out_specs=P("data", None),
-            check_vma=False)(jnp.asarray(x))
+        out = spmd(lambda v: collective.reduce_scatter(v), jnp.asarray(x))
         xr = x.reshape(N, N, 3)           # [rank, block, k]
         want = xr.sum(axis=0)             # [block, k]
         np.testing.assert_allclose(np.asarray(out), want, rtol=1e-5)
@@ -238,8 +235,9 @@ def _tree_mean_spmd(tree_stacked, policy, block=32, bucket_bytes=4 << 20,
                                        bucket_bytes=bucket_bytes)
         return jax.tree_util.tree_map(lambda v: v[None], mean)
 
-    return jax.shard_map(f, mesh=mesh, in_specs=(specs,),
-                         out_specs=specs, check_vma=False)(tree_stacked)
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(specs,),
+                                 out_specs=specs,
+                                 check_vma=False))(tree_stacked)
 
 
 class TestCompressedTreeMean:
@@ -464,10 +462,10 @@ class TestDataParallelWrapper:
         rng = np.random.RandomState(0)
         g = rng.randn(N, 32).astype(np.float32)
 
-        out = jax.shard_map(
+        out = jax.jit(jax.shard_map(
             lambda v: dp.sync_gradients({"g": v[0]})["g"][None],
             mesh=mesh, in_specs=P("data", None),
-            out_specs=P("data", None), check_vma=False)(jnp.asarray(g))
+            out_specs=P("data", None), check_vma=False))(jnp.asarray(g))
         want = g.mean(axis=0)
         for i in range(N):
             np.testing.assert_allclose(np.asarray(out)[i], want,
@@ -483,15 +481,14 @@ class TestDataParallelWrapper:
             with dp.no_sync():
                 return dp.sync_gradients({"g": v[0]})["g"][None]
 
-        out = jax.shard_map(f, mesh=mesh, in_specs=P("data", None),
-                            out_specs=P("data", None),
-                            check_vma=False)(jnp.asarray(g))
+        out = jax.jit(jax.shard_map(
+            f, mesh=mesh, in_specs=P("data", None),
+            out_specs=P("data", None), check_vma=False))(jnp.asarray(g))
         np.testing.assert_array_equal(np.asarray(out), g)
 
 
 class TestFleetUtils:
     def test_fused_allreduce_exact_and_compressed(self):
-        mesh = build_mesh({"data": N})
         rng = np.random.RandomState(0)
         g = rng.randn(N, 128).astype(np.float32)
         want = g.mean(axis=0)
@@ -499,9 +496,7 @@ class TestFleetUtils:
         def f32(v):
             return fused_allreduce_gradients({"g": v[0]})["g"][None]
 
-        out = jax.shard_map(f32, mesh=mesh, in_specs=P("data", None),
-                            out_specs=P("data", None),
-                            check_vma=False)(jnp.asarray(g))
+        out = spmd(f32, jnp.asarray(g))
         for i in range(N):
             np.testing.assert_allclose(np.asarray(out)[i], want, rtol=1e-6)
 
@@ -511,10 +506,8 @@ class TestFleetUtils:
                 residuals={"g": jnp.zeros_like(v[0])})
             return grads["g"][None], res["g"][None]
 
-        got, res = jax.shard_map(
-            fi8, mesh=mesh, in_specs=P("data", None),
-            out_specs=(P("data", None), P("data", None)),
-            check_vma=False)(jnp.asarray(g))
+        got, res = spmd(fi8, jnp.asarray(g),
+                        out_specs=(P("data", None), P("data", None)))
         scale = np.abs(want).max()
         assert np.abs(np.asarray(got)[0] - want).max() < 0.05 * scale
         assert np.abs(np.asarray(res)).max() > 0
@@ -725,10 +718,10 @@ class TestPerAxisPolicy:
                 {"g": x[0]}, ("data", "model"), policy=policy, block=32)
             return mean["g"][None]
 
-        out = np.asarray(jax.shard_map(
+        out = np.asarray(jax.jit(jax.shard_map(
             f, mesh=mesh, in_specs=P(("data", "model"), None),
             out_specs=P(("data", "model"), None),
-            check_vma=False)(jnp.asarray(g)))
+            check_vma=False))(jnp.asarray(g)))
         want = g.mean(axis=0)
         scale = np.abs(want).max()
         assert np.abs(out[0] - want).max() < 0.25 * scale
@@ -738,16 +731,13 @@ class TestPerAxisPolicy:
     def test_all_fp32_mapping_is_exact(self):
         rng = np.random.RandomState(3)
         g = rng.randn(N, 64).astype(np.float32)
-        mesh = build_mesh({"data": N})
 
         def f(x):
             mean, _ = compressed_tree_mean(
                 {"g": x[0]}, "data", policy={"other": "int4"})
             return mean["g"][None]
 
-        out = np.asarray(jax.shard_map(
-            f, mesh=mesh, in_specs=P("data", None),
-            out_specs=P("data", None), check_vma=False)(jnp.asarray(g)))
+        out = np.asarray(spmd(f, jnp.asarray(g)))
         for i in range(N):
             np.testing.assert_allclose(out[i], g.mean(axis=0), rtol=1e-6)
 
@@ -760,17 +750,13 @@ class TestCompressedPsumScatter:
     def _run(self, policy, block=32):
         rng = np.random.RandomState(5)
         x = rng.randn(N, 2 * N, 6).astype(np.float32)  # per-rank (2N, 6)
-        mesh = build_mesh({"data": N})
 
         def f(v):
             s = compressed_psum_scatter(v[0], "data", scatter_dim=0,
                                         policy=policy, block=block)
             return s[None]
 
-        out = jax.shard_map(f, mesh=mesh,
-                            in_specs=P("data", None, None),
-                            out_specs=P("data", None, None),
-                            check_vma=False)(jnp.asarray(x))
+        out = spmd(f, jnp.asarray(x))
         # rank i keeps chunk i of the rank-sum -> global out == full sum
         got = np.asarray(out).reshape(2 * N, 6)
         want = x.sum(axis=0)
@@ -793,33 +779,25 @@ class TestCompressedPsumScatter:
     def test_scatter_dim_one(self):
         rng = np.random.RandomState(6)
         x = rng.randn(N, 6, 2 * N).astype(np.float32)
-        mesh = build_mesh({"data": N})
 
         def f(v):
             s = compressed_psum_scatter(v[0], "data", scatter_dim=1,
                                         policy="int8", block=16)
             return s[None]
 
-        out = jax.shard_map(f, mesh=mesh,
-                            in_specs=P("data", None, None),
-                            out_specs=P("data", None, None),
-                            check_vma=False)(jnp.asarray(x))
+        out = spmd(f, jnp.asarray(x))
         got = np.concatenate(list(np.asarray(out)), axis=1)
         want = x.sum(axis=0)
         scale = np.abs(want).max()
         assert np.abs(got - want).max() < 0.05 * scale
 
     def test_indivisible_scatter_dim_rejected(self):
-        mesh = build_mesh({"data": N})
-
         def f(v):
             return compressed_psum_scatter(v[0], "data",
                                            policy="int8")[None]
 
         with pytest.raises(ValueError):
-            jax.shard_map(f, mesh=mesh, in_specs=P("data", None, None),
-                          out_specs=P("data", None, None),
-                          check_vma=False)(jnp.zeros((N, N + 1, 4)))
+            spmd(f, jnp.zeros((N, N + 1, 4)))
 
     @pytest.mark.parametrize("policy", ["int8", "int4"])
     def test_zero2_training_with_compressed_leaves(self, policy):

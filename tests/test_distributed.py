@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+import _staged
 import paddle_tpu as paddle
 from paddle_tpu import nn
 from paddle_tpu.distributed.mesh import (CommunicateTopology,
@@ -444,12 +445,11 @@ class TestFlashAttentionInterpret:
         q = jnp.asarray(rs.randn(1, 128, 1, 64), dtype=jnp.float32)
         k = jnp.asarray(rs.randn(1, 128, 1, 64), dtype=jnp.float32)
         v = jnp.asarray(rs.randn(1, 128, 1, 64), dtype=jnp.float32)
-        gf = jax.grad(lambda a, b, c: jnp.sum(
-            flash_attention(a, b, c, causal=True, interpret=True, block_q=128, block_k=128) ** 2),
-            argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(lambda a, b, c: jnp.sum(
-            _xla_attention(a, b, c, causal=True) ** 2),
-            argnums=(0, 1, 2))(q, k, v)
+        gf = _staged.grads(lambda a, b, c: jnp.sum(flash_attention(
+            a, b, c, causal=True, interpret=True, block_q=128,
+            block_k=128) ** 2), q, k, v)
+        gr = _staged.grads(lambda a, b, c: jnp.sum(
+            _xla_attention(a, b, c, causal=True) ** 2), q, k, v)
         for a, b in zip(gf, gr):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-3, atol=1e-3)

@@ -1,12 +1,15 @@
 """Round-3 flash-attention widening (verdict item 5): ragged tails,
 per-batch KV padding masks, and in-kernel dropout — all checked against the
 XLA reference via the Pallas interpreter on CPU."""
+import functools
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
+import _staged
 from paddle_tpu.nn.functional.attention import _xla_attention
 from paddle_tpu.ops.pallas.flash_attention import flash_attention
 
@@ -25,9 +28,10 @@ class TestKvLensMask:
         mask = (jnp.arange(256)[None, None, None, :] <
                 lens.reshape(-1, 1, 1, 1))
         for causal in (False, True):
-            out = flash_attention(q, k, v, causal=causal, kv_lens=lens,
-                                  interpret=True)
-            ref = _xla_attention(q, k, v, mask=mask, causal=causal)
+            out, ref = _staged.run(lambda q, k, v: (
+                flash_attention(q, k, v, causal=causal, kv_lens=lens,
+                                interpret=True),
+                _xla_attention(q, k, v, mask=mask, causal=causal)), q, k, v)
             np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                        rtol=2e-3, atol=2e-3)
 
@@ -37,11 +41,11 @@ class TestKvLensMask:
         lens = jnp.asarray([100], jnp.int32)
         mask = (jnp.arange(128)[None, None, None, :] <
                 lens.reshape(-1, 1, 1, 1))
-        gf = jax.grad(lambda a, b_, c: jnp.sum(flash_attention(
+        gf = _staged.grads(lambda a, b_, c: jnp.sum(flash_attention(
             a, b_, c, kv_lens=lens, interpret=True,
-            block_q=128, block_k=128) ** 2), argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(lambda a, b_, c: jnp.sum(_xla_attention(
-            a, b_, c, mask=mask) ** 2), argnums=(0, 1, 2))(q, k, v)
+            block_q=128, block_k=128) ** 2), q, k, v)
+        gr = _staged.grads(lambda a, b_, c: jnp.sum(_xla_attention(
+            a, b_, c, mask=mask) ** 2), q, k, v)
         for a, b_ in zip(gf, gr):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                        rtol=2e-3, atol=2e-3)
@@ -53,14 +57,10 @@ class TestKvLensMask:
         rs = np.random.RandomState(8)
         q, k, v = _qkv(rs, b=2, s=128, h=1)
         lens = jnp.asarray([0, 128], jnp.int32)
-        out = flash_attention(q, k, v, kv_lens=lens, interpret=True)
+        out, (dk, dv) = _staged.out_and_grads(
+            lambda k_, v_: flash_attention(q, k_, v_, kv_lens=lens,
+                                           interpret=True), k, v)
         np.testing.assert_array_equal(np.asarray(out[0]), 0.0)
-
-        def loss(k_, v_):
-            return jnp.sum(flash_attention(q, k_, v_, kv_lens=lens,
-                                           interpret=True) ** 2)
-
-        dk, dv = jax.grad(loss, argnums=(0, 1))(k, v)
         np.testing.assert_array_equal(np.asarray(dk[0]), 0.0)
         np.testing.assert_array_equal(np.asarray(dv[0]), 0.0)
         assert np.any(np.asarray(dv[1]) != 0.0)
@@ -68,18 +68,18 @@ class TestKvLensMask:
     def test_dropout_rate_one_returns_zeros(self):
         rs = np.random.RandomState(9)
         q, k, v = _qkv(rs, b=1, s=128, h=1)
-        out = flash_attention(q, k, v, dropout_rate=1.0, interpret=True)
+        out = _staged.run(flash_attention, q, k, v, dropout_rate=1.0,
+                          interpret=True)
         np.testing.assert_array_equal(np.asarray(out), 0.0)
 
     def test_ragged_grads_match_xla(self):
         """Padded tail must contribute ZERO gradient."""
         rs = np.random.RandomState(2)
         q, k, v = _qkv(rs, b=1, s=200, h=1)
-        gf = jax.grad(lambda a, b_, c: jnp.sum(flash_attention(
-            a, b_, c, causal=True, interpret=True) ** 2),
-            argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(lambda a, b_, c: jnp.sum(_xla_attention(
-            a, b_, c, causal=True) ** 2), argnums=(0, 1, 2))(q, k, v)
+        gf = _staged.grads(lambda a, b_, c: jnp.sum(flash_attention(
+            a, b_, c, causal=True, interpret=True) ** 2), q, k, v)
+        gr = _staged.grads(lambda a, b_, c: jnp.sum(_xla_attention(
+            a, b_, c, causal=True) ** 2), q, k, v)
         for a, b_ in zip(gf, gr):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                        rtol=2e-3, atol=2e-3)
@@ -119,12 +119,13 @@ class TestKernelDropout:
         rs = np.random.RandomState(5)
         q, k, v = _qkv(rs, b=1, s=128, h=1)
 
+        @jax.jit
         def loss(a):
             return jnp.sum(flash_attention(
                 a, k, v, dropout_rate=0.25, dropout_seed=11,
                 interpret=True, block_q=128, block_k=128) ** 2)
 
-        g = jax.grad(loss)(q)
+        g = _staged.grads(loss, q)
         rs2 = np.random.RandomState(6)
         for _ in range(4):
             d = jnp.asarray(rs2.randn(*q.shape), jnp.float32)
@@ -153,6 +154,7 @@ class TestDispatch:
 # ---------------------------------------------------------------------------
 # bf16 operands: the products run in the dtype the operands arrive in
 # ---------------------------------------------------------------------------
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
 def _kernel_dropout_mask(bh, s, block, rate, seed):
     """The multiplier the kernels apply, rebuilt tile by tile:
     ``_xla_attention`` draws its mask from another generator, the kernels'
@@ -171,16 +173,11 @@ def _dense_dropout_attention(q, k, v, mult):
     return jnp.einsum("bhst,bthd->bshd", probs, v)
 
 
-def _out_and_grads(fn, *qkv):
-    def loss(q, k, v):
-        out = fn(q, k, v)
-        return jnp.sum(out.astype(jnp.float32) ** 2), out
-    grads, out = jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(*qkv)
-    return (out,) + grads
-
-
 def _assert_bf16_close(got, want):
-    for name, a, w in zip(("out", "dq", "dk", "dv"), got, want):
+    """``_staged.out_and_grads``' ``(out, (dq, dk, dv))`` of bf16 operands
+    against those of float32 ones."""
+    for name, a, w in zip(("out", "dq", "dk", "dv"), (got[0],) + got[1],
+                          (want[0],) + want[1]):
         assert a.dtype == jnp.bfloat16, name
         a, w = np.asarray(a, np.float32), np.asarray(w)
         assert np.abs(a - w).max() <= 1e-2 * np.abs(w).max(), name
@@ -239,8 +236,8 @@ def test_bf16_operands_match_float32_reference(case, d):
         return flash_attention(q, k, v, interpret=True, block_q=block,
                                block_k=block, **kw)
 
-    _assert_bf16_close(_out_and_grads(flash, q, k, v),
-                       _out_and_grads(ref, q32, k32, v32))
+    _assert_bf16_close(_staged.out_and_grads(flash, q, k, v),
+                       _staged.out_and_grads(ref, q32, k32, v32))
 
 
 def test_cells_bucket_takes_the_measured_row(tmp_path, monkeypatch):
@@ -264,11 +261,11 @@ def test_cells_bucket_takes_the_measured_row(tmp_path, monkeypatch):
         rs = np.random.RandomState(5)
         q, k, v = (jnp.asarray(rs.randn(1, 1024, 2, 64), jnp.bfloat16)
                    for _ in range(3))
-        got = _out_and_grads(lambda q, k, v: flash_attention(
+        got = _staged.out_and_grads(lambda q, k, v: flash_attention(
             q, k, v, causal=True, interpret=True), q, k, v)
     finally:
         tuner.clear_cache()
-    want = _out_and_grads(
+    want = _staged.out_and_grads(
         lambda q, k, v: _xla_attention(q, k, v, causal=True),
         *(x.astype(jnp.float32) for x in (q, k, v)))
     _assert_bf16_close(got, want)
@@ -383,17 +380,15 @@ def test_lane_blocks_match_xla(b, s, h, h_kv, d, window, kv_lens, blocks):
                 kw["kv_lens"].reshape(-1, 1, 1, 1))
 
     def flash(q, k, v):
-        out = flash_attention(q, k, v, causal=True, window=window,
-                              block_q=blocks[0], block_k=blocks[1],
-                              interpret=True, **kw)
-        return jnp.sum(do * out), out
+        return flash_attention(q, k, v, causal=True, window=window,
+                               block_q=blocks[0], block_k=blocks[1],
+                               interpret=True, **kw)
 
     def xla(q, k, v):
-        out = _xla_attention(q, k, v, causal=True, window=window, mask=mask)
-        return jnp.sum(do * out), out
+        return _xla_attention(q, k, v, causal=True, window=window, mask=mask)
 
-    got, out = jax.grad(flash, argnums=(0, 1, 2), has_aux=True)(q, k, v)
-    want, ref = jax.grad(xla, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    out, got = _staged.out_and_grads(flash, q, k, v, ct=do)
+    ref, want = _staged.out_and_grads(xla, q, k, v, ct=do)
     assert out.shape == q.shape and out.dtype == q.dtype
     np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-4)
     for name, a, w in zip(("dq", "dk", "dv"), got, want):
@@ -407,10 +402,10 @@ def test_lane_blocks_bf16_match_float32_reference(h, h_kv):
     """The cells' dtype at width 64: bf16 operands through a lane block of
     two heads, under the tolerance of the test above it was derived for."""
     q, k, v, _ = _layout_case(5, 1, 256, h, h_kv, 64, jnp.bfloat16)
-    got = _out_and_grads(lambda q, k, v: flash_attention(
+    got = _staged.out_and_grads(lambda q, k, v: flash_attention(
         q, k, v, causal=True, interpret=True, block_q=128, block_k=128),
         q, k, v)
-    want = _out_and_grads(
+    want = _staged.out_and_grads(
         lambda q, k, v: _xla_attention(q, k, v, causal=True),
         *(x.astype(jnp.float32) for x in (q, k, v)))
     _assert_bf16_close(got, want)
@@ -580,28 +575,8 @@ _TILE_CASES = {
 }
 
 
-@pytest.fixture
-def tile_counter():
-    """A fresh telemetry registry, and the two staged functions' caches
-    dropped: the tiles are counted where a kernel is staged, and a shape an
-    earlier test staged would count nothing."""
-    import importlib
-
-    from paddle_tpu import telemetry
-    from paddle_tpu.telemetry.metrics import Registry
-    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
-    prev, reg = telemetry.get_registry(), Registry()
-    telemetry._set_registry(reg)
-    telemetry.enable()
-    fa._fwd.clear_cache()
-    fa._bwd_calls.clear_cache()
-    yield lambda: reg.get("flash_tiles_staged_total")
-    telemetry.disable()
-    telemetry._set_registry(prev)
-
-
 @pytest.mark.parametrize("case", list(_TILE_CASES))
-def test_tile_kinds_match_the_masked_path(case, tile_counter):
+def test_tile_kinds_match_the_masked_path(case, flash_staged):
     """Output and the three gradients of each geometry against
     ``_xla_attention`` (where the reference can say it) and against the same
     call forced onto the masked dense path, which a ``kv_lens`` of the full
@@ -630,7 +605,7 @@ def test_tile_kinds_match_the_masked_path(case, tile_counter):
     if not isinstance(kinds[0], tuple):
         kinds = (kinds,) * 3        # the same in all three kernels
     got = run()
-    counter = tile_counter()
+    counter = flash_staged("flash_tiles_staged_total")
 
     def staged():
         return tuple(tuple(int(counter.value(kernel=kernel, kind=kind))
@@ -647,12 +622,11 @@ def test_tile_kinds_match_the_masked_path(case, tile_counter):
             np.testing.assert_allclose(a, w, rtol=1e-5, atol=1e-5,
                                        err_msg=name)
     if "dropout_rate" not in kw:
-        def xla(q, k, v):
-            out = _xla_attention(q, k, v, mask=mask,
-                                 causal=kw.get("causal", False),
-                                 window=kw.get("window"))
-            return jnp.sum(do * out), out
-        want, ref = jax.grad(xla, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        def xla(q, k, v):         # staged: no flash kernel, nothing counted
+            return _xla_attention(q, k, v, mask=mask,
+                                  causal=kw.get("causal", False),
+                                  window=kw.get("window"))
+        ref, want = _staged.out_and_grads(xla, q, k, v, ct=do)
         for name, a, w in zip(names, got, (ref,) + want):
             np.testing.assert_allclose(a, w, rtol=5e-4, atol=5e-4,
                                        err_msg=name)

@@ -14,6 +14,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import _staged
 from paddle_tpu.analysis.walker import walk
 from paddle_tpu.ops.pallas.flash_attention import (BWD_DKV, BWD_DQ, FWD,
                                                     KERNEL_NAMES,
@@ -222,11 +223,10 @@ def test_bit_equal_to_the_plain_maps(tiles, blocks, heads, kv_heads, d,
         fa._fwd.clear_cache()
         fa._bwd_calls.clear_cache()
 
-        def loss(q, k, v):
-            out = flash_attention(q, k, v, causal=True, block_q=blocks[0],
-                                  block_k=blocks[1], interpret=True, **kw)
-            return jnp.sum(out * ct), out
-        grads, out = jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        out, grads = _staged.out_and_grads(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=True, block_q=blocks[0], block_k=blocks[1],
+                interpret=True, **kw), q, k, v, ct=ct)
         return (out,) + grads
 
     held = run()
@@ -241,23 +241,10 @@ def test_bit_equal_to_the_plain_maps(tiles, blocks, heads, kv_heads, d,
                                       err_msg=name)
 
 
-@pytest.fixture
-def held_counter():
-    """A fresh telemetry registry and the staged functions' caches dropped:
-    the steps are counted where a kernel is staged."""
-    from paddle_tpu import telemetry
-    from paddle_tpu.telemetry.metrics import Registry
-    prev, reg = telemetry.get_registry(), Registry()
-    telemetry._set_registry(reg)
-    telemetry.enable()
-    fa._fwd.clear_cache()
-    fa._bwd_calls.clear_cache()
-    yield lambda: {kernel: int(reg.get("flash_steps_held_total").value(
-        kernel=kernel)) for kernel in KERNEL_NAMES}
-    telemetry.disable()
-    telemetry._set_registry(prev)
-    fa._fwd.clear_cache()
-    fa._bwd_calls.clear_cache()
+def _held(flash_staged):
+    """The steps held in each kernel, as ``KERNEL_NAMES`` orders them."""
+    held = flash_staged("flash_steps_held_total")
+    return tuple(int(held.value(kernel=kernel)) for kernel in KERNEL_NAMES)
 
 
 # name: (q shape, KV heads, kw of the call, steps held a lane block in
@@ -288,7 +275,7 @@ _CELL_GEOMETRIES = {
 
 
 @pytest.mark.parametrize("cell", list(_CELL_GEOMETRIES))
-def test_steps_held_at_the_cells_geometries(cell, held_counter):
+def test_steps_held_at_the_cells_geometries(cell, flash_staged):
     """``flash_steps_held_total{kernel}`` of one staged layer at the shapes
     the cells run, in the blocks the tuning DB resolves for them: the steps
     of a lane block's grid over the diagonal (the dk/dv kernel's for each
@@ -300,14 +287,14 @@ def test_steps_held_at_the_cells_geometries(cell, held_counter):
     jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
         q, k, v, interpret=True, **kw).astype(jnp.float32)),
         argnums=(0, 1, 2)))(q, kv, kv)
-    assert tuple(held_counter().values()) == want
+    assert _held(flash_staged) == want
 
 
-def test_a_tile_table_holds_nothing(held_counter):
+def test_a_tile_table_holds_nothing(flash_staged):
     """Block diffusion walks a list of the visible tiles: every step
     computes."""
     q = jax.ShapeDtypeStruct((1, 2048, 2, 128), jnp.float32)
     jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
         q, k, v, block_diffusion=4, block_q=256, block_k=256,
         interpret=True)), argnums=(0, 1, 2)))(q, q, q)
-    assert tuple(held_counter().values()) == (0, 0, 0)
+    assert _held(flash_staged) == (0, 0, 0)

@@ -8,6 +8,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import _staged
 from paddle_tpu.nn.functional import fused_linear_cross_entropy
 from paddle_tpu.nn.functional.attention import _xla_attention
 from paddle_tpu.ops.chunked_ce import chunked_lm_ce
@@ -27,11 +28,9 @@ def _data(n=128, h=64, v=512, seed=0, ignore_frac=0.0):
 
 def _both(hid, w, y, bt, bv):
     """(loss, (dh, dw)) for fused kernel and chunked reference."""
-    fu = jax.value_and_grad(
-        lambda a, b: fused_lm_ce(a, b, y, block_tokens=bt, block_vocab=bv,
-                                 interpret=True), argnums=(0, 1))(hid, w)
-    ref = jax.value_and_grad(
-        lambda a, b: chunked_lm_ce(a, b, y), argnums=(0, 1))(hid, w)
+    fu = _staged.value_and_grads(lambda a, b: fused_lm_ce(
+        a, b, y, block_tokens=bt, block_vocab=bv, interpret=True), hid, w)
+    ref = _staged.value_and_grads(lambda a, b: chunked_lm_ce(a, b, y), hid, w)
     return fu, ref
 
 
@@ -70,10 +69,9 @@ class TestFusedCeParity:
     def test_all_ignored_is_zero_loss_zero_grads(self):
         hid, w, _ = _data(n=32)
         y = jnp.full((32,), -100, jnp.int32)
-        loss, (dh, dw) = jax.value_and_grad(
+        loss, (dh, dw) = _staged.value_and_grads(
             lambda a, b: fused_lm_ce(a, b, y, block_tokens=16,
-                                     block_vocab=256, interpret=True),
-            argnums=(0, 1))(hid, w)
+                                     block_vocab=256, interpret=True), hid, w)
         assert float(loss) == 0.0
         np.testing.assert_array_equal(np.asarray(dh), 0.0)
         np.testing.assert_array_equal(np.asarray(dw), 0.0)
@@ -141,11 +139,10 @@ class TestFlashSweptConfigs:
         rs = np.random.RandomState(4)
         q, k, v = (jnp.asarray(rs.randn(1, 256, 1, 64), jnp.float32)
                    for _ in range(3))
-        gf = jax.grad(lambda a, b, c: jnp.sum(flash_attention(
-            a, b, c, block_q=128, block_k=128, interpret=True) ** 2),
-            argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(lambda a, b, c: jnp.sum(
-            _xla_attention(a, b, c) ** 2), argnums=(0, 1, 2))(q, k, v)
+        gf = _staged.grads(lambda a, b, c: jnp.sum(flash_attention(
+            a, b, c, block_q=128, block_k=128, interpret=True) ** 2), q, k, v)
+        gr = _staged.grads(lambda a, b, c: jnp.sum(
+            _xla_attention(a, b, c) ** 2), q, k, v)
         for a, b in zip(gf, gr):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=2e-3, atol=2e-3)

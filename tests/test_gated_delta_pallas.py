@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _staged
 from paddle_tpu import telemetry
 from paddle_tpu.nn import functional as F
 from paddle_tpu.nn.functional import linear_attention as la
@@ -67,12 +68,7 @@ def results(name, path):
             def rule(*a):
                 return F.gated_delta_rule(*a, path="recurrent")
 
-    def f(*a):
-        out = rule(*a)
-        return jnp.sum(out.astype(jnp.float32) * ct), out
-
-    grads, out = jax.jit(jax.grad(f, argnums=(0, 1, 2, 3, 4),
-                                  has_aux=True))(*args)
+    out, grads = _staged.out_and_grads(rule, *args, ct=ct)
     return tuple(np.asarray(x, np.float32) for x in (out,) + grads)
 
 

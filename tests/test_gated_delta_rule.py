@@ -3,11 +3,14 @@ sizes on the CPU with seeded inputs. The recurrence over positions
 (``path="recurrent"``) is the definition; the chunked path, its triangular
 systems and its scan over chunk states are held to it, in values and in all
 five gradients."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _staged
 from paddle_tpu import nn, telemetry
 from paddle_tpu.nn import functional as F
 from paddle_tpu.nn.functional import linear_attention as la
@@ -25,11 +28,13 @@ def inputs(seed, b=2, seq=128, h=3, dk=16, dv=24, dtype=jnp.float32):
     return q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta
 
 
-def grads(args, ct, **kw):
-    def f(*a):
-        out = F.gated_delta_rule(*a, **kw)
-        return jnp.sum(out.astype(jnp.float32) * ct)
-    return jax.grad(f, argnums=(0, 1, 2, 3, 4))(*args)
+rule = functools.partial(_staged.run, F.gated_delta_rule)
+
+
+def out_and_grads(args, ct, **kw):
+    """The rule's output and its five gradients, as one program."""
+    return _staged.out_and_grads(functools.partial(F.gated_delta_rule, **kw),
+                                 *args, ct=ct)
 
 
 def plain_loop(q, k, v, g, beta):
@@ -50,7 +55,7 @@ def plain_loop(q, k, v, g, beta):
 
 def test_recurrent_path_is_the_definition():
     args = inputs(0, b=1, seq=24, h=2, dk=4, dv=5)
-    got = F.gated_delta_rule(*args, path="recurrent")
+    got = rule(*args, path="recurrent")
     np.testing.assert_allclose(got, plain_loop(*args), rtol=1e-5, atol=1e-6)
 
 
@@ -61,13 +66,12 @@ def test_chunked_equals_recurrent_in_values_and_all_gradients(chunk, seq):
     padded with beta = 0, g = 0 and cut again."""
     args = inputs(1, seq=seq)
     ct = jax.random.normal(jax.random.key(9), args[2].shape)
-    want = F.gated_delta_rule(*args, path="recurrent")
-    got = F.gated_delta_rule(*args, chunk=chunk)
+    want, g_want = out_and_grads(args, ct, path="recurrent")
+    got, g_got = out_and_grads(args, ct, chunk=chunk)
     assert got.shape == want.shape == args[2].shape
     np.testing.assert_allclose(got, want, atol=1e-5 * float(
         jnp.max(jnp.abs(want))), rtol=0)
-    for a, b in zip(grads(args, ct, chunk=chunk),
-                    grads(args, ct, path="recurrent")):
+    for a, b in zip(g_got, g_want):
         assert a.shape == b.shape
         np.testing.assert_allclose(a, b, atol=1e-5 * float(
             jnp.max(jnp.abs(b))), rtol=0)
@@ -75,7 +79,7 @@ def test_chunked_equals_recurrent_in_values_and_all_gradients(chunk, seq):
 
 def test_chunk_sizes_agree_with_each_other():
     args = inputs(2, seq=192)
-    outs = [F.gated_delta_rule(*args, chunk=c) for c in (16, 32, 64)]
+    outs = [rule(*args, chunk=c) for c in (16, 32, 64)]
     for o in outs[1:]:
         np.testing.assert_allclose(o, outs[0], atol=2e-6)
 
@@ -89,13 +93,13 @@ def test_bf16_operands_stay_inside_their_rounding():
     args = inputs(3, dtype=jnp.bfloat16)
     ct = jax.random.normal(jax.random.key(9), args[2].shape)
     f32 = tuple(x.astype(jnp.float32) for x in args)
-    got = F.gated_delta_rule(*args)
+    got, g_got = out_and_grads(args, ct)
     assert got.dtype == jnp.bfloat16
-    want = F.gated_delta_rule(*f32, path="recurrent")
+    want, g_want = out_and_grads(f32, ct, path="recurrent")
     scale = float(jnp.max(jnp.abs(want)))
     assert float(jnp.max(jnp.abs(got.astype(jnp.float32) - want))) \
         < 0.01 * scale
-    for a, b in zip(grads(args, ct), grads(f32, ct, path="recurrent")):
+    for a, b in zip(g_got, g_want):
         assert float(jnp.max(jnp.abs(a.astype(jnp.float32) - b))) \
             < 0.02 * float(jnp.max(jnp.abs(b)))
 
@@ -110,12 +114,14 @@ def test_head_groups_change_nothing(monkeypatch, rows):
     assert [la._head_groups(4, n) for n in (64, 8, 4, 3, 2, 1)] \
         == [1, 1, 1, 1, 2, 4]
     assert la._head_groups(32, la.GROUP_HEADS) == 2
-    whole, whole_grads = F.gated_delta_rule(*args), grads(args, ct)
+    whole, whole_grads = out_and_grads(args, ct)
     for group_heads in (1, 2):
+        # a program of its own after the patch: the global is read as the
+        # rule is traced
         monkeypatch.setattr(la, "GROUP_HEADS", group_heads)
-        np.testing.assert_allclose(F.gated_delta_rule(*args), whole,
-                                   atol=1e-6)
-        for a, b in zip(grads(args, ct), whole_grads):
+        out, out_grads = out_and_grads(args, ct)
+        np.testing.assert_allclose(out, whole, atol=1e-6)
+        for a, b in zip(out_grads, whole_grads):
             np.testing.assert_allclose(a, b, atol=2e-6)
 
 
@@ -128,11 +134,10 @@ def test_without_gate_and_at_full_steps_it_is_the_plain_delta_rule():
     zeros, ones = jnp.zeros((1, seq, 1)), jnp.ones((1, seq, 1))
     for kw in ({"path": "recurrent"}, {"chunk": 4}):
         np.testing.assert_allclose(
-            F.gated_delta_rule(k, k, v, zeros, ones, **kw), v, atol=1e-6)
+            rule(k, k, v, zeros, ones, **kw), v, atol=1e-6)
     # and a key written twice is overwritten, not added to
     k2 = jnp.concatenate([k[:, :1]] * 2, axis=1)
-    out = F.gated_delta_rule(k2, k2, v[:, :2], zeros[:, :2], ones[:, :2],
-                             chunk=2)
+    out = rule(k2, k2, v[:, :2], zeros[:, :2], ones[:, :2], chunk=2)
     np.testing.assert_allclose(out[:, 1], v[:, 1], atol=1e-6)
 
 
@@ -147,7 +152,7 @@ def test_with_beta_zero_the_state_only_decays():
     want = v[:, :1] * jnp.exp(-0.25 * jnp.arange(seq))[None, :, None, None]
     for kw in ({"path": "recurrent"}, {"chunk": 4}, {"chunk": 16}):
         np.testing.assert_allclose(
-            F.gated_delta_rule(k, k, v, g, beta, **kw), want, rtol=1e-5)
+            rule(k, k, v, g, beta, **kw), want, rtol=1e-5)
 
 
 @pytest.mark.parametrize("n", [4, 16, 64])
@@ -161,9 +166,9 @@ def test_inverse_by_products_against_linalg_inv(n):
     np.testing.assert_array_equal(
         la.inverse_unit_lower(a + jnp.triu(jnp.ones((n, n)))), got)
     ct = jax.random.normal(jax.random.key(1), a.shape)
-    g = jax.grad(lambda a: jnp.sum(la.inverse_unit_lower(a) * ct))(a)
-    g_ref = jax.grad(lambda a: jnp.sum(jnp.linalg.inv(
-        jnp.eye(n) + jnp.tril(a, -1)) * ct))(a)
+    g = _staged.grads(lambda a: jnp.sum(la.inverse_unit_lower(a) * ct), a)
+    g_ref = _staged.grads(lambda a: jnp.sum(jnp.linalg.inv(
+        jnp.eye(n) + jnp.tril(a, -1)) * ct), a)
     np.testing.assert_allclose(g, g_ref, atol=1e-4 * float(
         jnp.max(jnp.abs(g_ref))))
 
@@ -255,11 +260,11 @@ def test_the_layer_is_its_equations():
     k = k / jnp.sqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
     g = -jnp.exp(p["A_log"].value) * jax.nn.softplus(
         ba[..., 4:] + p["dt_bias"].value)
-    o = F.gated_delta_rule(jnp.repeat(q, 2, 2), jnp.repeat(k, 2, 2), v, g,
-                           jax.nn.sigmoid(ba[..., :4]), path="recurrent")
+    o = rule(jnp.repeat(q, 2, 2), jnp.repeat(k, 2, 2), v, g,
+             jax.nn.sigmoid(ba[..., :4]), path="recurrent")
     y = F.gated_rms_norm(o, z, p["norm.weight"].value, 1e-6)
     want = y.reshape(2, 48, 32) @ p["out_proj.weight"].value
-    np.testing.assert_allclose(layer(x), want, atol=2e-5)
+    np.testing.assert_allclose(_staged.run(layer, x), want, atol=2e-5)
     with pytest.raises(ValueError, match="value heads"):
         nn.GatedDeltaNet(32, 3, 4, 8, 8)
 
@@ -289,8 +294,9 @@ def test_a_sampled_row_takes_the_path_a_step_takes(rows):
     assert [trips for depth, trips in fwd if depth == 0] == [2]
     assert (1, 2) in fwd                        # 32 positions: two chunks
     ct = jnp.ones(args[2].shape)
-    bwd = loops(jax.make_jaxpr(lambda *a: grads(a, ct, chunk=16))(
-        *args).jaxpr)
+    bwd = loops(jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(
+        F.gated_delta_rule(*a, chunk=16) * ct), (0, 1, 2, 3, 4)))(
+            *args).jaxpr)
     assert [trips for depth, trips in bwd if depth == 0] == [2, 2]
 
 

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _staged
 from benchmark.families.glm4moelite import published_columns
 from benchmark.reference import glm4moelite as reference
 from paddle_tpu import telemetry
@@ -68,13 +69,10 @@ def test_layer_equals_the_plain_statement(heads, seq, sizes):
         return reference.attention(x, as_reference(p, arch), arch, 1e-5,
                                    False)
 
-    np.testing.assert_allclose(ours(params, x), theirs(params, x),
-                               rtol=2e-5, atol=2e-6)
     weight = jax.random.normal(jax.random.key(2), (2, seq, 32))
-    got = jax.grad(lambda p, x: jnp.sum(ours(p, x) * weight),
-                   argnums=(0, 1))(params, x)
-    want = jax.grad(lambda p, x: jnp.sum(theirs(p, x) * weight),
-                    argnums=(0, 1))(params, x)
+    out, got = _staged.out_and_grads(ours, params, x, ct=weight)
+    ref, want = _staged.out_and_grads(theirs, params, x, ct=weight)
+    np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-6)
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5)
@@ -89,12 +87,10 @@ def test_scale_is_of_the_whole_query_width():
     ref = as_reference(params, arch)
     # scaling q_b by sqrt(16 / 12) is the other scale
     other = dict(ref, q_b_w=ref["q_b_w"] * np.sqrt(16 / 12))
-    got = attn(x)
-    np.testing.assert_allclose(
-        got, reference.attention(x, ref, arch, 1e-5, False), rtol=2e-5,
-        atol=2e-6)
-    assert float(jnp.abs(got - reference.attention(
-        x, other, arch, 1e-5, False)).max()) > 1e-3
+    got = _staged.forward(attn)(x)
+    plain = jax.jit(lambda x, p: reference.attention(x, p, arch, 1e-5, False))
+    np.testing.assert_allclose(got, plain(x, ref), rtol=2e-5, atol=2e-6)
+    assert float(jnp.abs(got - plain(x, other)).max()) > 1e-3
 
 
 @pytest.mark.parametrize("without", ["both", "q_a_norm", "kv_a_norm"])
@@ -111,8 +107,10 @@ def test_the_two_latent_norms_are_there(without, monkeypatch):
         return c if c.shape[-1] in skipped[without] else right(c, g, eps)
 
     monkeypatch.setattr(reference, "latent_norm", norm)
-    wrong = reference.attention(x, ref, arch, 1e-5, False)
-    assert float(jnp.abs(attn(x) - wrong).max()) > 1e-2
+    # staged after the patch: the reference reads it as it is traced
+    wrong = _staged.run(reference.attention, x, ref, arch=arch, eps=1e-5,
+                        remat=False)
+    assert float(jnp.abs(_staged.forward(attn)(x) - wrong).max()) > 1e-2
 
 
 def test_the_rotary_key_is_one_head_rotated_once(monkeypatch):
@@ -149,9 +147,12 @@ def test_the_rotary_key_is_one_head_rotated_once(monkeypatch):
 
 def test_explicit_positions_turn_the_rotary_lanes():
     attn, _ = layer()
+    attend = _staged.forward(attn)
     x = jax.random.normal(jax.random.key(1), (1, 8, 32))
-    np.testing.assert_allclose(attn(x, jnp.arange(8)), attn(x), rtol=1e-6)
-    assert float(jnp.abs(attn(x, jnp.arange(8) * 3) - attn(x)).max()) > 1e-4
+    np.testing.assert_allclose(attend(x, jnp.arange(8)), attend(x),
+                               rtol=1e-6)
+    assert float(jnp.abs(attend(x, jnp.arange(8) * 3) - attend(x)).max()) \
+        > 1e-4
 
 
 def test_what_is_not_built_is_refused():

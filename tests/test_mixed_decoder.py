@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _staged
 from paddle_tpu import nn, telemetry
 from paddle_tpu.incubate.moe import DroplessMoELayer, route_top_k
 from paddle_tpu.jit.functionalization import functional_call, state_of
@@ -44,8 +45,9 @@ def _explicit_attention(q, k, v, window):
     (4, 4, None), (4, 2, None), (6, 2, 70), (4, 1, 128), (2, 2, 1)])
 def test_xla_attention_window_and_grouped_heads(h, h_kv, window):
     q, k, v, _ = _qkv(0, 2, 192, h, h_kv, d=16)
-    got = _xla_attention(q, k, v, causal=True, window=window)
-    want = _explicit_attention(q, k, v, window)
+    got, want = _staged.run(lambda q, k, v: (
+        _xla_attention(q, k, v, causal=True, window=window),
+        _explicit_attention(q, k, v, window)), q, k, v)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
@@ -62,20 +64,17 @@ def test_flash_window_grouped_heads_match_xla(s, h, h_kv, window, bq, bk):
     q, k, v, do = _qkv(1, 2, s, h, h_kv)
 
     def flash(q, k, v):
-        return jnp.sum(do * flash_attention(
-            q, k, v, causal=True, window=window, block_q=bq, block_k=bk,
-            interpret=True))
+        return flash_attention(q, k, v, causal=True, window=window,
+                               block_q=bq, block_k=bk, interpret=True)
 
     def xla(q, k, v):
-        return jnp.sum(do * _xla_attention(q, k, v, causal=True,
-                                           window=window))
+        return _xla_attention(q, k, v, causal=True, window=window)
 
+    out, got = _staged.out_and_grads(flash, q, k, v, ct=do)
     np.testing.assert_allclose(
-        flash_attention(q, k, v, causal=True, window=window, block_q=bq,
-                        block_k=bk, interpret=True),
-        _explicit_attention(q, k, v, window), rtol=2e-5, atol=2e-5)
-    got = jax.grad(flash, argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(xla, argnums=(0, 1, 2))(q, k, v)
+        out, _staged.run(_explicit_attention, q, k, v, window=window),
+        rtol=2e-5, atol=2e-5)
+    _, want = _staged.out_and_grads(xla, q, k, v, ct=do)
     for a, b in zip(got, want):
         assert a.shape == b.shape
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
@@ -104,8 +103,10 @@ def test_window_needs_causal_and_heads_must_divide():
 
 def test_sdpa_takes_window_and_grouped_heads():
     q, k, v, _ = _qkv(3, 1, 96, 4, 2, d=16)
-    got = F.scaled_dot_product_attention(q, k, v, is_causal=True, window=20)
-    np.testing.assert_allclose(got, _explicit_attention(q, k, v, 20),
+    got, want = _staged.run(lambda q, k, v: (
+        F.scaled_dot_product_attention(q, k, v, is_causal=True, window=20),
+        _explicit_attention(q, k, v, 20)), q, k, v)
+    np.testing.assert_allclose(got, want,
                                rtol=1e-5, atol=1e-5)
 
 
@@ -234,8 +235,8 @@ def test_the_layer_routes_by_its_scoring_rule(scoring, score):
     np.testing.assert_allclose(
         weights, 2.5 * top / top.sum(-1, keepdims=True), rtol=1e-5)
     x = jnp.reshape(tokens, (2, 5, 8))
-    np.testing.assert_allclose(layer(x), _dense_moe(layer, x), rtol=1e-5,
-                               atol=1e-5)
+    np.testing.assert_allclose(_staged.forward(layer)(x),
+                               _dense_moe(layer, x), rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("tile", [512, 8], ids=["one_part", "two_parts"])
@@ -250,13 +251,12 @@ def test_dropless_layer_equals_the_dense_loop(held, tile, monkeypatch):
     two_parts = layer.buffer_rows(48) > layer.chunk_rows(48)
     assert two_parts == (tile == 8 and held is not None)
     x = jax.random.normal(jax.random.key(0), (2, 24, 16))
-    np.testing.assert_allclose(layer(x), _dense_moe(layer, x), rtol=1e-5,
-                               atol=1e-5)
     params, buffers = state_of(layer)
-    grad = jax.grad(lambda p: jnp.sum(functional_call(
-        layer, p, buffers, x)[0] ** 2))(dict(params))
-    want = jax.grad(lambda p: jnp.sum(_dense_moe(layer, x, p) ** 2))(
-        dict(params))
+    out, grad = _staged.out_and_grads(lambda p: functional_call(
+        layer, p, buffers, x)[0], dict(params))
+    dense, want = _staged.out_and_grads(lambda p: _dense_moe(layer, x, p),
+                                        dict(params))
+    np.testing.assert_allclose(out, dense, rtol=1e-5, atol=1e-5)
     assert set(grad) == set(want) and len(grad) == 7
     for name in want:
         np.testing.assert_allclose(grad[name], want[name], rtol=1e-4,
@@ -323,15 +323,15 @@ def test_rows_of_no_group_may_hold_anything(monkeypatch):
     object.__setattr__(layer.experts, "forward", unwritten)
     x = jax.random.normal(jax.random.key(0), (2, 24, 16))
     assert layer.buffer_rows(48) > layer.chunk_rows(48) > 48 * 4 * 8 / 32
-    got = layer(x)
+    got = _staged.forward(layer)(x)
     assert bool(jnp.all(jnp.isfinite(got)))
     np.testing.assert_allclose(got, _dense_moe(layer, x), rtol=1e-5,
                                atol=1e-5)
     params, buffers = state_of(layer)
-    grad = jax.grad(lambda p: jnp.sum(functional_call(
-        layer, p, buffers, x)[0] ** 2))(dict(params))
-    want = jax.grad(lambda p: jnp.sum(_dense_moe(layer, x, p) ** 2))(
-        dict(params))
+    grad = _staged.grads(lambda p: jnp.sum(functional_call(
+        layer, p, buffers, x)[0] ** 2), dict(params))
+    want = _staged.grads(lambda p: jnp.sum(_dense_moe(layer, x, p) ** 2),
+                         dict(params))
     for name in want:
         assert bool(jnp.all(jnp.isfinite(grad[name]))), name
         np.testing.assert_allclose(grad[name], want[name], rtol=1e-4,

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _staged
 from benchmark.families import qwen3next as family
 from benchmark.reference import qwen3next as reference
 from paddle_tpu import nn, telemetry
@@ -276,17 +277,6 @@ def test_checkpoint_blocks_recomputes_a_blocks_halves_apart(ffn):
     """``checkpoint_blocks``: two checkpoints a block, the mixer's half and
     the feed-forward half, so that the two halves' intermediates never
     exist together; without it none."""
-    def checkpoints(jaxpr):
-        """``jax.checkpoint``s staged at any depth, not those inside one."""
-        n = 0
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "remat2":
-                n += 1
-            else:
-                n += sum(map(checkpoints,
-                             jax.core.jaxprs_in_params(eqn.params)))
-        return n
-
     ids = jnp.zeros((1, 40), jnp.int32)
     for checkpoint, want in ((False, 0), (True, 2 * 4)):
         model = toy_model(checkpoint_blocks=checkpoint,
@@ -294,4 +284,4 @@ def test_checkpoint_blocks_recomputes_a_blocks_halves_apart(ffn):
         params = dict(state_of(model)[0])
         jaxpr = jax.make_jaxpr(
             lambda p: functional_call(model, p, None, ids)[0])(params)
-        assert checkpoints(jaxpr.jaxpr) == want, checkpoint
+        assert _staged.checkpoints(jaxpr.jaxpr) == want, checkpoint

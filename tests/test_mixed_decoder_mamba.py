@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _staged
 from paddle_tpu import telemetry
 from paddle_tpu.incubate.moe import (DroplessMoELayer, GroupedExperts,
                                      route_top_k)
@@ -66,12 +67,11 @@ def test_relu2_experts_and_shared_expert_against_a_dense_loop():
                            "shared_expert.down_proj.weight",
                            "experts.up_proj", "experts.down_proj"}
     x = jax.random.normal(jax.random.key(0), (2, 20, 16))
-    np.testing.assert_allclose(layer(x), dense_relu2_moe(layer, x, params),
-                               rtol=1e-5, atol=1e-5)
-    grad = jax.jit(jax.grad(lambda p: jnp.sum(functional_call(
-        layer, p, buffers, x)[0] ** 2)))(params)
-    want = jax.jit(jax.grad(lambda p: jnp.sum(
-        dense_relu2_moe(layer, x, p) ** 2)))(params)
+    out, grad = _staged.out_and_grads(lambda p: functional_call(
+        layer, p, buffers, x)[0], params)
+    dense, want = _staged.out_and_grads(lambda p: dense_relu2_moe(
+        layer, x, p), params)
+    np.testing.assert_allclose(out, dense, rtol=1e-5, atol=1e-5)
     for name in want:
         np.testing.assert_allclose(grad[name], want[name], rtol=1e-4,
                                    atol=1e-5, err_msg=name)
@@ -108,25 +108,15 @@ def test_one_sublayer_blocks_are_named_by_what_they_hold():
     # a layer of one sublayer: x + sublayer(norm(x))
     block = model.decoder.h[1]
     x = jax.random.normal(jax.random.key(1), (1, 10, 32))
-    np.testing.assert_allclose(block(x), x + block.moe(block.input_norm(x)),
-                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(
+        _staged.forward(block)(x), x + _staged.forward(block.moe)(
+            _staged.forward(block.input_norm)(x)), rtol=1e-6, atol=1e-6)
 
 
 def test_a_block_needs_a_sublayer():
     with pytest.raises(ValueError, match="mixer or a feed-forward"):
         toy_model(layer_types=[None], mlp_layer_types=[None],
                   heads_per_layer=[4])
-
-
-def checkpoints(jaxpr):
-    """``jax.checkpoint``s staged at any depth, not those inside one."""
-    n = 0
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "remat2":
-            n += 1
-        else:
-            n += sum(map(checkpoints, jax.core.jaxprs_in_params(eqn.params)))
-    return n
 
 
 def test_checkpoint_blocks_is_one_checkpoint_a_sublayer_and_changes_nothing():
@@ -143,10 +133,11 @@ def test_checkpoint_blocks_is_one_checkpoint_a_sublayer_and_changes_nothing():
 
     checked = toy_model(checkpoint_blocks=True)
     jaxpr = jax.make_jaxpr(loss(checked))(params).jaxpr
-    assert checkpoints(jaxpr) == 6            # one a block of one sublayer
-    assert checkpoints(jax.make_jaxpr(loss(plain))(params).jaxpr) == 0
-    want, g_want = jax.jit(jax.value_and_grad(loss(plain)))(params)
-    got, g_got = jax.jit(jax.value_and_grad(loss(checked)))(params)
+    assert _staged.checkpoints(jaxpr) == 6     # one a block of one sublayer
+    assert _staged.checkpoints(
+        jax.make_jaxpr(loss(plain))(params).jaxpr) == 0
+    want, g_want = _staged.value_and_grads(loss(plain), params)
+    got, g_got = _staged.value_and_grads(loss(checked), params)
     np.testing.assert_allclose(got, want, rtol=1e-6)
     for name in g_want:
         np.testing.assert_allclose(g_got[name], g_want[name], rtol=1e-5,
@@ -165,13 +156,15 @@ def test_attention_without_rotation_is_plain_softmax_attention():
     mask = np.tril(np.ones((12, 12), bool))
     probs = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), -1)
     o = jnp.einsum("bhst,bthd->bshd", probs, v).reshape(2, 12, 64)
-    np.testing.assert_allclose(attn(x), o @ p["o_proj.weight"], rtol=1e-5,
+    attend = _staged.forward(attn)
+    out = attend(x)
+    np.testing.assert_allclose(out, o @ p["o_proj.weight"], rtol=1e-5,
                                atol=1e-5)
     # no positional encoding: the last position's output does not depend on
     # the order of the positions before it
     perm = jnp.concatenate([jnp.arange(10, -1, -1), jnp.asarray([11])])
-    np.testing.assert_allclose(attn(x[:, perm])[:, -1], attn(x)[:, -1],
-                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(attend(x[:, perm])[:, -1],
+                               out[:, -1], rtol=1e-5, atol=1e-5)
 
 
 def test_mamba_layers_refuse_positions_and_count_their_scans():

@@ -7,10 +7,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _staged
 import paddle_tpu as paddle
 from paddle_tpu import telemetry
 from paddle_tpu.jit.functionalization import functional_call, state_of
-from paddle_tpu.nn import functional as F
 from paddle_tpu.text.models import (MixedDecoderForPretraining,
                                     MultiTokenPrediction)
 
@@ -95,14 +95,13 @@ def test_embedding_and_head_are_the_trunks_and_get_both_gradients():
     labels = jnp.asarray([[2, 3, 4, 90, 6, 7, 8, 9]])  # 90: a label only
     params = dict(state_of(m)[0])
 
-    def term(name):
-        def f(p):
-            out, new = functional_call(m, p, None, (ids, labels))
-            return out if name == "total" else new[name]
-        return jax.grad(f)(params)
+    def terms(p):
+        out, new = functional_call(m, p, None, (ids, labels))
+        return out, new["mtp_main_loss"], new["mtp_next_loss"]
 
-    total, main, ahead = (term(n) for n in (
-        "total", "mtp_main_loss", "mtp_next_loss"))
+    # the three gradients as one program
+    total, main, ahead = _staged.run(lambda p: tuple(
+        jax.grad(lambda p, i=i: terms(p)[i])(p) for i in range(3)), params)
     for leaf in ("decoder.embed_tokens.weight", "lm_head.weight"):
         assert float(jnp.abs(main[leaf]).max()) > 0
         assert float(jnp.abs(ahead[leaf]).max()) > 0
@@ -167,8 +166,9 @@ def test_scopes_and_the_checkpointed_module(checkpoint):
         assert root + scope in text, scope
     assert "mtp/lm_head" not in text and "mtp/loss" not in text
     plain = model(checkpoint_blocks=False)
-    got, want = jax.value_and_grad(loss)(params), jax.value_and_grad(
-        lambda p: functional_call(plain, p, None, (ids, labels))[0])(params)
+    got = _staged.value_and_grads(loss, params)
+    want = _staged.value_and_grads(
+        lambda p: functional_call(plain, p, None, (ids, labels))[0], params)
     np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
     for k in params:
         np.testing.assert_allclose(got[1][k], want[1][k], rtol=2e-4,

@@ -1,34 +1,18 @@
 """OpTest-style numeric-gradient harness (reference:
 fluid/tests/unittests/op_test.py:270 OpTest — check_output vs reference impl,
 check_grad vs finite differences :110,:1409)."""
+import functools
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
-
-def numeric_grad(fn, x, eps=1e-3):
-    """Central finite differences of scalar fn at x."""
-    x = np.asarray(x, dtype=np.float64)
-    g = np.zeros_like(x)
-    flat = x.reshape(-1)
-    gf = g.reshape(-1)
-    for i in range(flat.size):
-        orig = flat[i]
-        flat[i] = orig + eps
-        f1 = float(fn(jnp.asarray(x, dtype=jnp.float32)))
-        flat[i] = orig - eps
-        f0 = float(fn(jnp.asarray(x, dtype=jnp.float32)))
-        flat[i] = orig
-        gf[i] = (f1 - f0) / (2 * eps)
-    return g
+import _staged
 
 
-def check_grad(fn, x, rtol=5e-2, atol=5e-3):
-    analytic = np.asarray(jax.grad(lambda v: fn(v).sum())(jnp.asarray(x)))
-    numeric = numeric_grad(lambda v: fn(v).sum(), x)
-    np.testing.assert_allclose(analytic, numeric, rtol=rtol, atol=atol)
+check_grad = functools.partial(_staged.check_grad, rtol=5e-2, atol=5e-3)
 
 
 class TestActivationGrads:

@@ -8,12 +8,15 @@ at <10 and carry reasons, like the reference's per-op white list.
 
 Run with -s to print the coverage report.
 """
+import functools
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
+import _staged
 import paddle_tpu.tensor as T
 from paddle_tpu.nn import functional as F
 
@@ -44,27 +47,8 @@ def scalarize(out):
     return sum(jnp.sum(l) for l in leaves)
 
 
-def numeric_grad(fn, x, eps=1e-3):
-    x = np.asarray(x, dtype=np.float64)
-    g = np.zeros_like(x)
-    flat, gf = x.reshape(-1), g.reshape(-1)
-    for i in range(flat.size):
-        orig = flat[i]
-        flat[i] = orig + eps
-        f1 = float(fn(jnp.asarray(x, jnp.float32)))
-        flat[i] = orig - eps
-        f0 = float(fn(jnp.asarray(x, jnp.float32)))
-        flat[i] = orig
-        gf[i] = (f1 - f0) / (2 * eps)
-    return g
-
-
-def check_grad(f, x, rtol=6e-2, atol=6e-3):
-    lossf = lambda v: scalarize(f(v))  # noqa: E731
-    analytic = np.asarray(jax.grad(lossf)(jnp.asarray(x, jnp.float32)),
-                          dtype=np.float64)
-    numeric = numeric_grad(lossf, x)
-    np.testing.assert_allclose(analytic, numeric, rtol=rtol, atol=atol)
+check_grad = functools.partial(_staged.check_grad, rtol=6e-2, atol=6e-3,
+                               scalarize=scalarize)
 
 
 # ---------------------------------------------------------------------------

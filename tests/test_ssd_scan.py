@@ -3,6 +3,7 @@ gated norm in groups and gate first, and the ``Mamba2Mixer`` layer, at
 small sizes on the CPU in float32. The recurrence over positions
 (``path="recurrent"``) is the definition; the chunked path is held to it in
 values and in the gradients of all six inputs."""
+import functools
 import math
 
 import jax
@@ -10,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _staged
 from paddle_tpu import nn, telemetry
 from paddle_tpu.nn import functional as F
 from paddle_tpu.nn.functional import state_space
@@ -24,6 +26,9 @@ def inputs(seed, b=2, seq=80, heads=6, groups=2, p=4, n=8):
     C = jax.random.normal(ks[4], (b, seq, groups, n))
     D = jax.random.normal(ks[5], (heads,))
     return x, dt, A, B, C, D
+
+
+scan = functools.partial(_staged.run, F.ssd_scan)
 
 
 def plain_loop(x, dt, A, B, C, D):
@@ -48,7 +53,7 @@ def plain_loop(x, dt, A, B, C, D):
 def test_recurrent_path_is_the_definition():
     args = inputs(0, b=1, seq=19)
     for D in (args[5], None):
-        got = F.ssd_scan(*args[:5], D, path="recurrent")
+        got = scan(*args[:5], D, path="recurrent")
         np.testing.assert_allclose(got, plain_loop(*args[:5], D), atol=2e-5,
                                    rtol=1e-5)
 
@@ -59,23 +64,13 @@ def test_chunked_equals_recurrent_in_values_and_all_gradients(chunk, seq,
                                                               with_d):
     """Three heads a group; a sequence that is a multiple of the chunk, one
     that is not and one shorter than a chunk."""
-    x, dt, A, B, C, D = inputs(1, seq=seq)
-    D = D if with_d else None
-    ct = jax.random.normal(jax.random.key(9), x.shape)
-
-    def f(path):
-        def loss(*a):
-            return jnp.sum(F.ssd_scan(*a[:5], a[5] if with_d else None,
-                                      chunk=chunk, path=path) * ct)
-        return loss
-
-    args = (x, dt, A, B, C, D if with_d else jnp.zeros_like(A))
-    want = F.ssd_scan(x, dt, A, B, C, D, path="recurrent")
-    got = F.ssd_scan(x, dt, A, B, C, D, chunk=chunk)
+    args = inputs(1, seq=seq)[:6 if with_d else 5]      # D = None without
+    ct = jax.random.normal(jax.random.key(9), args[0].shape)
+    want, g_want = _staged.out_and_grads(functools.partial(
+        F.ssd_scan, chunk=chunk, path="recurrent"), *args, ct=ct)
+    got, g_got = _staged.out_and_grads(functools.partial(
+        F.ssd_scan, chunk=chunk), *args, ct=ct)
     np.testing.assert_allclose(got, want, atol=5e-5, rtol=5e-5)
-    argnums = tuple(range(6 if with_d else 5))
-    g_want = jax.grad(f("recurrent"), argnums)(*args)
-    g_got = jax.grad(f("chunked"), argnums)(*args)
     for name, a, b in zip("x dt A B C D".split(), g_got, g_want):
         scale = float(jnp.max(jnp.abs(b)))
         assert float(jnp.max(jnp.abs(a - b))) <= 2e-5 * scale + 1e-6, name
@@ -86,11 +81,10 @@ def test_padding_positions_write_and_decay_nothing():
     outputs are the same, so the padding the chunked path adds (dt = 0)
     cannot reach them either."""
     x, dt, A, B, C, D = inputs(2, seq=40)
-    short = F.ssd_scan(x[:, :37], dt[:, :37], A, B[:, :37], C[:, :37], D,
-                       chunk=16)
-    np.testing.assert_allclose(short, F.ssd_scan(x, dt, A, B, C, D,
-                                                 chunk=16)[:, :37],
-                               atol=1e-5)
+    short = scan(x[:, :37], dt[:, :37], A, B[:, :37], C[:, :37], D,
+                 chunk=16)
+    np.testing.assert_allclose(short, scan(x, dt, A, B, C, D,
+                                           chunk=16)[:, :37], atol=1e-5)
 
 
 def test_one_group_product_serves_its_heads():
@@ -121,21 +115,21 @@ def test_state_is_carried_from_chunk_to_chunk():
     work."""
     x, dt, A, B, C, D = inputs(4, seq=64)
     dt = dt * 0.05                              # slow decays: long memory
-    want = F.ssd_scan(x, dt, A, B, C, D, path="recurrent")
-    got = F.ssd_scan(x, dt, A, B, C, D, chunk=16)
+    want = scan(x, dt, A, B, C, D, path="recurrent")
+    got = scan(x, dt, A, B, C, D, chunk=16)
     np.testing.assert_allclose(got, want, atol=5e-5, rtol=5e-5)
-    alone = jnp.concatenate([F.ssd_scan(x[:, i:i + 16], dt[:, i:i + 16], A,
-                                        B[:, i:i + 16], C[:, i:i + 16], D,
-                                        path="recurrent")
+    recurrent = jax.jit(functools.partial(F.ssd_scan, path="recurrent"))
+    alone = jnp.concatenate([recurrent(x[:, i:i + 16], dt[:, i:i + 16], A,
+                                       B[:, i:i + 16], C[:, i:i + 16], D)
                              for i in range(0, 64, 16)], axis=1)
     assert float(jnp.max(jnp.abs(alone - want))) > 0.1
 
 
 def test_bf16_operands_stay_inside_their_rounding():
     x, dt, A, B, C, D = inputs(5, seq=64)
-    want = F.ssd_scan(x, dt, A, B, C, D, path="recurrent")
+    want = scan(x, dt, A, B, C, D, path="recurrent")
     low = [v.astype(jnp.bfloat16) for v in (x, B, C)]
-    got = F.ssd_scan(low[0], dt, A, low[1], low[2], D, chunk=16)
+    got = scan(low[0], dt, A, low[1], low[2], D, chunk=16)
     assert got.dtype == jnp.bfloat16
     err = float(jnp.linalg.norm((got.astype(jnp.float32) - want).ravel())
                 / jnp.linalg.norm(want.ravel()))
@@ -246,7 +240,7 @@ def test_the_layer_is_its_equations():
     m = mixer(1)
     p = {k: np.asarray(v.value, np.float64) for k, v in m.named_parameters()}
     x = np.asarray(jax.random.normal(jax.random.key(3), (2, 21, 16)))
-    got = np.asarray(m(jnp.asarray(x, jnp.float32)))
+    got = np.asarray(_staged.run(m, jnp.asarray(x, jnp.float32)))
     h, d, g, n = 4, 6, 2, 5
     inner = h * d
     proj = x @ p["in_proj.weight"]

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _staged
 from paddle_tpu import telemetry
 from paddle_tpu.jit.functionalization import functional_call, state_of
 from paddle_tpu.nn import functional as F
@@ -63,12 +64,7 @@ def results(name, path):
             return F.ssd_scan(x, dt, A, B, C, D if with_d else None,
                               chunk=kernel.CHUNK, path=path)
 
-    def f(*a):
-        out = scan(*a)
-        return jnp.sum(out.astype(jnp.float32) * ct), out
-
-    grads, out = jax.jit(jax.grad(f, argnums=tuple(range(6)),
-                                  has_aux=True))(*args)
+    out, grads = _staged.out_and_grads(scan, *args, ct=ct)
     return tuple(np.asarray(v, np.float32) for v in (out,) + grads)
 
 
