@@ -31,10 +31,13 @@ Phases (each through the entry points a user calls, weights from a seed):
   and a Gated DeltaNet block beside a gated full-attention block at head
   width 256 (``linear_attn_calls_staged_total`` says the rule took the
   kernels, ``gated_delta_chunks_total``, the expert layers' counters), the
-  rule's kernels on a layer-shaped row of 8,192 positions against XLA's
-  chunked path at ``Precision.HIGHEST`` (the precision guard: a float32
-  product that fell to one bf16 pass fails here and nowhere in the
-  benchmark), the expert layer's grouped products on the kernels at the
+  rule's kernels on a layer-shaped row of 8,192 positions of each cell that
+  runs them (Qwen3-Next's heads of 128 lanes, Olmo-Hybrid's of 96 and 192
+  with step sizes up to 2) against XLA's chunked path at
+  ``Precision.HIGHEST`` (the precision guard: a float32 product that fell
+  to one bf16 pass fails here and nowhere in the benchmark;
+  ``gated_delta_lanes_staged_total`` says how many zero lanes the kernels
+  padded), the expert layer's grouped products on the kernels at the
   Nemotron cell's widths against ``lax.ragged_dot`` at ``HIGHEST``
   (``moe_grouped_calls_staged_total`` says the layer took them), fused
   LM-head CE fwd/bwd at the bench
@@ -244,6 +247,12 @@ def _linear_attn_calls(registry):
     chunks = registry.get("gated_delta_chunks_total")
     if chunks:
         out["chunks"] = int(chunks.value())
+    lanes = registry.get("gated_delta_lanes_staged_total")
+    if lanes:
+        out["lanes"] = {operand: {kind: int(lanes.value(operand=operand,
+                                                        kind=kind))
+                                  for kind in ("data", "pad")}
+                        for operand in ("qk", "v")}
     flat = registry.to_dict()
     for name in ("moe_tokens_routed_total", "moe_held_assignments_total",
                  "moe_max_load_over_mean"):
@@ -427,7 +436,9 @@ def phase_kernels(run: Run):
         checks += ns.check_linear_attention(run.interpret)
         linear_attn = _linear_attn_calls(tel.registry)
         linear_rope_calls = _rope_calls(tel.registry)
-    checks += ns.check_gated_delta_precision(run.interpret)
+    with telemetry.scope(profile=False) as tel:
+        checks += ns.check_gated_delta_precision(run.interpret)
+        precision_rule = _linear_attn_calls(tel.registry)
     with telemetry.scope(profile=False) as tel:
         checks += ns.check_ssd_scan(run.interpret)
         ssd = _ssd_scan_calls(tel.registry)
@@ -454,6 +465,7 @@ def phase_kernels(run: Run):
             interpret=run.interpret, config_origins=origins,
             flash_tiles_staged=flash_tiles, flash_steps_held=flash_held,
             rope_calls_staged=rope_calls, linear_attn_staged=linear_attn,
+            gated_delta_rows_staged=precision_rule,
             linear_model_rope_calls_staged=linear_rope_calls,
             ssd_scan_staged=ssd, grouped_staged=grouped,
             latent_attn_staged=latent)
@@ -477,6 +489,18 @@ def phase_kernels(run: Run):
     want["chunked" if run.rehearsal else "pallas"] = 2
     check(linear_attn.get("calls") == want,
           f"gated_delta_rule staged as {linear_attn}, expected {want}")
+    # check_gated_delta_precision: on the chip the rule staged once a row
+    # through F.gated_delta_rule, by the kernels: Qwen3-Next's heads of 128
+    # lanes with no padding, Olmo-Hybrid's 96 key lanes padded to 128 and
+    # 192 value lanes to 256 (rehearsed, it calls the interpreted kernels
+    # itself and stages nothing)
+    want = {} if run.rehearsal else {
+        "calls": {"pallas": 2, "chunked": 0, "recurrent": 0},
+        "chunks": 2 * 8192 // 64,
+        "lanes": {"qk": {"data": 128 + 96, "pad": 32},
+                  "v": {"data": 128 + 192, "pad": 64}}}
+    check(precision_rule == want, f"the precision rows' rule staged as "
+                                  f"{precision_rule}, expected {want}")
     check(linear_rope_calls.get(path, {}).get("norm=1") == 2
           and sum(n for calls in linear_rope_calls.values()
                   for n in calls.values()) == 2,

@@ -23,7 +23,8 @@ dependent steps where the recurrence has ``seq``. Two implementations of
 that one algorithm, chosen from what the call can observe (backend, head
 widths, chunk, dtype, sequence), never by the caller:
 
-- on a TPU, at head widths of one 128-lane block and the layer's chunk of
+- on a TPU, at key heads of up to one 128-lane block, value heads of up to
+  two (each padded with zero lanes to whole blocks) and the layer's chunk of
   64, two Pallas kernels a (row, head) (``ops/pallas/gated_delta.py``:
   ``gated_delta_fwd`` / ``gated_delta_bwd``): a chunk's system, its
   inverse, ``W``, ``U`` and the outputs stay in VMEM and the state is
@@ -330,10 +331,14 @@ def _chunked_heads(q, k, v, g, beta, chunk):
     return o[:, :seq].astype(v.dtype)
 
 
-def _count_staged(path: str, chunks: int):
+def _count_staged(path: str, chunks: int, widths=None):
     """``linear_attn_calls_staged_total{path}`` and
     ``gated_delta_chunks_total``: one call of ``gated_delta_rule`` being
-    staged, and the dependent chunk steps a row of it has."""
+    staged, and the dependent chunk steps a row of it has. On the kernels'
+    path ``widths`` ``{operand: (head width, lanes the kernels carry)}``
+    for ``qk`` and ``v`` adds ``gated_delta_lanes_staged_total{operand,
+    kind=data|pad}``: a head's own lanes and the zero lanes padded to whole
+    128-lane blocks."""
     from ... import telemetry
     if telemetry.enabled():
         telemetry.counter(
@@ -344,6 +349,15 @@ def _count_staged(path: str, chunks: int):
             "gated_delta_chunks_total",
             "Chunk states a row walks one after another, summed over the "
             "staged calls of gated_delta_rule").inc(chunks)
+        if widths:
+            lanes = telemetry.counter(
+                "gated_delta_lanes_staged_total",
+                "Lanes a head takes in the staged calls of the gated delta "
+                "rule's kernels: its own and the zero lanes padded to whole "
+                "128-lane blocks")
+            for operand, (d, width) in widths.items():
+                lanes.inc(d, operand=operand, kind="data")
+                lanes.inc(width - d, operand=operand, kind="pad")
 
 
 def gated_delta_rule(q, k, v, g, beta, chunk: int = 64,
@@ -369,8 +383,9 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 64,
     backward pass for a real row. Staged under the scope
     ``gated_delta_rule``; ``linear_attn_calls_staged_total{path=pallas|
     chunked|recurrent}`` counts the staged calls by what was decided
-    (``chunked``: XLA's) and ``gated_delta_chunks_total`` their chunk
-    steps."""
+    (``chunked``: XLA's), ``gated_delta_chunks_total`` their chunk steps
+    and, on the kernels' path, ``gated_delta_lanes_staged_total{operand=
+    qk|v, kind=data|pad}`` the lanes a head takes there."""
     if path not in ("chunked", "recurrent"):
         raise ValueError(f"unknown path {path!r}")
     from ...ops.pallas import gated_delta as kernel
@@ -379,7 +394,12 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 64,
             and q.dtype == k.dtype == v.dtype \
             and kernel.supported(q.shape, v.shape, v.dtype, chunk):
         path = "pallas"
-    _count_staged(path, seq if path == "recurrent" else -(-seq // chunk))
+    widths = None
+    if path == "pallas":
+        widths = {name: (d, kernel.pad_lanes(d))
+                  for name, d in (("qk", q.shape[-1]), ("v", v.shape[-1]))}
+    _count_staged(path, seq if path == "recurrent" else -(-seq // chunk),
+                  widths)
     with jax.named_scope("gated_delta_rule"):
         if path == "recurrent":
             return _recurrent(q, k, v, g, beta)

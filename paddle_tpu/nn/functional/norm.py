@@ -66,17 +66,25 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5, name=N
     return out
 
 
-def rms_norm(x, weight=None, epsilon=1e-6, name=None, offset=0.0):
+def rms_norm(x, weight=None, epsilon=1e-6, name=None, offset=0.0,
+             axis_name=None):
     """``x / sqrt(mean(x**2) + epsilon) * weight`` over the last axis: no
     mean is subtracted and there is no bias. The statistics are float32
     whatever ``x`` is. With ``offset`` the scale is ``offset + weight`` (a
     zero-centred weight under ``offset=1``: it starts at 0 and weight decay
     pulls the scale to 1), formed and applied in float32 before the result
-    takes ``x``'s dtype."""
+    takes ``x``'s dtype. ``axis_name``: the last axis is this member's share
+    of a wider one split over the named axis (a tensor-parallel layer's
+    lanes), and the mean runs over all members' lanes (two ``psum``s)."""
     weight = _unwrap(weight)
     x32 = x.astype(jnp.float32)
-    out = x32 * jax.lax.rsqrt(
-        jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + epsilon)
+    if axis_name is None:
+        mean = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+    else:
+        mean = jax.lax.psum(
+            jnp.sum(jnp.square(x32), axis=-1, keepdims=True), axis_name) \
+            / jax.lax.psum(x.shape[-1], axis_name)
+    out = x32 * jax.lax.rsqrt(mean + epsilon)
     if offset:
         return (out * (offset + weight.astype(jnp.float32))).astype(x.dtype)
     out = out.astype(x.dtype)

@@ -43,8 +43,10 @@ class GatedDeltaNet(Layer):
        ``[b | a] = in_proj_ba(x)`` (``2 value_heads``);
     2. ``[q | k | v] <- silu(causal_conv1d([q | k | v], conv_weight))``:
        depthwise over the sequence, ``conv_kernel`` taps, no bias;
-    3. ``beta = sigmoid(b)``, ``g = -exp(A_log) * softplus(a + dt_bias)``
-       in float32, one a value head a position;
+    3. ``beta = sigmoid(b)``, or ``2 sigmoid(b)`` with ``allow_neg_eigval``
+       (the step's transition ``I - beta k k^T`` then has the eigenvalue
+       ``1 - beta`` in ``(-1, 1)``: Olmo-Hybrid's layers), ``g = -exp(A_log)
+       * softplus(a + dt_bias)`` in float32, one a value head a position;
     4. ``q <- q / sqrt(sum q^2 + eps) / sqrt(d_k)``, ``k <- k / sqrt(sum
        k^2 + eps)`` over a head;
     5. ``o = F.gated_delta_rule(q, k, v, g, beta)`` in chunks of ``CHUNK``
@@ -60,7 +62,7 @@ class GatedDeltaNet(Layer):
     """
 
     def __init__(self, hidden_size, key_heads, value_heads, d_k, d_v,
-                 conv_kernel=4, epsilon=1e-6):
+                 conv_kernel=4, epsilon=1e-6, allow_neg_eigval=False):
         super().__init__()
         if value_heads % key_heads:
             raise ValueError(f"{value_heads} value heads over {key_heads} "
@@ -68,6 +70,7 @@ class GatedDeltaNet(Layer):
         self.key_heads, self.value_heads = key_heads, value_heads
         self.d_k, self.d_v = d_k, d_v
         self.epsilon = epsilon
+        self.allow_neg_eigval = allow_neg_eigval
         self.key_dim, self.value_dim = key_heads * d_k, value_heads * d_v
         self.in_proj_qkvz = Linear(hidden_size,
                                    2 * self.key_dim + 2 * self.value_dim,
@@ -105,6 +108,8 @@ class GatedDeltaNet(Layer):
                         (b, s, self.key_heads, self.d_k))
         v = jnp.reshape(mixed[..., 2 * kd:], (b, s, hv, self.d_v))
         beta = jax.nn.sigmoid(ba[..., :hv].astype(f32))
+        if self.allow_neg_eigval:
+            beta = 2.0 * beta
         g = -jnp.exp(self.A_log.value.astype(f32)) * jax.nn.softplus(
             ba[..., hv:].astype(f32) + self.dt_bias.value.astype(f32))
         group = hv // self.key_heads

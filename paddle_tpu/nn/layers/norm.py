@@ -185,14 +185,17 @@ class LayerNorm(Layer):
 class RMSNorm(Layer):
     """Root-mean-square norm over the last axis with a learned scale
     (``F.rms_norm``). ``offset=1.0`` makes the weight zero-centred: the
-    scale is ``1 + weight`` and the weight starts at 0."""
+    scale is ``1 + weight`` and the weight starts at 0. ``axis_name``: the
+    layer holds a share of a wider axis split over that named axis, and the
+    statistic runs over every share's lanes."""
 
     def __init__(self, hidden_size, epsilon=1e-6, weight_attr=None,
-                 name=None, offset=0.0):
+                 name=None, offset=0.0, axis_name=None):
         super().__init__()
         self.hidden_size = hidden_size
         self.epsilon = epsilon
         self.offset = offset
+        self.axis_name = axis_name
         self.weight = self.create_parameter(
             (hidden_size,), attr=weight_attr,
             initializer=_to_initializer(weight_attr, None)
@@ -206,7 +209,8 @@ class RMSNorm(Layer):
         return self.offset + self.weight.value.astype(jnp.float32)
 
     def forward(self, x):
-        return F.rms_norm(x, self.weight, self.epsilon, offset=self.offset)
+        return F.rms_norm(x, self.weight, self.epsilon, offset=self.offset,
+                          axis_name=self.axis_name)
 
     def extra_repr(self):
         return f"hidden_size={self.hidden_size}"

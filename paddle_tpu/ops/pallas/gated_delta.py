@@ -42,8 +42,14 @@ dropped: what three bf16 passes give). ``q``, ``k``, ``v`` in bf16 enter as
 they are.
 
 Layout: ``q, k, v`` are read as the layer's fusions leave them, ``(batch,
-seq, heads x 128)`` with a lane block a head (a free reshape of ``(batch,
-seq, heads, 128)``), and ``o`` is written the same way. The per-position
+seq, heads x width)`` with a whole number of 128-lane blocks a head (a free
+reshape of ``(batch, seq, heads, width)``), and ``o`` is written the same
+way. A head narrower than its blocks (Olmo-Hybrid's ``d_k = 96`` and ``d_v
+= 192``) arrives with zero lanes after its own, ``pad_lanes``: a zero key
+lane adds nothing to ``k k^T``, ``q k^T`` or ``S^T q`` and a zero value lane
+writes a zero column of the state and of ``o``, so no value changes, and
+the pad fuses into the fusions that make ``q, k, v`` and the cut into the
+one that reads ``o``. The per-position
 vectors (cumulative decay, ``beta``) arrive with a chunk's positions along
 the lanes; the factors of rows come from one small product with a constant
 on the MXU (``_Masks.columns``: exact, three bf16 terms of the float32 value), in
@@ -92,13 +98,27 @@ def tile_chunks(chunks, tile=TILE):
     return chunks if chunks < tile else None
 
 
+def pad_lanes(d):
+    """The lanes a head of width ``d`` takes in the kernels: ``d`` rounded
+    up to whole 128-lane blocks."""
+    return -(-d // LANES) * LANES
+
+
+def _fills(d, blocks):
+    """A head of width ``d`` takes at most ``blocks`` 128-lane blocks, and
+    more than half of the lanes it takes are its own."""
+    return pad_lanes(d) <= blocks * LANES and 2 * d > pad_lanes(d)
+
+
 def supported(q_shape, v_shape, dtype, chunk):
     """Whether the kernels take ``q`` ``(batch, seq, heads, d_k)`` and ``v``
-    ``(batch, seq, heads, d_v)``: both head widths one 128-lane block, the
+    ``(batch, seq, heads, d_v)``: a key head of one 128-lane block and a
+    value head of two at most, each padded to whole blocks (``pad_lanes``)
+    with fewer zero lanes than its own (narrower heads take XLA's path), the
     chunk they are built for, bf16 or float32 operands and a sequence whose
     chunks (the last one padded) are a whole number of tiles."""
     seq = q_shape[1]
-    return (q_shape[-1] == LANES and v_shape[-1] == LANES
+    return (_fills(q_shape[-1], 1) and _fills(v_shape[-1], 2)
             and chunk == CHUNK
             and jnp.dtype(dtype) in (jnp.dtype(_BF16), jnp.dtype(_F32))
             and tile_chunks(-(-seq // chunk)) is not None)
@@ -361,38 +381,42 @@ def _bwd_kernel(q_ref, k_ref, v_ref, gc_ref, beta_ref, s_ref, do_ref,
 def _call(name, q, k, v, gc, beta, tile, interpret, states=None, do=None):
     """Forward (``gated_delta_fwd``: ``o`` and the state entering each
     chunk) or backward (``gated_delta_bwd``: ``dq, dk, dv, dgc, dbeta``).
-    ``q, k, v`` (and ``do``) ``(batch, seq, heads x 128)``, ``gc`` and
-    ``beta`` ``(batch, heads, tiles, tile, CHUNK)`` float32, ``states``
-    ``(batch, heads, chunks, 128, 128)`` float32."""
+    ``q, k`` ``(batch, seq, heads x dk)``, ``v`` (and ``o``, ``do``)
+    ``(batch, seq, heads x dv)``, ``dk`` and ``dv`` whole 128-lane blocks,
+    ``gc`` and ``beta`` ``(batch, heads, tiles, tile, CHUNK)`` float32,
+    ``states`` ``(batch, heads, chunks, dk, dv)`` float32."""
     backward = name == BWD
     b, heads, tiles = gc.shape[:3]
     seq = q.shape[1]
+    dk, dv = q.shape[-1] // heads, v.shape[-1] // heads
     # the backward walks a head's tiles from the last to the first
     at = (lambda t: tiles - 1 - t) if backward else (lambda t: t)
-    rows = pl.BlockSpec((1, tile * CHUNK, LANES),
-                        lambda i, j, t: (i, at(t), j))
+    keys = pl.BlockSpec((1, tile * CHUNK, dk), lambda i, j, t: (i, at(t), j))
+    values = pl.BlockSpec((1, tile * CHUNK, dv),
+                          lambda i, j, t: (i, at(t), j))
     vec = pl.BlockSpec((1, 1, 1, tile, CHUNK),
                        lambda i, j, t: (i, j, at(t), 0, 0))
-    state = pl.BlockSpec((1, 1, tile, LANES, LANES),
+    state = pl.BlockSpec((1, 1, tile, dk, dv),
                          lambda i, j, t: (i, j, at(t), 0, 0))
-    like = jax.ShapeDtypeStruct(q.shape, q.dtype)
+    q_like = jax.ShapeDtypeStruct(q.shape, q.dtype)
+    v_like = jax.ShapeDtypeStruct(v.shape, q.dtype)
     vec_like = jax.ShapeDtypeStruct(gc.shape, _F32)
     if backward:
         kernel, operands = _bwd_kernel, (q, k, v, gc, beta, states, do)
-        in_specs = [rows] * 3 + [vec] * 2 + [state, rows]
-        out_specs = [rows] * 3 + [vec] * 2
-        out_shape = [like] * 3 + [vec_like] * 2
+        in_specs = [keys] * 2 + [values] + [vec] * 2 + [state, values]
+        out_specs = [keys] * 2 + [values] + [vec] * 2
+        out_shape = [q_like] * 2 + [v_like] + [vec_like] * 2
     else:
         kernel, operands = _fwd_kernel, (q, k, v, gc, beta)
-        in_specs = [rows] * 3 + [vec] * 2
-        out_specs = [rows, state]
-        out_shape = [like, jax.ShapeDtypeStruct(
-            (b, heads, seq // CHUNK, LANES, LANES), _F32)]
+        in_specs = [keys] * 2 + [values] + [vec] * 2
+        out_specs = [values, state]
+        out_shape = [v_like, jax.ShapeDtypeStruct(
+            (b, heads, seq // CHUNK, dk, dv), _F32)]
     return pl.pallas_call(
         functools.partial(kernel, tile=tile),
         grid=(b, heads, tiles), in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((LANES, LANES), _F32)],
+        scratch_shapes=[pltpu.VMEM((dk, dv), _F32)],
         interpret=interpret, name=name,
         compiler_params=pltpu.CompilerParams(
             # the state is carried over a head's tiles
@@ -432,14 +456,18 @@ _rule.defvjp(_rule_fwd, _rule_bwd)
 
 def gated_delta(q, k, v, g, beta, tile=TILE, interpret=False):
     """``F.gated_delta_rule``'s chunked path through the kernels: ``q, k``
-    ``(batch, seq, heads, 128)``, ``v`` likewise, ``g`` and ``beta``
-    ``(batch, seq, heads)``; ``q, k, v`` of one dtype, ``o`` in it. The
-    caller asks ``supported`` first. A sequence that is no whole number of chunks is
+    ``(batch, seq, heads, d_k)``, ``v`` ``(batch, seq, heads, d_v)``, ``g``
+    and ``beta`` ``(batch, seq, heads)``; ``q, k, v`` of one dtype, ``o`` in
+    it. The caller asks ``supported`` first. A head narrower than whole
+    128-lane blocks gets zero lanes after its own (``pad_lanes``) and ``o``
+    is cut back to ``d_v``; a sequence that is no whole number of chunks is
     padded with ``beta = 0, g = 0`` and cut again. The sum of ``g`` from a
     chunk's start, and its transpose, stay XLA's. Differentiable in all
     five. ``tile`` is the module's constant for every caller but the tests,
     which take two chunks a grid step so that a short row crosses tiles."""
-    b, seq, heads, _ = q.shape
+    b, seq, heads, d_k = q.shape
+    d_v = v.shape[-1]
+    dk, dv = pad_lanes(d_k), pad_lanes(d_v)
     pad = -seq % CHUNK
     if pad:
         q, k, v, g, beta = (jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),)
@@ -456,9 +484,15 @@ def gated_delta(q, k, v, g, beta, tile=TILE, interpret=False):
         x = jnp.moveaxis(x.astype(_F32), 1, 2)
         return jnp.reshape(x, (b, heads, chunks // tile, tile, CHUNK))
 
-    def rows(x):
-        return jnp.reshape(x, (b, seq + pad, heads * LANES))
+    def rows(x, width):
+        """``(batch, seq, heads, d)`` as ``(batch, seq, heads x width)``,
+        each head's lanes past ``d`` zero."""
+        if x.shape[-1] < width:
+            x = jnp.pad(x, ((0, 0),) * 3 + ((0, width - x.shape[-1]),))
+        return jnp.reshape(x, (b, seq + pad, heads * width))
 
-    o = _rule(rows(q), rows(k), rows(v), jnp.cumsum(vector(g), axis=-1),
-              vector(beta), tile, bool(interpret))
-    return jnp.reshape(o, (b, seq + pad, heads, LANES))[:, :seq]
+    o = _rule(rows(q, dk), rows(k, dk), rows(v, dv),
+              jnp.cumsum(vector(g), axis=-1), vector(beta), tile,
+              bool(interpret))
+    o = jnp.reshape(o, (b, seq + pad, heads, dv))
+    return o[:, :seq] if dv == d_v else o[:, :seq, :, :d_v]

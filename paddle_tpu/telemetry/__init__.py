@@ -98,6 +98,11 @@ latent_attn_calls_staged_total counter    text.models MultiHeadLatent
 gated_delta_chunks_total       counter    chunk states a row of those calls
                                           walks one after another (seq /
                                           chunk; seq on the recurrent path)
+gated_delta_lanes_staged_total counter    the lanes a head takes in those
+                                          calls that took the kernels {operand=
+                                          qk|v, kind=data (its own)|pad
+                                          (zero lanes to whole 128-lane
+                                          blocks)}
 ssd_scan_calls_staged_total    counter    nn.functional.ssd_scan, where a
                                           call is staged: the path it took
                                           {path=chunked|recurrent}

@@ -5,9 +5,11 @@ number of query heads, and a dense, a sparse (routed experts plus a shared
 expert) or no feed-forward block. A layer with both is two residual
 sublayers, ``h = x + mixer(norm(x)); y = h + ffn(norm(h))``; a layer with
 one of them is one, ``y = x + sublayer(norm(x))`` (Nemotron-H's pattern of
-Mamba, expert and attention layers).
+Mamba, expert and attention layers). ``norm_placement="post"`` moves every
+block's norms onto its sublayers' outputs, ``h = x + norm(mixer(x)); y = h +
+norm(ffn(h))`` (Olmo 2's reordered norms).
 
-What every layer shares: pre-norm residual blocks with RMSNorm
+What every layer shares: residual blocks with RMSNorm
 (``norm_offset=1`` for zero-centred weights, scale ``1 + w``), token
 embeddings only, a final RMSNorm and an untied head. Feed-forward blocks
 are gated SiLU FFNs, or squared-ReLU experts (``expert_activation=
@@ -20,7 +22,8 @@ at all, ``rope[kind]`` None), and an
 optional sigmoid gate on the attention output: one a head from a
 projection of its own (``"head"``) or one a lane from a doubled ``q_proj``
 (``"elementwise"``). ``qk_norm`` adds an RMSNorm over the head width on q
-and k before the rotation. ``"linear_attention"`` layers are
+and k before the rotation, or with ``qk_norm="whole"`` one over all of q's
+and all of k's lanes (Olmo 2's). ``"linear_attention"`` layers are
 ``nn.GatedDeltaNet`` (``linear_attention`` holds its sizes): a state of
 fixed size a head carried along the sequence, no positions and no mask.
 ``"latent_attention"`` layers are ``MultiHeadLatentAttention``
@@ -48,7 +51,8 @@ Names are what the benchmark's scope metrics read: root
 ``mixeddecoderforpretraining`` or ``mixeddecoderforblockdiffusion``, trunk
 ``decoder``, blocks ``h.N``, in a block ``attn``, ``linear_attn`` or
 ``mamba`` and ``mlp`` or ``moe`` (one of the two in a one-sublayer block),
-then ``lm_head``; the multi-token-prediction module is
+under ``norm_placement="post"`` their norms ``post_mixer_norm`` and
+``post_ffn_norm``, then ``lm_head``; the multi-token-prediction module is
 ``mtp`` with its block ``mtp.block``; in a latent-attention layer the scopes
 ``latent_q`` and ``latent_kv`` hold what stands where grouped-query
 attention has its three projections.
@@ -86,18 +90,32 @@ class GroupedQueryAttention(Layer):
     norm and rotation (``.../qk_norm/rope/...``); without a norm the
     rotation is under ``rope`` alone. ``rope=None`` rotates nothing: the
     layer has no positional encoding and sees the order through the causal
-    mask alone (a QK norm is then applied by itself)."""
+    mask alone (a QK norm is then applied by itself).
+
+    ``qk_norm_over="whole"`` puts the norm over all ``num_heads x head_dim``
+    lanes of q and all ``kv_heads x head_dim`` of k instead, one weight of
+    that width each, before the split into heads (Olmo 2's QK norm), under
+    the scope ``qk_norm``; a rotation follows it. ``axis_name``: the layer
+    is one share of a tensor-parallel layer whose heads are split over that
+    named axis, and the whole-width norm's mean of squares runs over every
+    share's lanes; None (the default) takes this layer's own lanes, as one
+    chip that holds its share alone does."""
 
     def __init__(self, hidden_size, num_heads, kv_heads, head_dim, rope,
                  window=None, gate=None, qk_norm_epsilon=None,
-                 norm_offset=0.0):
+                 norm_offset=0.0, qk_norm_over="head", axis_name=None):
         super().__init__()
         if num_heads % kv_heads:
             raise ValueError(f"{num_heads} query heads over {kv_heads} KV "
                              f"heads")
         if gate not in (None, "head", "elementwise"):
             raise ValueError(f"unknown attention gate {gate!r}")
-        self.gate = gate
+        if qk_norm_over not in ("head", "whole"):
+            raise ValueError(f"unknown QK norm width {qk_norm_over!r}")
+        if qk_norm_over == "whole" and gate == "elementwise":
+            raise ValueError("an elementwise gate beside a whole-width QK "
+                             "norm is not built")
+        self.gate, self.qk_norm_over = gate, qk_norm_over
         self.num_heads, self.kv_heads = num_heads, kv_heads
         self.head_dim, self.window = head_dim, window
         # rope: {"theta", "rotary_dim", "yarn" or None}, or None for no
@@ -119,7 +137,12 @@ class GroupedQueryAttention(Layer):
         self.o_proj = nn.Linear(num_heads * head_dim, hidden_size,
                                 bias_attr=False)
         self.q_norm = self.k_norm = None
-        if qk_norm_epsilon is not None:
+        if qk_norm_epsilon is not None and qk_norm_over == "whole":
+            self.q_norm = nn.RMSNorm(num_heads * head_dim, qk_norm_epsilon,
+                                     offset=norm_offset, axis_name=axis_name)
+            self.k_norm = nn.RMSNorm(kv_heads * head_dim, qk_norm_epsilon,
+                                     offset=norm_offset, axis_name=axis_name)
+        elif qk_norm_epsilon is not None:
             self.q_norm = nn.RMSNorm(head_dim, qk_norm_epsilon,
                                      offset=norm_offset)
             self.k_norm = nn.RMSNorm(head_dim, qk_norm_epsilon,
@@ -141,17 +164,20 @@ class GroupedQueryAttention(Layer):
         the causal mask."""
         b, s, _ = x.shape
         d = self.head_dim
-        q = jnp.reshape(self.q_proj(x), (b, s, self.num_heads, -1))
-        if self.gate == "elementwise":
-            q, gate = q[..., :d], q[..., d:]
-        k = jnp.reshape(self.k_proj(x), (b, s, self.kv_heads, d))
-        v = jnp.reshape(self.v_proj(x), (b, s, self.kv_heads, d))
-        if self.q_norm is None:
-            q, k = self._rope(q, positions), self._rope(k, positions)
+        if self.q_norm is not None and self.qk_norm_over == "whole":
+            q, k, v, gate = self._whole_normed(x, positions)
         else:
-            with jax.named_scope("qk_norm"):
-                q = self._rope(q, positions, self.q_norm)
-                k = self._rope(k, positions, self.k_norm)
+            q = jnp.reshape(self.q_proj(x), (b, s, self.num_heads, -1))
+            if self.gate == "elementwise":
+                q, gate = q[..., :d], q[..., d:]
+            k = jnp.reshape(self.k_proj(x), (b, s, self.kv_heads, d))
+            v = jnp.reshape(self.v_proj(x), (b, s, self.kv_heads, d))
+            if self.q_norm is None:
+                q, k = self._rope(q, positions), self._rope(k, positions)
+            else:
+                with jax.named_scope("qk_norm"):
+                    q = self._rope(q, positions, self.q_norm)
+                    k = self._rope(k, positions, self.k_norm)
         out = F.scaled_dot_product_attention(
             q, k, v, is_causal=block_diffusion is None, window=self.window,
             training=self.training, block_diffusion=block_diffusion)
@@ -161,6 +187,20 @@ class GroupedQueryAttention(Layer):
             out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
                 out.dtype)
         return self.o_proj(jnp.reshape(out, (b, s, self.num_heads * d)))
+
+    def _whole_normed(self, x, positions):
+        """``q, k, v`` as heads (and no elementwise gate), q and k normed
+        over all their lanes before the split into heads and then
+        rotated."""
+        b, s, _ = x.shape
+        d = self.head_dim
+        q, k = self.q_proj(x), self.k_proj(x)
+        with jax.named_scope("qk_norm"):
+            q, k = self.q_norm(q), self.k_norm(k)
+        q = self._rope(jnp.reshape(q, (b, s, self.num_heads, d)), positions)
+        k = self._rope(jnp.reshape(k, (b, s, self.kv_heads, d)), positions)
+        v = jnp.reshape(self.v_proj(x), (b, s, self.kv_heads, d))
+        return q, k, v, None
 
 
 def _count_latent_call(rope_path: str):
@@ -279,35 +319,57 @@ class MixedDecoderBlock(Layer):
     ``mixer_name``; these two take the hidden states alone) and ``mlp``
     (dense) or ``moe`` (sparse). A block of one sublayer (``mixer`` or
     ``ffn`` None) is ``y = x + sublayer(norm(x))`` under ``input_norm`` and
-    has no ``post_attn_norm``."""
+    has no ``post_attn_norm``. ``norm_placement="post"`` norms each
+    sublayer's output instead, ``h = x + post_mixer_norm(mixer(x)); y = h +
+    post_ffn_norm(ffn(h))``, and the block has neither of the other two."""
 
     def __init__(self, mixer: Layer, ffn: Layer, sparse: bool, hidden_size,
-                 epsilon, norm_offset=0.0, mixer_name="attn"):
+                 epsilon, norm_offset=0.0, mixer_name="attn",
+                 norm_placement="pre"):
         super().__init__()
         if mixer is None and ffn is None:
             raise ValueError("a block needs a mixer or a feed-forward block")
-        self.input_norm = nn.RMSNorm(hidden_size, epsilon,
-                                     offset=norm_offset)
+        if norm_placement not in ("pre", "post"):
+            raise ValueError(f"unknown norm placement {norm_placement!r}")
+        self.post_norms = norm_placement == "post"
+
+        def norm():
+            return nn.RMSNorm(hidden_size, epsilon, offset=norm_offset)
+
+        if not self.post_norms:
+            self.input_norm = norm()
         self._mixer_name = mixer_name
         if mixer is not None:
             setattr(self, self._mixer_name, mixer)
-        if mixer is not None and ffn is not None:
-            self.post_attn_norm = nn.RMSNorm(hidden_size, epsilon,
-                                             offset=norm_offset)
+            if self.post_norms:
+                self.post_mixer_norm = norm()
+            elif ffn is not None:
+                self.post_attn_norm = norm()
         self._ffn_name = "moe" if sparse else "mlp"
         if ffn is not None:
             setattr(self, self._ffn_name, ffn)
+            if self.post_norms:
+                self.post_ffn_norm = norm()
         self.has_mixer, self.has_ffn = mixer is not None, ffn is not None
 
-    def mixer_half(self, x, positions=None, block_diffusion=None):
+    def _mix(self, x, positions, block_diffusion):
         mixer = getattr(self, self._mixer_name)
         if self._mixer_name != "attn":
-            return x + mixer(self.input_norm(x))
-        return x + mixer(self.input_norm(x), positions, block_diffusion)
+            return mixer(x)
+        return mixer(x, positions, block_diffusion)
+
+    def mixer_half(self, x, positions=None, block_diffusion=None):
+        if self.post_norms:
+            return x + self.post_mixer_norm(
+                self._mix(x, positions, block_diffusion))
+        return x + self._mix(self.input_norm(x), positions, block_diffusion)
 
     def ffn_half(self, x):
+        ffn = getattr(self, self._ffn_name)
+        if self.post_norms:
+            return x + self.post_ffn_norm(ffn(x))
         norm = self.post_attn_norm if self.has_mixer else self.input_norm
-        return x + getattr(self, self._ffn_name)(norm(x))
+        return x + ffn(norm(x))
 
     def forward(self, x, positions=None, block_diffusion=None):
         if self.has_mixer:
@@ -363,10 +425,13 @@ class MixedDecoderModel(Layer):
     ``make_block(mixer_kind, ffn_kind, heads)`` builds one more block of the
     model's sizes (the multi-token-prediction module's). ``attention_gate`` is ``"head"`` (``gated_attention=
     True`` says the same), ``"elementwise"`` or None. ``qk_norm``: an
-    RMSNorm over the head width on q and k, the model's ``epsilon``.
+    RMSNorm over the head width on q and k, the model's ``epsilon``;
+    ``"whole"``: one over all of q's lanes and one over all of k's.
     ``norm_offset=1`` makes every RMSNorm weight zero-centred (scale ``1 +
-    w``). ``rope[kind]`` holds ``theta``, ``rotary_dim`` and optionally
-    ``yarn`` for each kind of attention, or None for no rotation.
+    w``). ``norm_placement``: ``"pre"`` norms a sublayer's input, ``"post"``
+    its output (``MixedDecoderBlock``); the final norm is there in both.
+    ``rope[kind]`` holds ``theta``, ``rotary_dim`` and optionally ``yarn``
+    for each kind of attention, or None for no rotation.
     ``checkpoint_blocks`` recomputes each block in the backward pass (the
     trainer's ``remat`` checkpoints the whole model at once), its mixer
     half and its feed-forward half apart: one more hidden state is kept a
@@ -390,7 +455,8 @@ class MixedDecoderModel(Layer):
                  attention_gate=None, shared_expert_gate=False,
                  linear_attention=None, norm_offset=0.0,
                  latent_attention=None, router_selection_bias=False,
-                 mamba=None, expert_activation="silu"):
+                 mamba=None, expert_activation="silu",
+                 norm_placement="pre"):
         super().__init__()
         if not (len(layer_types) == len(heads_per_layer)
                 == len(mlp_layer_types)):
@@ -424,7 +490,8 @@ class MixedDecoderModel(Layer):
                     window=sliding_window if kind == SLIDING else None,
                     gate=attention_gate,
                     qk_norm_epsilon=epsilon if qk_norm else None,
-                    norm_offset=norm_offset)
+                    norm_offset=norm_offset,
+                    qk_norm_over="whole" if qk_norm == "whole" else "head")
             if ffn_kind == SPARSE:
                 ffn = DroplessMoELayer(
                     hidden_size, expert_size, num_experts, experts_per_token,
@@ -443,7 +510,8 @@ class MixedDecoderModel(Layer):
             return MixedDecoderBlock(
                 mixer, ffn, ffn_kind == SPARSE, hidden_size, epsilon,
                 norm_offset=norm_offset, mixer_name={
-                    LINEAR: "linear_attn", MAMBA: "mamba"}.get(kind, "attn"))
+                    LINEAR: "linear_attn", MAMBA: "mamba"}.get(kind, "attn"),
+                norm_placement=norm_placement)
 
         self.make_block = block
         self.h = nn.LayerList([

@@ -416,6 +416,40 @@ def test_gated_delta_kernels_at_the_qwen3_next_cells_shape(v5e, rows):
     assert f"f32[{rows},32,128,128,128]" in text
 
 
+def test_gated_delta_kernels_at_the_olmo_hybrid_cells_shape(v5e):
+    """The gated delta rule of ``olmo-hybrid-7b.seq8192``'s linear layers:
+    four rows of 8,192 positions, 15 heads of ``d_k = 96`` and ``d_v = 192``
+    padded with zero lanes to 128 and 256, in one program with the flash
+    kernels of the cell's full layer (15 heads of 128). The kernels lower
+    and fit Mosaic's scoped VMEM in the module's tile at a ``128 x 256``
+    state, and the residual is that padded state a chunk."""
+    from paddle_tpu.ops.pallas import gated_delta
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+
+    qk, v = (4, 8192, 15, 96), (4, 8192, 15, 192)
+    assert gated_delta.supported(qk, v, jnp.bfloat16, 64)
+
+    def f(q, k, v, g, beta, fq, fk, fv):
+        def loss(q, k, v, g, beta, fq, fk, fv):
+            with jax.named_scope("gated_delta_rule"):
+                o = gated_delta.gated_delta(q, k, v, g, beta)
+            with jax.named_scope("attn"):
+                a = flash_attention(fq, fk, fv, causal=True)
+            return jnp.sum(o.astype(jnp.float32) ** 2) \
+                + jnp.sum(a.astype(jnp.float32) ** 2)
+        return jax.grad(loss, argnums=tuple(range(8)))(
+            q, k, v, g, beta, fq, fk, fv)
+
+    full = ((4, 8192, 15, 128), jnp.bfloat16)
+    text = _compile(f, v5e, (qk, jnp.bfloat16), (qk, jnp.bfloat16),
+                    (v, jnp.bfloat16), (qk[:3], jnp.float32),
+                    (qk[:3], jnp.float32), full, full, full)
+    for kernel in ("gated_delta_fwd", "gated_delta_bwd", "flash_bwd_dkv"):
+        assert any("tpu_custom_call" in line and f"%{kernel}" in line
+                   for line in text.splitlines()), kernel
+    assert "f32[4,15,128,128,256]" in text
+
+
 @pytest.mark.parametrize("rows", [2, 1])
 def test_ssd_scan_kernels_at_the_nemotron_cells_shape(v5e, rows):
     """The state-space scan of ``nemotron-3-nano-30b-a3b.seq8192``'s Mamba

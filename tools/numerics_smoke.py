@@ -300,73 +300,109 @@ def check_linear_attention(interpret):
     return results
 
 
-# ``check_gated_delta_precision``'s limits, as shares of each reference
-# tensor's norm: between what the kernels read at three bf16 passes a float32
-# product and what one pass reads (PERF.md section 6, PR 34, my chip runs:
-# 1.3e-4 to 1.4e-4 against 3.7e-3 for o, dq, dk and dv, 3.7e-6 against 2.4e-3
-# for dg and dbeta, which no bf16 rounds)
-GATED_DELTA_PRECISION_TOL = {"o": 7e-4, "dq": 7e-4, "dk": 7e-4, "dv": 7e-4,
-                             "dg": 1e-4, "dbeta": 1e-4}
+# ``check_gated_delta_precision``'s rows: value heads, ``d_k``, ``d_v`` and the
+# largest step size of a layer of each cell that runs the rule's kernels
+GATED_DELTA_ROWS = {"qwen3_next": (32, 128, 128, 1.0),
+                    "olmo_hybrid": (15, 96, 192, 2.0)}
+# its limits, as shares of each reference tensor's norm: between what the
+# kernels read at three bf16 passes a float32 product and what one pass reads
+# (PERF.md section 6, a TPU v5e: at the Qwen3-Next row 1.3e-4 to 1.4e-4
+# against 3.3e-3 to 3.7e-3 for o, dq, dk and dv, 3.6e-6 to 3.7e-6 against
+# 2.4e-3 for dg and dbeta, which no bf16 rounds; at the Olmo-Hybrid row
+# 1.3e-4 to 1.5e-4 against 3.4e-3 to 3.5e-3, and 3.8e-6 to 4.0e-6 against
+# 2.6e-3)
+GATED_DELTA_PRECISION_TOL = {
+    "qwen3_next": {"o": 7e-4, "dq": 7e-4, "dk": 7e-4, "dv": 7e-4,
+                   "dg": 1e-4, "dbeta": 1e-4},
+    "olmo_hybrid": {"o": 7e-4, "dq": 7e-4, "dk": 7e-4, "dv": 7e-4,
+                    "dg": 1e-4, "dbeta": 1e-4}}
+
+
+def gated_delta_row(name, seq, key=34):
+    """A layer-shaped input of the rule at ``GATED_DELTA_ROWS[name]``: one
+    row of ``seq`` positions, bf16 ``q, k, v`` as the layer normalises them,
+    the layer's decay, a step size up to the row's largest, and a bf16
+    cotangent."""
+    import jax
+    import jax.numpy as jnp
+
+    h, dk, dv, most = GATED_DELTA_ROWS[name]
+    ks = jax.random.split(jax.random.key(key), 7)
+    q, k = (jax.random.normal(s, (1, seq, h, dk)) for s in ks[:2])
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) / dk ** 0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    q, k = q.astype(jnp.bfloat16), k.astype(jnp.bfloat16)
+    v = jax.random.normal(ks[2], (1, seq, h, dv), jnp.bfloat16)
+    # the layer's decay, -A softplus(a + dt_bias): rates from e^-6 to e
+    g = -jnp.exp(jax.random.uniform(ks[3], (1, seq, h), minval=-6.0,
+                                    maxval=1.0)) \
+        * jax.random.uniform(ks[6], (1, seq, h))
+    beta = most * jax.nn.sigmoid(jax.random.normal(ks[4], (1, seq, h)))
+    # a cotangent that bf16 holds: the kernels' o is bf16, so is its do
+    ct = jax.random.normal(ks[5], v.shape, jnp.bfloat16).astype(jnp.float32)
+    return (q, k, v, g, beta), ct
 
 
 def check_gated_delta_precision(interpret):
     """The guard the benchmark's comparison lacks (it does not tell a
     bf16-rounded state from a sound one): the kernels' ``o`` and five
-    gradients on one layer-shaped input of the Qwen3-Next cell (a row of
-    8,192 positions, 32 value heads of ``d_k = d_v = 128``, bf16; rehearsed
-    on the CPU at 256 positions and 2 heads) against XLA's chunked path at
+    gradients on one layer-shaped input of each cell that runs them
+    (``GATED_DELTA_ROWS``: a row of 8,192 positions, Qwen3-Next's 32 value
+    heads of ``d_k = d_v = 128`` and Olmo-Hybrid's 15 of ``d_k = 96, d_v =
+    192`` with step sizes up to 2, bf16; rehearsed on the CPU at 256
+    positions and 2 heads) against XLA's chunked path at
     ``Precision.HIGHEST`` over the same numbers in float32, each as the
     norm of the difference over the reference's norm. A product that fell
-    to one bf16 pass reads an order of magnitude over these limits."""
+    to one bf16 pass reads an order of magnitude over these limits. On the
+    chip the kernels are called through ``F.gated_delta_rule``: what path it
+    took and the lanes it padded are for the caller to read from the
+    counters."""
     from unittest import mock
 
     import jax
     import jax.numpy as jnp
 
+    from paddle_tpu.nn import functional as F
     from paddle_tpu.nn.functional import linear_attention as la
     from paddle_tpu.ops.pallas import gated_delta
 
-    seq, h, d = (256, 2, 128) if interpret else (8192, 32, 128)
-    ks = jax.random.split(jax.random.key(34), 7)
-    q, k = (jax.random.normal(key, (1, seq, h, d)) for key in ks[:2])
-    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) / d ** 0.5
-    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
-    q, k = q.astype(jnp.bfloat16), k.astype(jnp.bfloat16)
-    v = jax.random.normal(ks[2], (1, seq, h, d), jnp.bfloat16)
-    # the layer's decay, -A softplus(a + dt_bias): rates from e^-6 to e
-    g = -jnp.exp(jax.random.uniform(ks[3], (1, seq, h), minval=-6.0,
-                                    maxval=1.0)) \
-        * jax.random.uniform(ks[6], (1, seq, h))
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (1, seq, h)))
-    # a cotangent that bf16 holds: the kernels' o is bf16, so is its do
-    ct = jax.random.normal(ks[5], v.shape, jnp.bfloat16).astype(jnp.float32)
-
-    def rule(fn):
+    def rule(fn, ct):
         def f(*args):
             out = fn(*args)
             return jnp.sum(out.astype(jnp.float32) * ct), out
         return jax.jit(jax.grad(f, argnums=(0, 1, 2, 3, 4), has_aux=True))
 
-    got, out = rule(lambda *a: gated_delta.gated_delta(
-        *a, interpret=interpret))(q, k, v, g, beta)
-    # ``kk`` and ``qk`` carry no precision of their own (their operands are
-    # bf16 numbers), so their transposes, which multiply a float32 cotangent,
-    # take the default: one pass on the chip unless it is raised here
-    with mock.patch.object(la, "PRECISION", jax.lax.Precision.HIGHEST), \
-            jax.default_matmul_precision("highest"):
-        want, out_ref = rule(lambda *a: la._chunked(
-            *a, gated_delta.CHUNK, la.GROUP_HEADS))(*(
-                x.astype(jnp.float32) for x in (q, k, v, g, beta)))
-    rel = {}
-    for name, a, w in zip(GATED_DELTA_PRECISION_TOL, (out,) + got,
-                          (out_ref,) + want):
-        # the reference rounded as the kernels round what they write
-        a, w = a.astype(jnp.float32), w.astype(a.dtype).astype(jnp.float32)
-        rel[name] = float(jnp.linalg.norm(a - w) / jnp.linalg.norm(w))
-    return [{"check": "gated_delta_kernels_vs_xla_highest", "rel_l2": rel,
-             "tol": GATED_DELTA_PRECISION_TOL,
-             "ok": all(rel[n] < t
-                       for n, t in GATED_DELTA_PRECISION_TOL.items())}]
+    results = []
+    for name, tol in GATED_DELTA_PRECISION_TOL.items():
+        args, ct = gated_delta_row(name, 256 if interpret else 8192)
+        if interpret:
+            args = tuple(x[:, :, :2] for x in args)
+            ct = ct[:, :, :2]
+        # on the chip through F.gated_delta_rule, which takes the kernels
+        # there (and counts their lanes); rehearsed, the kernels
+        # interpreted
+        kernels = (lambda *a: gated_delta.gated_delta(*a, interpret=True)) \
+            if interpret else F.gated_delta_rule
+        got, out = rule(kernels, ct)(*args)
+        # ``kk`` and ``qk`` carry no precision of their own (their operands
+        # are bf16 numbers), so their transposes, which multiply a float32
+        # cotangent, take the default: one pass on the chip unless it is
+        # raised here
+        with mock.patch.object(la, "PRECISION", jax.lax.Precision.HIGHEST), \
+                jax.default_matmul_precision("highest"):
+            want, out_ref = rule(lambda *a: la._chunked(
+                *a, gated_delta.CHUNK, la.GROUP_HEADS), ct)(*(
+                    x.astype(jnp.float32) for x in args))
+        rel = {}
+        for part, a, w in zip(tol, (out,) + got, (out_ref,) + want):
+            # the reference rounded as the kernels round what they write
+            a, w = a.astype(jnp.float32), w.astype(a.dtype).astype(
+                jnp.float32)
+            rel[part] = float(jnp.linalg.norm(a - w) / jnp.linalg.norm(w))
+        results.append({"check": f"gated_delta_kernels_vs_xla_highest_{name}",
+                        "rel_l2": rel, "tol": tol,
+                        "ok": all(rel[n] < t for n, t in tol.items())})
+    return results
 
 
 # ``check_ssd_scan``'s limits, as shares of each reference tensor's norm:
